@@ -1,9 +1,11 @@
 """Run every BASELINE config bench in its own process; collect the JSON lines.
 
-Usage: python bench/run_all.py [--out BENCH_SUITE.json]
-Each config runs in a fresh subprocess so compile caches, env overrides, and
-device state never leak between configs. A config failure is recorded, not
-fatal — the suite always emits a complete report.
+Usage: python bench/run_all.py [--out chiprun_out/bench_suite.json]
+Each config runs in a fresh subprocess so env overrides and device state
+never leak between configs, and so each in turn is the one process that
+holds the chip: this parent never imports jax. It needs a TPU (a first
+child checks, and nothing runs without one). A config failure is recorded
+and the rest still run, but the suite then exits non-zero.
 """
 
 from __future__ import annotations
@@ -52,11 +54,29 @@ CONFIGS = [
 ]
 
 
-def main() -> None:
+_CHIP_CHECK = (
+    "import jax\n"
+    "d = jax.devices()[0]\n"
+    "if d.platform != 'tpu':\n"
+    "    raise SystemExit(f'the suite measures a TPU, jax found "
+    "{d.platform!r}')\n"
+    "print(d.device_kind)\n"
+)
+
+
+def main() -> int:
     here = os.path.dirname(os.path.abspath(__file__))
-    out_path = "BENCH_SUITE.json"
+    out_path = os.path.join(os.path.dirname(here), "chiprun_out",
+                            "bench_suite.json")
     if "--out" in sys.argv:
         out_path = sys.argv[sys.argv.index("--out") + 1]
+
+    check = subprocess.run([sys.executable, "-c", _CHIP_CHECK],
+                           capture_output=True, text=True, timeout=300)
+    if check.returncode:
+        print(check.stderr[-1500:], file=sys.stderr)
+        return 1
+    print(f"device: {check.stdout.strip()}", flush=True)
 
     results = []
     for name, extra_env in CONFIGS:
@@ -84,10 +104,15 @@ def main() -> None:
         print(f"[{status}] {name}: {json.dumps(parsed) if parsed else proc.stderr[-300:]}",
               flush=True)
 
+    os.makedirs(os.path.dirname(os.path.abspath(out_path)), exist_ok=True)
     with open(out_path, "w") as f:
         json.dump(results, f, indent=2)
     print(f"wrote {out_path}")
+    failed = [r["config"] for r in results if r["rc"] or not r["result"]]
+    if failed:
+        print(f"failed: {', '.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

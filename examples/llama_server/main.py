@@ -108,9 +108,38 @@ async def stream_ws(ctx: gofr_tpu.Context):
     return {"done": True}
 
 
+def build_app(params, cfg, **llm_kwargs) -> gofr_tpu.App:
+    """The serving surface over one model: ``register_llm("chat", ...)``
+    plus the three transports. ``main`` sizes the model from the
+    environment; ``chip_smoke.py`` passes Llama-3-8B widths."""
+    app = gofr_tpu.new_app()
+    app.register_llm("chat", params, cfg, **llm_kwargs)
+
+    app.post("/generate", generate)
+    app.websocket("/stream", stream_ws)
+
+    svc = JSONService("llm.Chat")
+
+    async def grpc_generate(request, context):
+        # one frame per decode-chunk burst, not per token: 16x fewer gRPC
+        # messages at chunk=16 with identical token latency (tokens arrive
+        # from the device in bursts anyway)
+        llm = app.container.ml.llm("chat")
+        max_new = int(request.get("max_new_tokens", 64))
+        _admissible(llm, request["prompt_ids"], max_new)
+        async for burst in llm.stream_chunks(request["prompt_ids"],
+                                             max_new,
+                                             priority=_priority(request),
+                                             deadline_s=_deadline(request)):
+            yield {"tokens": burst}
+
+    svc.stream("Generate", grpc_generate)
+    app.register_service(svc, impl=None)
+    return app
+
+
 def main() -> gofr_tpu.App:
     global TOKENIZER
-    app = gofr_tpu.new_app()
     TOKENIZER = _tokenizer_from_env()
     # LLAMA_PRESET / LLAMA_KV_QUANT / LLAMA_W8 / LLAMA_CKPT -> config
     # (shared with openai_server; a HF checkpoint defines the arch)
@@ -129,8 +158,8 @@ def main() -> gofr_tpu.App:
     raw_disagg = os.environ.get("LLM_DISAGG", "").strip()
     if raw_disagg and raw_disagg not in ("0", "1"):
         raise ValueError(f"LLM_DISAGG must be 0 or 1, got {raw_disagg!r}")
-    app.register_llm(
-        "chat", params, cfg,
+    return build_app(
+        params, cfg,
         batch_slots=int(os.environ.get("LLM_SLOTS", "4")),
         max_seq=min(cfg.max_seq_len, 1024),
         chunk=int(os.environ.get("LLM_CHUNK", "4")),
@@ -157,28 +186,6 @@ def main() -> gofr_tpu.App:
         # admit suffix-only (paged generators only)
         **({"disagg": raw_disagg == "1"} if raw_disagg else {}),
     )
-
-    app.post("/generate", generate)
-    app.websocket("/stream", stream_ws)
-
-    svc = JSONService("llm.Chat")
-
-    async def grpc_generate(request, context):
-        # one frame per decode-chunk burst, not per token: 16x fewer gRPC
-        # messages at chunk=16 with identical token latency (tokens arrive
-        # from the device in bursts anyway)
-        llm = app.container.ml.llm("chat")
-        max_new = int(request.get("max_new_tokens", 64))
-        _admissible(llm, request["prompt_ids"], max_new)
-        async for burst in llm.stream_chunks(request["prompt_ids"],
-                                             max_new,
-                                             priority=_priority(request),
-                                             deadline_s=_deadline(request)):
-            yield {"tokens": burst}
-
-    svc.stream("Generate", grpc_generate)
-    app.register_service(svc, impl=None)
-    return app
 
 
 if __name__ == "__main__":

@@ -242,7 +242,7 @@ class Container:
                       "engine batch buckets, native pjrt executables)")
         m.new_counter("app_ml_compile_cache_hits_total",
                       "program compiles served by the persistent XLA "
-                      "compilation cache (GOFR_ML_COMPILATION_CACHE_DIR)")
+                      "compilation cache")
         m.new_gauge("app_ml_programs",
                     "jitted/native programs in a model's compiled "
                     "inventory (the /debug/programs row count)")

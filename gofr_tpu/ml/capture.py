@@ -115,7 +115,8 @@ def sampler_snapshot(gen) -> dict | None:
 def runtime_fingerprint() -> dict:
     """The runtime identity a capture bundle (and the ``runtime`` block
     of ``/debug/serving``) snapshots: jax version, backend, device
-    kind/count, and every armed ``GOFR_ML_*`` knob. Replay diffs this
+    kind/count, which attention branch (Pallas kernel or XLA) each traced
+    shape took, and every armed ``GOFR_ML_*`` knob. Replay diffs this
     dict against the bundle's copy — same traffic on a different
     runtime is a comparison, not a reproduction."""
     out: dict = {
@@ -125,6 +126,8 @@ def runtime_fingerprint() -> dict:
     try:  # lazy: this module stays importable (and cheap) without jax
         import jax
 
+        from ..ops import kernel_branches
+
         devs = jax.devices()
         out["jax"] = jax.__version__
         out["backend"] = jax.default_backend()
@@ -132,8 +135,9 @@ def runtime_fingerprint() -> dict:
             "kind": devs[0].device_kind if devs else None,
             "count": len(devs),
         }
+        out["kernels"] = kernel_branches()
     except Exception:
-        out.update(jax=None, backend=None, devices=None)
+        out.update(jax=None, backend=None, devices=None, kernels=None)
     return out
 
 

@@ -83,9 +83,9 @@ class Engine:
         self._metrics = metrics
         self._tracer = tracer
         self.backend = backend
-        # GOFR_ML_COMPILATION_CACHE_DIR: persistent XLA compilation cache —
-        # restarts load the shape-bucket executables from disk instead of
-        # recompiling them (same knob Generator.warmup honors)
+        # persistent XLA compilation cache — restarts load the shape-bucket
+        # executables from disk instead of recompiling them (the same
+        # cache Generator.warmup turns on)
         maybe_enable_compilation_cache()
         self.compiled_buckets: set[int] = set()  # batch dims seen on device
         # program & compile telemetry (ml/programs.py): one row per

@@ -21,7 +21,7 @@ restart?". This module is the shared recording machinery:
   ``/jax/compilation_cache/cache_hits|cache_misses``): whatever jax
   compiles on this thread inside the ``with`` block is charged to the
   program being recorded, so "compiled fresh" vs "served from the
-  persistent cache" (``GOFR_ML_COMPILATION_CACHE_DIR``) vs "already in
+  persistent cache" (``scheduler.maybe_enable_compilation_cache``) vs "already in
   the in-process jit cache" becomes a per-row fact instead of folklore.
 
 Aggregates export as ``app_ml_compile_seconds_total`` /

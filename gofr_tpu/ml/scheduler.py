@@ -96,41 +96,37 @@ def normalize_priority(priority) -> int:
     return priority
 
 
-def maybe_enable_compilation_cache() -> str | None:
-    """Honor ``GOFR_ML_COMPILATION_CACHE_DIR``: point jax's persistent
-    compilation cache at the directory so a restarted server loads the
-    chunk-fn ladder and prefill buckets from disk instead of recompiling
-    them (the ladder made warmup several programs larger). Returns the
-    directory when enabled. Safe to call repeatedly and on old jax
-    versions (each knob is best-effort)."""
-    path = os.environ.get("GOFR_ML_COMPILATION_CACHE_DIR")
-    if not path:
-        return None
-    import jax
+# where the persistent compilation cache lives when the environment names
+# no place for it: one fixed path inside the checkout. The path is part of
+# the cache key, so a directory that moves between runs never hits.
+DEFAULT_COMPILATION_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-    try:
-        jax.config.update("jax_compilation_cache_dir", path)
-    except Exception:
-        return None  # jax without the persistent cache: nothing to do
+
+def maybe_enable_compilation_cache() -> str:
+    """Turn on jax's persistent compilation cache so a restarted server
+    loads the chunk-fn ladder and prefill buckets from disk instead of
+    recompiling them. Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax's
+    own handling of it stands and no directory is set here; otherwise the
+    cache goes to ``DEFAULT_COMPILATION_CACHE_DIR``. Returns the directory
+    in effect. Safe to call repeatedly."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as _cc
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          DEFAULT_COMPILATION_CACHE_DIR)
     # serving programs are small but numerous: the default min-compile-time
     # threshold (1 s) would skip exactly the ladder entries restarts want
-    for knob, value in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                        ("jax_persistent_cache_min_entry_size_bytes", 0)):
-        try:
-            jax.config.update(knob, value)
-        except Exception:
-            pass
-    try:
-        # jax decides cache-or-not lazily at the FIRST compile and then
-        # sticks with that decision; a Generator is always built after the
-        # model's own param/cache compiles, so drop the memoized state and
-        # let the next compile re-read the (now set) cache dir
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc)
-        _cc.reset_cache()
-    except Exception:
-        pass
-    return path
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # jax decides cache-or-not lazily at the FIRST compile and then sticks
+    # with that decision; a Generator is always built after the model's own
+    # param/cache compiles, so drop the memoized state and let the next
+    # compile re-read the cache dir
+    _cc.reset_cache()
+    return jax.config.jax_compilation_cache_dir
 
 
 class TokenBudgetScheduler:

@@ -367,8 +367,11 @@ def init_params(cfg: LlamaConfig, key) -> dict:
                           cfg.n_kv_heads, cfg.head_dim, cfg.ffn_dim)
 
     def dense(key, *shape, fan_in):
-        return (jax.random.normal(key, shape, jnp.float32) * (fan_in ** -0.5)
-                ).astype(cfg.dtype)
+        # draw and cast in one program: eagerly, the f32 draw (3.8 GB for
+        # one FFN stack at 8B widths) is resident beside the bf16 result
+        return jax.jit(
+            lambda k: (jax.random.normal(k, shape, jnp.float32)
+                       * (fan_in ** -0.5)).astype(cfg.dtype))(key)
 
     ks = jax.random.split(k_layers, 7)
     return {
@@ -482,9 +485,8 @@ def _decode_layer(cfg: LlamaConfig, x, lp, cos, sin, arrays, layer,
     scan's CARRY so XLA aliases them in place: a first version returned
     per-layer caches through scan ys, which restacked (= copied) the
     entire multi-GB cache every token — that copy, not attention, was the
-    r1 decode bottleneck (BENCH_r01 8.4 ms steps). Here the only cache
-    write is the [B, KV, D] scatter of the new token at
-    ``[layer, rows, pos]``.
+    first decode bottleneck. Here the only cache write is the [B, KV, D]
+    scatter of the new token at ``[layer, rows, pos]``.
     """
     b = x.shape[0]
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -766,7 +768,7 @@ def prefill_segment_into(params: dict, tokens: jnp.ndarray,
     the slot's already-prefilled rows plus the segment (causal). A long
     prompt becomes several of these interleaved with decode chunks, so a
     2k-token prefill can no longer stall every live stream for its whole
-    duration (the TTFT-jitter fix, VERDICT r4 #2).
+    duration (the TTFT-jitter fix).
 
     Returns (logits of the segment's LAST VALID token [1, V], cache).
     ``new_len`` lands in cache["len"][slot]: pass the cache CAPACITY for
@@ -1072,7 +1074,7 @@ def paged_decode_step(params: dict, tokens: jnp.ndarray, cache: dict,
     the row's pages back into a virtual [P_max * page_s] sequence.
     """
     from ..ops import (apply_rope, attention, dequantize_kv, quantize_kv,
-                       repeat_kv, rms_norm, rope_table)
+                       record_branch, repeat_kv, rms_norm, rope_table)
 
     b = tokens.shape[0]
     page_s = cache["k"].shape[2]
@@ -1090,6 +1092,9 @@ def paged_decode_step(params: dict, tokens: jnp.ndarray, cache: dict,
                           scaling=cfg.rope_scaling)
     rows = jnp.arange(b)
     kv_idx = jnp.arange(KV)[None, :]
+    # there is no paged kernel: the gathered virtual sequence always goes
+    # through XLA, and the dispatch record says so
+    record_branch("paged_decode_attention", False, x, cache["k"])
 
     def body(carry, lp):
         x, arrays, layer = carry

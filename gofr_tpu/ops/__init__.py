@@ -38,7 +38,34 @@ __all__ = [
     "quantize_weight",
     "swiglu",
     "flash_attention",
+    "branch_key",
+    "record_branch",
+    "kernel_branches",
 ]
+
+# Which implementation each attention dispatcher chose, by op and shape:
+# "<op>[<shape key>]" -> "pallas" | "xla". The choice is made silently at
+# trace time from platform and shape; this table is its record, written
+# once per distinct shape (``runtime_fingerprint`` and ``chip_smoke.py``
+# read it).
+_BRANCHES: dict[str, str] = {}
+
+
+def branch_key(op: str, *arrays) -> str:
+    """The table's key for ``op`` on operands of these shapes (arrays or
+    ``ShapeDtypeStruct``s; the first operand's dtype stands for all)."""
+    shapes = ",".join("x".join(map(str, a.shape)) for a in arrays)
+    return f"{op}[{shapes},{arrays[0].dtype}]"
+
+
+def record_branch(op: str, pallas: bool, *arrays) -> None:
+    """Note at trace time which branch ``op`` took for these operands."""
+    _BRANCHES[branch_key(op, *arrays)] = "pallas" if pallas else "xla"
+
+
+def kernel_branches() -> dict[str, str]:
+    """A copy of the dispatch record, sorted by key."""
+    return dict(sorted(_BRANCHES.items()))
 
 
 def rms_norm(x: jnp.ndarray, scale: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
@@ -293,7 +320,9 @@ def cached_decode_attention(q, k_cache, v_cache, kv_len, *, layer=None,
     # models/llama.init_cache); fp caches are [L?, B, S, KV, D]
     stacked = k_cache.ndim == (4 if quantized else 5)
     s_max = k_cache.shape[2] if stacked else k_cache.shape[1]
-    if use_kernel and _on_tpu() and q.shape[1] == 1 and s_max % 256 == 0:
+    kernel = use_kernel and _on_tpu() and q.shape[1] == 1 and s_max % 256 == 0
+    record_branch("decode_attention", kernel, q, k_cache)
+    if kernel:
         from .decode_attention import gqa_decode_attention_tpu
 
         return gqa_decode_attention_tpu(q, k_cache, v_cache, kv_len,
@@ -391,7 +420,9 @@ def flash_attention(q, k, v, *, causal: bool = True, q_offset=0, kv_len=None,
     """
     tq, tk = q.shape[1], k.shape[1]
     bq, bk = min(block_q, tq), min(block_k, tk)
-    if _on_tpu() and tq >= 128 and tq % bq == 0 and tk % bk == 0:
+    kernel = _on_tpu() and tq >= 128 and tq % bq == 0 and tk % bk == 0
+    record_branch("flash_attention", kernel, q, k)
+    if kernel:
         return _flash_diff(q, k, v, kv_len, causal, q_offset, block_q,
                            block_k)
     return attention(q, k, v, causal=causal, q_offset=q_offset, kv_len=kv_len)

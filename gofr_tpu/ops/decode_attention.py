@@ -192,8 +192,8 @@ def gqa_decode_attention_tpu(q, k_cache, v_cache, kv_len, *, layer=None,
 
     in_specs = [
         pl.BlockSpec((None, h, d), lambda bi, kvlen, lyr: (bi, 0, 0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),  # k cache stays in HBM
-        pl.BlockSpec(memory_space=pltpu.ANY),  # v cache stays in HBM
+        pl.BlockSpec(memory_space=pl.ANY),  # k cache stays in HBM
+        pl.BlockSpec(memory_space=pl.ANY),  # v cache stays in HBM
     ]
     buf_shape = (2, block_s, kv * d) if quantized else (2, block_s, kv, d)
     scratch = [
@@ -205,8 +205,8 @@ def gqa_decode_attention_tpu(q, k_cache, v_cache, kv_len, *, layer=None,
     if quantized:
         kernel = functools.partial(
             _decode_kernel_quant, block_s=block_s, kv_heads=kv, n_rep=n_rep)
-        in_specs += [pl.BlockSpec(memory_space=pltpu.ANY),
-                     pl.BlockSpec(memory_space=pltpu.ANY)]
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY),
+                     pl.BlockSpec(memory_space=pl.ANY)]
         scratch += [pltpu.VMEM((2, kv, block_s), k_scale.dtype),
                     pltpu.VMEM((2, kv, block_s), v_scale.dtype)]
         sems += [pltpu.SemaphoreType.DMA((2,)), pltpu.SemaphoreType.DMA((2,))]

@@ -48,21 +48,7 @@ __all__ = [
 ]
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` with a jax<0.4.38 fallback — the compat twin of
-    ``constrain``'s ``get_abstract_mesh`` fallback. Older releases ship
-    it as ``jax.experimental.shard_map.shard_map`` with the replication
-    check under its old name (``check_rep``); without this shim every
-    sequence-parallel path (ring/Ulysses attention, the sp decode
-    combine, pipeline parallelism) is dead on this image's jax."""
-    native = getattr(jax, "shard_map", None)
-    if native is not None:
-        return native(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+shard_map = jax.shard_map
 
 AXES = ("dp", "fsdp", "pp", "ep", "tp", "sp")
 
@@ -193,28 +179,12 @@ def shard_like(tree: Any, spec: PartitionSpec, mesh: Mesh) -> Any:
     return jax.tree.map(lambda leaf: jax.device_put(leaf, sharding), tree)
 
 
-def _active_mesh():
-    """The ambient mesh, or None. jax >= 0.4.38 exposes
-    ``jax.sharding.get_abstract_mesh``; older releases track the ``with
-    mesh:`` context on the thread-resources env instead."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        return get()
-    try:
-        from jax._src.mesh import thread_resources
-
-        return thread_resources.env.physical_mesh
-    except Exception:
-        return None
-
-
 def constrain(x: Any, spec: PartitionSpec) -> Any:
     """with_sharding_constraint that is a no-op outside a mesh context
     (single-device unit tests, CPU paths). Inside a mesh, errors propagate —
     a typo'd axis or non-divisible dim must fail loudly, not silently
     replicate."""
-    env_mesh = _active_mesh()
-    if env_mesh is None or env_mesh.empty:
+    if jax.sharding.get_abstract_mesh().empty:
         return x
     return jax.lax.with_sharding_constraint(x, spec)
 

@@ -4,6 +4,7 @@ aging, and adaptive-vs-fixed token identity.
 """
 
 import asyncio
+import os
 import time
 import types
 
@@ -265,13 +266,37 @@ def test_prefetch_failure_counted_not_fatal(model):
     assert gen.pool_stats()["prefetch_errors"] == gen.prefetch_errors
 
 
-def test_compilation_cache_env(tmp_path, monkeypatch):
-    monkeypatch.delenv("GOFR_ML_COMPILATION_CACHE_DIR", raising=False)
-    assert maybe_enable_compilation_cache() is None
-    cache_dir = str(tmp_path / "xla-cache")
-    monkeypatch.setenv("GOFR_ML_COMPILATION_CACHE_DIR", cache_dir)
-    assert maybe_enable_compilation_cache() == cache_dir
-    assert jax.config.jax_compilation_cache_dir == cache_dir
+@pytest.mark.parametrize("env_set", [True, False],
+                         ids=["env-set", "env-unset"])
+def test_compilation_cache_dir(env_set, tmp_path, monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` set: jax's own handling stands and
+    the code sets no directory. Unset: one fixed path in the checkout."""
+    from gofr_tpu.ml.scheduler import DEFAULT_COMPILATION_CACHE_DIR
+
+    before = jax.config.jax_compilation_cache_dir
+    # stands for what jax read from the variable at import
+    jax_own = str(tmp_path / "jax-own")
+    jax.config.update("jax_compilation_cache_dir", jax_own)
+    try:
+        if env_set:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", jax_own)
+            assert maybe_enable_compilation_cache() == jax_own
+            assert jax.config.jax_compilation_cache_dir == jax_own
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            assert DEFAULT_COMPILATION_CACHE_DIR == os.path.join(
+                repo, ".jax_cache")
+            assert (maybe_enable_compilation_cache()
+                    == DEFAULT_COMPILATION_CACHE_DIR)
+            assert (jax.config.jax_compilation_cache_dir
+                    == DEFAULT_COMPILATION_CACHE_DIR)
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+    finally:
+        from jax.experimental.compilation_cache import compilation_cache
+
+        jax.config.update("jax_compilation_cache_dir", before)
+        compilation_cache.reset_cache()
 
 
 # --------------------------------------------------------------- server level

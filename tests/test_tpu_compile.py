@@ -1,0 +1,109 @@
+"""The serving path's kernels compiled for a TPU v5e that is described, not
+attached (on-chip-measurement guide, section 2): Llama-3-8B head shapes
+(H=32, KV=8, D=128), 32 slots, a 1024-token cache. Nothing runs — these
+catch what interpret mode cannot: a slice off the tiling, too much VMEM, a
+program that does not fit the chip. Skipped where the TPU compiler is not
+installed.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental import topologies
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+from gofr_tpu import ops
+from gofr_tpu.models import llama
+
+# The kernel modules are imported inside the tests: importing the submodule
+# ``ops.decode_attention`` rebinds the package attribute of that name from
+# the function to the module, which must not happen while pytest collects.
+
+L, B, S, H, KV, D = 16, 32, 1024, 32, 8, 128
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """A ``SingleDeviceSharding`` on one chip of a described v5e:2x2."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs land in /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:
+        pytest.skip(f"no TPU compiler to describe a v5e to: {exc}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip: the next one would warn
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _shape(chip, dtype, *shape):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+
+def _cache_shapes(chip, layout):
+    if layout == "bf16-stacked":
+        kv = _shape(chip, jnp.bfloat16, L, B, S, KV, D)
+        return kv, None
+    kv = _shape(chip, jnp.int8, L, B, S, KV * D)
+    return kv, _shape(chip, jnp.bfloat16, L, B, KV, S)
+
+
+@pytest.mark.parametrize("layout", ["bf16-stacked", "int8-flat"])
+def test_decode_kernel_compiles(chip, layout):
+    from gofr_tpu.ops.decode_attention import gqa_decode_attention_tpu
+
+    kv, scale = _cache_shapes(chip, layout)
+
+    def step(q, k, v, kv_len, layer, *scales):
+        k_scale, v_scale = scales or (None, None)
+        return gqa_decode_attention_tpu(q, k, v, kv_len, layer=layer,
+                                        k_scale=k_scale, v_scale=v_scale)
+
+    args = [_shape(chip, jnp.bfloat16, B, 1, H, D), kv, kv,
+            _shape(chip, jnp.int32, B), _shape(chip, jnp.int32)]
+    if scale is not None:
+        args += [scale, scale]
+    compiled = jax.jit(step).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_kernel_compiles(chip):
+    from gofr_tpu.ops.flash_attention import flash_attention_tpu
+
+    qkv = _shape(chip, jnp.bfloat16, 1, S, H, D)
+    compiled = jax.jit(
+        lambda q, k, v: flash_attention_tpu(q, k, v, causal=True)
+    ).lower(qkv, qkv, qkv).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_decode_step_compiles_with_kernel(chip, monkeypatch):
+    """One whole ``llama.decode_step`` at 8B widths (depth 2): the program
+    the server dispatches per token carries the Pallas kernel and fits."""
+    # under JAX_PLATFORMS=cpu the dispatcher would take its XLA branch: the
+    # test steers it, the program has no option for it
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    cfg = llama.llama3_8b(n_layers=2)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+            tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(lambda: llama.init_cache(cfg, B, S)))
+    compiled = jax.jit(
+        lambda p, t, c: llama.decode_step(p, t, c, cfg)
+    ).lower(params, _shape(chip, jnp.int32, B), cache).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 16 * 2**30)
