@@ -2091,7 +2091,7 @@ async def main() -> None:
     # ---- phase O: pipelined serving loop — double-buffered dispatch -----
     # The ISSUE-18 acceptance surface: pipeline off/on × window {1, K} ×
     # spec off/on over the SAME steady mixed load. For each cell report
-    # steady tok/s, the flight recorder's device_idle_share estimate
+    # steady tok/s, the flight recorder's host_idle_estimate estimate
     # (launch→settle busy credit vs dispatch wall — the number the
     # double-buffering exists to collapse), overlapped_dispatches,
     # client-side TTFT/TPOT p50/p99, and greedy token identity
@@ -2229,8 +2229,8 @@ async def main() -> None:
                         stalls = entry.get("stalls", {})
                         # the headline number of the whole PR: how much
                         # of the dispatch wall the device sat idle
-                        cell["device_idle_share"] = stalls.get(
-                            "device_idle_share")
+                        cell["host_idle_estimate"] = stalls.get(
+                            "host_idle_estimate")
                         cell["overlapped_dispatches"] = stalls.get(
                             "overlapped_dispatches")
                         if mode == "on":
@@ -2254,13 +2254,13 @@ async def main() -> None:
                     speedup_o = round(
                         on_o["steady_tok_s"] / off_o["steady_tok_s"], 3)
                 idle_delta_o = None
-                if (isinstance(off_o.get("device_idle_share"), float)
-                        and isinstance(on_o.get("device_idle_share"),
+                if (isinstance(off_o.get("host_idle_estimate"), float)
+                        and isinstance(on_o.get("host_idle_estimate"),
                                        float)):
                     # positive = the double-buffered loop kept the
                     # device busier (acceptance wants this at window=K)
-                    idle_delta_o = round(off_o["device_idle_share"]
-                                         - on_o["device_idle_share"], 4)
+                    idle_delta_o = round(off_o["host_idle_estimate"]
+                                         - on_o["host_idle_estimate"], 4)
                 identical_o = (ident_o.get("off") == ident_o.get("on")
                                if len(ident_o) == 2 else None)
                 grid_o[f"{variant}_w{wk}"] = {
@@ -2504,7 +2504,7 @@ async def main() -> None:
                               else "skipped (headline budget)"),
             # phase O: pipelined serving loop — double-buffered dispatch
             # off/on × window {1,K} × spec off/on (steady tok/s,
-            # device_idle_share, TTFT/TPOT p50/p99, token identity)
+            # host_idle_estimate, TTFT/TPOT p50/p99, token identity)
             "pipeline": (pipeline_arm if pipeline_arm is not None
                          else "skipped (headline budget)"),
             # phase P: self-tuning — replay-driven config search over

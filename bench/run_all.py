@@ -28,7 +28,7 @@ import time
 # fused-decode-window single-step-vs-fused A/B (steady tok/s, launch
 # phase share, TTFT/TPOT percentiles, greedy token identity); phase O:
 # the pipelined-serving-loop double-buffered-dispatch A/B (steady
-# tok/s, device_idle_share, greedy token identity); phase P: the
+# tok/s, host_idle_estimate, greedy token identity); phase P: the
 # self-tuning arm — replay-driven config search over the committed
 # bench/ bundle (scoreboard, winner, lift vs default) + the winner
 # shadow-canaried on a live pool (verdict, balanced canary ledger);

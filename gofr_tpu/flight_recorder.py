@@ -9,13 +9,15 @@ one curl can answer "where did the step time go?" and "what happened right
 before the crash?":
 
 - ``DispatchRecorder`` — per-dispatch **stall attribution**. The serving
-  thread stamps monotonic phase durations (queue pop, scheduler decide,
-  batch assemble, program launch, async-D2H issue, device wait, emit) as
-  it works; every
-  device dispatch commits one record into a bounded ring, with the
-  unattributed remainder of the pass recorded honestly as ``other`` — the
-  phases of a record always sum to its wall time. Rolling per-phase
-  shares (over the ring) feed the ``llms.<name>.stalls`` block of
+  thread wraps its work in ``with rec.phase(name)`` (queue pop, scheduler
+  decide, batch assemble, program launch, async-D2H issue, device wait,
+  emit): a span with a start and an end on ``perf_counter`` that is also
+  a ``gofr.serve.<phase>`` annotation in a ``jax.profiler`` capture.
+  Every device dispatch commits one record (when, what program, how many
+  rows, its spans) into the process-global ``dispatch_log()``, the
+  unattributed remainder recorded honestly as ``other`` — the phases of
+  a record always sum to its wall time. Rolling per-phase shares (over
+  the recorder's newest records) feed the ``llms.<name>.stalls`` block of
   ``/debug/serving`` and the ``app_llm_dispatch_phase_seconds{phase=…}``
   histogram; ``top_stall`` names the top *host-side* phase so ROADMAP-3c
   work knows what to kill first. ``GOFR_ML_FLIGHT_RECORDER=0`` disables
@@ -52,14 +54,17 @@ TTFT/TPOT budget go, across the fleet?" — lives in the sibling journey
 tracer (``ml/journey.py``): dispatch records carry the rids they served
 and journey marks carry the dispatch seq, so forensics pivot both ways.
 
-Everything here is host-side stdlib — no jax imports, safe to import from
-the debug endpoints without paying the ml package's startup cost.
+Everything here is host-side stdlib — jax is imported lazily (a capture,
+a phase's annotation), so the debug endpoints import this module without
+paying the ml package's startup cost.
 """
 
 from __future__ import annotations
 
 import collections
+import contextlib
 import io
+import itertools
 import os
 import shutil
 import tempfile
@@ -67,9 +72,10 @@ import threading
 import time
 import zipfile
 
-__all__ = ["PHASES", "DispatchRecorder", "EventLog", "CrashVault",
-           "AutoProfiler", "ProfileVault", "PROFILE_LOCK",
-           "event_log", "crash_vault", "profile_vault",
+__all__ = ["PHASES", "DispatchRecorder", "DispatchLog", "EventLog",
+           "CrashVault", "AutoProfiler", "ProfileVault", "PROFILE_LOCK",
+           "phase", "dispatch_log", "event_log", "crash_vault",
+           "profile_vault",
            "recorder_enabled", "autoprof_enabled", "zip_dir_bytes"]
 
 # the jax profiler is process-global state: ONE capture at a time, ever —
@@ -78,23 +84,11 @@ __all__ = ["PHASES", "DispatchRecorder", "EventLog", "CrashVault",
 PROFILE_LOCK = threading.Lock()
 
 # the dispatch-phase taxonomy (the label set of
-# app_llm_dispatch_phase_seconds). ``route`` is recorded by the replica
-# pool's router; everything else by one LLMServer serving thread.
-# ``launch`` (program launch + arg staging, incl. chunked-prefill
-# segments) and ``d2h_issue`` (issuing the async token prefetch) split
-# what used to be one ``dispatch`` phase, so the PR-7 "launch is ~59% of
-# step time" finding is directly attributable before/after the fusion
-# work. ``ship`` (computing + spilling a prefix's KV pages out of a
-# prefill replica) and ``land`` (adopting transported pages into a
-# decode replica's host tier) are the disaggregated-serving KV-transport
-# phases (ml/kv_transport.py), stamped by the serving thread of the
-# replica doing that side of the handoff. ``sp_prefill`` is one
-# sequence-parallel prefill wave (GOFR_ML_SP, ml/sp_serving.py) — a
-# long prompt's sharded forward + KV landing, stamped by the generator
-# at admission so the attribution names the SP wave when long prompts
-# dominate a dispatch instead of lumping it into ``assemble``.
-# ``other`` is the honest remainder: wall time of a dispatch pass no
-# instrumented site claimed (host bookkeeping loops, GC, OS scheduling).
+# app_llm_dispatch_phase_seconds; docs/tpu/observability.md says what each
+# phase covers). ``route`` is recorded by the replica pool's router,
+# everything else by one LLMServer serving thread; ``other`` is the honest
+# remainder: wall time of a pass no instrumented site claimed (host
+# bookkeeping loops, GC, OS scheduling).
 PHASES = ("queue_pop", "decide", "assemble", "sp_prefill", "launch",
           "d2h_issue", "device_wait", "emit", "route", "ship", "land",
           "other")
@@ -109,40 +103,82 @@ def recorder_enabled() -> bool:
     return os.environ.get("GOFR_ML_FLIGHT_RECORDER", "1").strip() != "0"
 
 
+_NO_PHASE = contextlib.nullcontext()  # the one no-op context, recorder off
+# a pass that launched no decode program (the tail flush) commits as this
+_NO_LAUNCH = ("flush", 0, 0)
+_TraceAnnotation = None  # jax.profiler's, imported at the first phase
+
+
+def phase(rec: "DispatchRecorder | None", name: str, **meta):
+    """``rec.phase(name, **meta)``, or the shared no-op context where the
+    recorder is off (``GOFR_ML_FLIGHT_RECORDER=0``: nothing is constructed)."""
+    return _NO_PHASE if rec is None else rec.phase(name, **meta)
+
+
+class _Span:
+    """One phase of the current pass, open between ``with`` and its end;
+    in a profiler capture an event on the serving thread's host line."""
+
+    __slots__ = ("rec", "name", "t0", "t1", "inner_s", "ann")
+
+    def __init__(self, rec: "DispatchRecorder", name: str, meta: dict):
+        global _TraceAnnotation
+        if _TraceAnnotation is None:
+            from jax.profiler import TraceAnnotation as _TraceAnnotation
+        self.rec, self.name, self.inner_s = rec, name, 0.0
+        self.ann = _TraceAnnotation("gofr.serve." + name, **meta)
+
+    def __enter__(self) -> "_Span":
+        self.ann.__enter__()
+        self.t0 = self.t1 = time.perf_counter()
+        self.rec._open.append(self)
+        self.rec._spans.append(self)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = time.perf_counter()
+        self.ann.__exit__(*exc)
+        rec, took = self.rec, self.t1 - self.t0
+        rec._open.pop()
+        if rec._open:
+            rec._open[-1].inner_s += took
+        # self time: the interval less what its child spans cover, so a
+        # record's phases sum to (never past) its wall
+        rec.note(self.name, max(0.0, took - self.inner_s))
+
+
 class DispatchRecorder:
     """Per-dispatch phase breakdown for one serving core.
 
-    The serving thread calls ``note(phase, seconds)`` as it works and
-    ``commit()`` once per device dispatch; ``reset()`` discards a pure
-    idle pass (an idle server's poll wait is not a stall of any
-    dispatch). ``snapshot()`` is safe from any thread.
+    The serving thread wraps its work in ``with rec.phase(name)`` and
+    calls ``commit()`` once per device dispatch; ``reset()`` discards a
+    pure idle pass (an idle server's poll wait is not a stall of any
+    dispatch). Records go to ``dispatch_log()``; ``snapshot()`` and
+    ``tail()`` read this recorder's newest ``ring`` of them, any thread.
     """
+
+    _ids = itertools.count(1)
 
     def __init__(self, *, model: str = "llm", metrics=None,
                  ring: int = 256) -> None:
         self.model = model
         self._metrics = metrics
-        self._ring: collections.deque[dict] = collections.deque(maxlen=ring)
-        # guards the ring and lifetime totals only — note() is
-        # serving-thread-private and takes no lock at all
+        self._rolling = ring
+        self._key = next(self._ids)  # this recorder's records in the log
+        # guards the lifetime totals only — note() and phase() are
+        # serving-thread-private and take no lock at all
         self._lock = threading.Lock()
         self._pending: dict[str, float] = {}
+        self._spans: list[_Span] = []  # this pass's, in order of start
+        self._open: list[_Span] = []   # the nesting stack
         self._pending_rids: list[str] = []  # rids served this pass
-        # fused-decode-window dim of the current pass: (planned K,
-        # realized steps, windows settled) — stamped by the generator's
-        # processing pass so the committed record describes the window(s)
-        # whose tokens it drained
+        # the decode programs this pass launched: the last one's kind,
+        # their steps, their rows x steps (see phase)
+        self._pending_launch = _NO_LAUNCH
+        # (planned K, realized steps, windows settled), see note_window
         self._pending_window: tuple[int, int, int] | None = None
-        # overlap dim of the current pass: the in-flight depth its
-        # dispatch launched on top of (2 = double-buffered under
-        # GOFR_ML_PIPELINE — per-dispatch phases no longer tile the wall)
-        self._pending_overlap = 0
-        # device-idle estimate state: settles credit estimated
-        # device-busy seconds to the pass; blocking settles whose
-        # dispatch launched onto an EMPTY device calibrate an EMA of
-        # device seconds per planned step (their launch→settle span IS
-        # the execution time — the device started at launch and the host
-        # blocked until it finished)
+        self._pending_overlap = 0  # see note_overlap
+        # host-idle estimate state, see note_settle
         self._pending_busy = 0.0
         self._pending_settled = 0
         self._exec_ema: float | None = None  # device s per planned step
@@ -155,37 +191,43 @@ class DispatchRecorder:
         self.observer = None
 
     @property
-    def pending(self) -> bool:
-        return bool(self._pending)
-
-    @property
-    def pending_total(self) -> float:
-        """Seconds already attributed in the current pass — callers timing
-        a COMPOSITE section (e.g. the admission wave, whose internal drain
-        notes device_wait/emit itself) subtract the delta so nested notes
-        are never double-counted against the section's own phase."""
-        return sum(self._pending.values())
-
-    @property
     def pending_device_work(self) -> bool:
         """True when the current pass actually touched the device (a
         dispatch, a blocking read-back, or token emission) — the gate for
         the serve loop's tail-flush commit, so idle passes that merely
-        glanced at an empty queue never pollute the dispatch ring."""
+        glanced at an empty queue never pollute the dispatch log."""
         return any(k in self._pending
                    for k in ("launch", "d2h_issue", "device_wait", "emit",
                              "ship", "land"))
 
+    def phase(self, name: str, **meta) -> _Span:
+        """``with rec.phase(name):`` stamps one span of the current pass:
+        start and end on ``perf_counter``, its self time added as
+        ``note()`` would, and a ``gofr.serve.<name>`` profiler annotation
+        (``meta`` its metadata). Spans nest; serving-thread only.
+
+        The ``launch`` of a decode program says what runs, ``kind=``
+        (``chunk``, ``mini``, ``window``, ``spec``, ``specwin``),
+        ``steps=`` and ``rows=`` (the rows producing tokens): they go on
+        the record and, with the ``seq`` the pass will commit under, on
+        the annotation."""
+        if "steps" in meta:
+            steps, rows = int(meta["steps"]), int(meta["rows"])
+            _, steps0, row_steps0 = self._pending_launch
+            self._pending_launch = (meta["kind"], steps0 + steps,
+                                    row_steps0 + rows * steps)
+            meta.update(seq=self.dispatches + 1, steps=steps, rows=rows)
+        return _Span(self, name, meta)
+
     def note(self, phase: str, seconds: float) -> None:
-        """Attribute ``seconds`` of the current pass to ``phase``.
-        Serving-thread only; one dict update, no lock."""
+        """Attribute ``seconds`` of the current pass to ``phase``: the
+        primitive under ``phase()``. Serving-thread only, like every
+        ``note_*``; one dict update, no lock."""
         self._pending[phase] = self._pending.get(phase, 0.0) + seconds
 
     def note_rid(self, rid: str) -> None:
-        """Tag the current pass with a request id it served (burst
-        delivery): the committed record carries the rid set, so forensics
-        can pivot dispatch→requests (journeys carry the other direction).
-        Serving-thread only, like ``note``."""
+        """Tag the current pass with a request id it served: forensics
+        pivot dispatch→requests (journeys carry the other direction)."""
         self._pending_rids.append(rid)
 
     def note_window(self, k: int, realized: int) -> None:
@@ -193,8 +235,7 @@ class DispatchRecorder:
         ``k`` planned device steps, ``realized`` steps the early-exit
         masks actually ran. A pass can settle MORE than one window (the
         double-buffered pipeline drains both at a barrier), so calls
-        accumulate into the committed record. Serving-thread only, like
-        ``note``."""
+        accumulate into the committed record."""
         if self._pending_window is None:
             self._pending_window = (int(k), int(realized), 1)
         else:
@@ -204,8 +245,7 @@ class DispatchRecorder:
     def note_overlap(self, depth: int) -> None:
         """Tag the current pass with the in-flight depth its dispatch
         launched on top of (1 = the classic lag-one pipeline, 2 =
-        double-buffered under GOFR_ML_PIPELINE). The committed record
-        keeps the max over the pass. Serving-thread only, like ``note``."""
+        double-buffered under GOFR_ML_PIPELINE); the record keeps the max."""
         if depth > self._pending_overlap:
             self._pending_overlap = depth
 
@@ -214,13 +254,12 @@ class DispatchRecorder:
         """One in-flight dispatch settled: ``span_s`` seconds from its
         launch to now, ``depth0`` dispatches already outstanding when it
         launched, ``steps`` planned device positions, ``wait_s`` the
-        blocking read-back tail just measured. Feeds the device-idle
+        blocking read-back tail just measured. Feeds the host-idle
         estimate: a settle that actually BLOCKED on a dispatch launched
         onto an empty device pins the execution time exactly (span =
         device run time), calibrating an EMA of device seconds per
         planned step; every settle then credits min(span, max(wait,
-        ema*steps)) estimated device-busy seconds to the current pass.
-        Serving-thread only, like ``note``."""
+        ema*steps)) estimated device-busy seconds to the current pass."""
         if wait_s > 1e-6 and depth0 == 0:
             per = span_s / max(1, steps)
             self._exec_ema = (per if self._exec_ema is None
@@ -234,7 +273,9 @@ class DispatchRecorder:
         """Drop the current pass unrecorded (idle poll: no dispatch to
         attribute the wait to) and re-anchor the wall clock."""
         self._pending.clear()
+        self._spans.clear()
         self._pending_rids.clear()
+        self._pending_launch = _NO_LAUNCH
         self._pending_window = None
         self._pending_overlap = 0
         self._pending_busy = 0.0
@@ -242,7 +283,8 @@ class DispatchRecorder:
         self._anchor = time.perf_counter()
 
     def commit(self) -> None:
-        """Close one dispatch record: phases noted since the last
+        """Close one dispatch record: when the pass ran (``t0``, ``t1``),
+        its spans, what it launched, and the phases noted since the last
         commit/reset plus the unattributed remainder as ``other``, so a
         record's phases always sum to its wall time."""
         now = time.perf_counter()
@@ -251,34 +293,36 @@ class DispatchRecorder:
                 else attributed)
         phases = dict(self._pending)
         phases["other"] = max(0.0, wall - attributed)
-        rec = {"wall_s": wall, "phases": phases}
+        # rows: the mean over the pass's decode steps, so that records sum
+        # to rows x steps whether a pass launched one program or several
+        kind, steps, row_steps = self._pending_launch
+        rows = row_steps / steps if steps else 0
+        if rows == int(rows):
+            rows = int(rows)
+        rec = {"model": self.model, "t0": now - wall, "t1": now,
+               "wall_s": wall, "kind": kind, "steps": steps, "rows": rows,
+               "phases": phases,
+               "spans": [(s.name, s.t0, s.t1) for s in self._spans]}
         if self._pending_rids:
             # stable de-dup (a slot may burst twice in one pass): the
             # record names every request this dispatch served
             rec["rids"] = list(dict.fromkeys(self._pending_rids))
-            self._pending_rids.clear()
         if self._pending_window is not None:
             k, realized, n = self._pending_window
             rec["window"] = {"k": k, "realized": realized, "n": n}
-            self._pending_window = None
         if self._pending_overlap:
             rec["overlap"] = self._pending_overlap
-            self._pending_overlap = 0
         if self._pending_settled:
-            # estimated device-busy seconds the settles of this pass
-            # vouch for — the device-idle share's numerator. Clipped at
-            # wall so a span that began in an earlier pass (the
-            # double-buffered lag) can never claim more than this record
+            # the host-idle estimate's numerator, clipped at wall: a span
+            # begun in an earlier pass never claims more than this record
             rec["busy_s"] = min(self._pending_busy, wall)
-            self._pending_busy = 0.0
-            self._pending_settled = 0
         with self._lock:
             self.dispatches += 1
             rec["seq"] = self.dispatches  # the journey marks' pivot key
-            self._ring.append(rec)
             for name, v in phases.items():
                 self.totals[name] = self.totals.get(name, 0.0) + v
-        self._pending.clear()
+        _DISPATCHES.append(self._key, rec)
+        self.reset()
         self._anchor = now
         obs = self.observer
         if obs is not None:
@@ -297,12 +341,10 @@ class DispatchRecorder:
                 pass  # bare managers in tests: recording stays optional
 
     def tail(self, n: int = 16) -> list[dict]:
-        """The newest ``n`` raw dispatch records (seq, wall, phases, and
-        the rids served) — crash bundles carry these so a postmortem can
-        pivot the victims' journeys onto the exact dispatches that ran
-        them. Safe from any thread."""
-        with self._lock:
-            records = list(self._ring)[-max(0, n):]
+        """The newest ``n`` raw dispatch records — crash bundles carry
+        these so a postmortem can pivot the victims' journeys onto the
+        exact dispatches that ran them. Safe from any thread."""
+        records = _DISPATCHES.records(self._key, min(n, self._rolling))
         return [{**r, "wall_s": round(r["wall_s"], 6),
                  "phases": {k: round(v, 6)
                             for k, v in r["phases"].items()}}
@@ -310,11 +352,11 @@ class DispatchRecorder:
 
     def snapshot(self) -> dict:
         """The ``stalls`` block of ``/debug/serving``: rolling per-phase
-        seconds and share-of-wall over the ring, the top host-side phase
-        by share, and how much of the wall the instrumented phases (i.e.
-        everything but ``other``) actually explained."""
+        seconds and share-of-wall over this recorder's newest records,
+        the top host-side phase by share, and how much of the wall the
+        instrumented phases (all but ``other``) explained."""
+        records = _DISPATCHES.records(self._key, self._rolling)
         with self._lock:
-            records = list(self._ring)
             dispatches = self.dispatches
             totals = {name: round(v, 6)
                       for name, v in self.totals.items() if v > 0.0}
@@ -348,13 +390,11 @@ class DispatchRecorder:
                 "realized_share": (round(realized / planned, 4)
                                    if planned else None),
             }
-        # device-idle estimate over the ring: the settles' estimated
-        # device-busy seconds (launch→settle spans, calibrated by
-        # blocking settles) against the wall — the share of the serving
-        # thread's wall during which the device had nothing to chew on.
-        # An ESTIMATE: prefill dispatches aren't credited, so it reads
-        # high on admission-heavy windows; the pipeline A/B compares
-        # like against like
+        # host-idle estimate: the settles' estimated device-busy seconds
+        # against the wall. An ESTIMATE on the host's clock: prefill
+        # dispatches aren't credited, so it reads high on admission-heavy
+        # windows; the pipeline A/B compares like against like. The
+        # device's own idle share comes from a profiler trace only
         busy = sum(r.get("busy_s", 0.0) for r in records)
         overlapped = sum(1 for r in records if r.get("overlap", 0) >= 2)
         return {
@@ -368,8 +408,8 @@ class DispatchRecorder:
             },
             "top_stall": top,
             "decode_window": decode_window,
-            "device_idle_share": (round(max(0.0, 1.0 - busy / wall), 4)
-                                  if wall > 0 and busy > 0.0 else None),
+            "host_idle_estimate": (round(max(0.0, 1.0 - busy / wall), 4)
+                                   if wall > 0 and busy > 0.0 else None),
             "overlapped_dispatches": overlapped,
             "attributed_share": (round(attributed / wall, 4)
                                  if wall > 0 else None),
@@ -466,6 +506,39 @@ class EventLog:
         """Newest ``n`` events, oldest first (crash-bundle context)."""
         with self._lock:
             return list(self._buf)[-max(0, n):]
+
+
+class DispatchLog:
+    """The process's committed dispatch records, and no live server needed
+    to read them: one bounded ring a recorder behind one lock, so a busy
+    replica never pushes a quiet one's records out. 2,048 records are two
+    minutes at the fastest dispatch rate measured; the rings of the newest
+    16 recorders stay (an elastic fleet's retired replicas roll off)."""
+
+    def __init__(self, per_recorder: int = 2048, recorders: int = 16) -> None:
+        self._rings: dict[int, collections.deque[dict]] = {}
+        self._per_recorder, self._recorders = per_recorder, recorders
+        self._lock = threading.Lock()
+
+    def append(self, key: int, rec: dict) -> None:
+        with self._lock:
+            ring = self._rings.get(key)
+            if ring is None:
+                while len(self._rings) >= self._recorders:
+                    del self._rings[next(iter(self._rings))]  # the oldest
+                ring = self._rings[key] = collections.deque(
+                    maxlen=self._per_recorder)
+            ring.append(rec)
+
+    def records(self, key: int | None = None,
+                last: int | None = None) -> list[dict]:
+        """Every recorder's records, each recorder's oldest first; or one
+        recorder's (``key``) newest ``last``, oldest first."""
+        with self._lock:
+            if key is None:
+                return [r for ring in self._rings.values() for r in ring]
+            newest = itertools.islice(reversed(self._rings.get(key, ())), last)
+            return list(newest)[::-1]
 
 
 class CrashVault:
@@ -805,15 +878,20 @@ class AutoProfiler:
 
 
 # the process-global instances every serving component shares — ONE fleet
-# event stream, ONE crash vault, and ONE profile vault per process, like
-# the metrics registry
+# event stream, ONE dispatch log, ONE crash vault, and ONE profile vault
+# per process, like the metrics registry
 _EVENTS = EventLog()
+_DISPATCHES = DispatchLog()
 _CRASHES = CrashVault()
 _PROFILES = ProfileVault()
 
 
 def event_log() -> EventLog:
     return _EVENTS
+
+
+def dispatch_log() -> DispatchLog:
+    return _DISPATCHES
 
 
 def crash_vault() -> CrashVault:

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..flight_recorder import phase
 from .kv_offload import HostKVStore
 from .programs import ProgramLog, abstractify, watch_compiles
 from .scheduler import TokenBudgetScheduler, maybe_enable_compilation_cache
@@ -264,6 +265,15 @@ class _Slot:
         # as a natural "stop" (ADVICE r4 #4)
         self.evicted = False
         self.callback = None
+
+
+def _jit(name: str, fn, **jit_kwargs):
+    """``jax.jit`` under a stable name: a profiler trace's programs
+    (``jit_<name>``) and jax's compile log say what each one is. Prefill
+    programs hold ``prefill`` and decode programs ``chunk_fn``, the
+    patterns the benchmark's trace readers find them by."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, **jit_kwargs)
 
 
 class Generator:
@@ -573,8 +583,8 @@ class Generator:
         self.fault = None
         # flight recorder (gofr_tpu/flight_recorder.py): the serving layer
         # installs a DispatchRecorder here so step()/drain() can stamp the
-        # decide/dispatch/device_wait/emit phase durations; every site
-        # guards with ``is not None`` — disabled costs one attribute test
+        # decide/launch/device_wait/emit phases (``with phase(rec, …)``:
+        # the shared no-op context while it is None)
         self.recorder = None
         # goodput ledger handle (ml/goodput.py): the serving layer installs
         # a model-bound ModelGoodput here so the spec verify path and the
@@ -806,13 +816,14 @@ class Generator:
         self._post_prefill = jax.jit(post_prefill, donate_argnums=(0,))
         if self.page_size:
             ps = self.page_size
-            self._prefill_paged = jax.jit(
+            self._prefill_paged = _jit(
+                "paged_prefill",
                 lambda p, t, l, c, row, slot: llama.paged_prefill_into(
                     p, t, l, cfg, c, row, slot, ps),
                 donate_argnums=(3,),
             )
 
-            def make_suffix_prefill(set_len: bool):
+            def make_suffix_prefill(name: str, set_len: bool):
                 def f(p, t, l, c, row, start, slot):
                     logits, c2 = llama.paged_suffix_prefill(
                         p, t, l, cfg, c, row, start, ps)
@@ -820,11 +831,13 @@ class Generator:
                         c2 = {**c2,
                               "len": c2["len"].at[slot].set(start + l[0])}
                     return logits, c2
-                return jax.jit(f, donate_argnums=(3,))
+                return _jit(name, f, donate_argnums=(3,))
 
-            self._suffix_prefill = make_suffix_prefill(True)
-            self._prefix_prefill = make_suffix_prefill(False)
-        self._prefill_into = jax.jit(
+            self._suffix_prefill = make_suffix_prefill("suffix_prefill", True)
+            self._prefix_prefill = make_suffix_prefill("prefix_prefill",
+                                                       False)
+        self._prefill_into = _jit(
+            "prefill_into",
             lambda p, t, l, c, slot: llama.prefill_into(p, t, l, cfg, c, slot,
                                                         mesh=mesh),
             donate_argnums=(3,),
@@ -840,19 +853,22 @@ class Generator:
             if self.page_size:
                 ps = self.page_size
 
-                def make_sp_paged(set_len: bool):
+                def make_sp_paged(name: str, set_len: bool):
                     def f(p, t, l, c, row, slot):
                         return llama.paged_prefill_into(
                             p, t, l, sp_cfg, c, row, slot, ps, mesh=mesh,
                             set_len=set_len)
-                    return jax.jit(f, donate_argnums=(3,))
+                    return _jit(name, f, donate_argnums=(3,))
 
-                self._sp_prefill_paged = make_sp_paged(True)
+                self._sp_prefill_paged = make_sp_paged("sp_paged_prefill",
+                                                       True)
                 # prefix builds (register_prefix / the disagg ship path)
                 # fill pages without admitting a slot
-                self._sp_prefix_paged = make_sp_paged(False)
+                self._sp_prefix_paged = make_sp_paged("sp_prefix_prefill",
+                                                      False)
             else:
-                self._sp_prefill_into = jax.jit(
+                self._sp_prefill_into = _jit(
+                    "sp_prefill_into",
                     lambda p, t, l, c, slot: llama.prefill_into(
                         p, t, l, sp_cfg, c, slot, mesh=mesh),
                     donate_argnums=(3,))
@@ -860,16 +876,18 @@ class Generator:
             if self.page_size:
                 ps = self.page_size
 
-                def seg_paged(p, t, l, c, row, start, slot, new_len):
+                def paged_segment_prefill(p, t, l, c, row, start, slot,
+                                          new_len):
                     logits, c2 = llama.paged_suffix_prefill(
                         p, t, l, cfg, c, row, start, ps)
                     return logits, {**c2, "len":
                                     c2["len"].at[slot].set(new_len)}
 
-                self._segment_prefill_paged = jax.jit(seg_paged,
+                self._segment_prefill_paged = jax.jit(paged_segment_prefill,
                                                       donate_argnums=(3,))
             else:
-                self._segment_prefill = jax.jit(
+                self._segment_prefill = _jit(
+                    "segment_prefill",
                     lambda p, t, l, c, slot, start, new_len:
                     llama.prefill_segment_into(p, t, l, cfg, c, slot, start,
                                                new_len, mesh=mesh),
@@ -892,7 +910,8 @@ class Generator:
 
         self._post_prefill_many = jax.jit(post_prefill_many,
                                           donate_argnums=(0,))
-        self._prefill_many = jax.jit(
+        self._prefill_many = _jit(
+            "prefill_many",
             lambda p, t, l, c, slots, valid: llama.prefill_into_many(
                 p, t, l, cfg, c, slots, valid, mesh=mesh),
             donate_argnums=(3,),
@@ -1270,11 +1289,13 @@ class Generator:
             # the draft must ingest every admitted prompt too: its cache
             # rows are the drafting context (same buckets as the target
             # prefill, so warmup compiles both together)
-            self._draft_prefill_into = jax.jit(
+            self._draft_prefill_into = _jit(
+                "draft_prefill_into",
                 lambda p, t, l, c, s: llama.prefill_into(
                     p, t, l, draft_cfg, c, s),
                 donate_argnums=(3,))
-            self._draft_prefill_many = jax.jit(
+            self._draft_prefill_many = _jit(
+                "draft_prefill_many",
                 lambda p, t, l, c, s, v: llama.prefill_into_many(
                     p, t, l, draft_cfg, c, s, v),
                 donate_argnums=(3,))
@@ -1830,8 +1851,8 @@ class Generator:
         jit with replicated out_shardings under multi-controller (an eager
         array would be process-local), plain eager zeros otherwise."""
         if self._repl is not None:
-            return jax.jit(lambda: jnp.zeros(shape, jnp.int32),
-                           out_shardings=self._repl)()
+            return _jit("init_tokens", lambda: jnp.zeros(shape, jnp.int32),
+                        out_shardings=self._repl)()
         with jax.default_device(self._device):
             return jnp.zeros(shape, jnp.int32)
 
@@ -1922,7 +1943,8 @@ class Generator:
             from ..parallel import NamedSharding
 
             specs = self._serving_cache_specs()
-            self.cache = jax.jit(
+            self.cache = _jit(
+                "init_cache",
                 lambda: llama.init_cache(cfg, self.batch_slots, self.max_seq),
                 out_shardings={
                     key: NamedSharding(self.mesh, s)
@@ -2301,24 +2323,27 @@ class Generator:
         tokens/shards: each shard sweeps only its slice of the prompt.
         Callers hold the mesh context."""
         sp = self._sp
-        rec = self.recorder
-        t0 = time.perf_counter()
         try:
-            if self.fault is not None:
-                self.fault("sp_prefill")
-            if prefix:
-                logits, self.cache = self._sp_prefix_paged(
-                    self.params, tokens, lens, self.cache, row,
-                    np.int32(slot))
-            elif self.page_size:
-                logits, self.cache = self._sp_prefill_paged(
-                    self.params, tokens, lens, self.cache, row,
-                    np.int32(slot))
-            else:
-                logits, self.cache = self._sp_prefill_into(
-                    self.params, tokens, lens, self.cache, np.int32(slot))
-            if self.fault is not None:
-                self.fault("sp_gather")
+            # its own phase label: an SP wave is neither a plain assemble
+            # nor a decode launch, and the stall attribution must name it
+            # when long prompts dominate a dispatch
+            with phase(self.recorder, "sp_prefill"):
+                if self.fault is not None:
+                    self.fault("sp_prefill")
+                if prefix:
+                    logits, self.cache = self._sp_prefix_paged(
+                        self.params, tokens, lens, self.cache, row,
+                        np.int32(slot))
+                elif self.page_size:
+                    logits, self.cache = self._sp_prefill_paged(
+                        self.params, tokens, lens, self.cache, row,
+                        np.int32(slot))
+                else:
+                    logits, self.cache = self._sp_prefill_into(
+                        self.params, tokens, lens, self.cache,
+                        np.int32(slot))
+                if self.fault is not None:
+                    self.fault("sp_gather")
         except Exception as exc:
             if any(getattr(leaf, "is_deleted", lambda: False)()
                    for leaf in jax.tree_util.tree_leaves(self.cache)):
@@ -2332,11 +2357,6 @@ class Generator:
         self.sp_tokens += int(lens[0])
         if self.scheduler is not None:
             self.scheduler.charge_sp(-(-int(lens[0]) // sp.shards))
-        if rec is not None:
-            # its own phase label: an SP wave is neither a plain
-            # assemble nor a decode launch, and the stall attribution
-            # must name it when long prompts dominate a dispatch
-            rec.note("sp_prefill", time.perf_counter() - t0)
         return logits
 
     def sp_stats(self) -> dict | None:
@@ -2891,18 +2911,14 @@ class Generator:
         unit = (self.spec_k + 1) if use_spec else 1
         n_steps = self.chunk
         if sched is not None:
-            t0 = time.perf_counter() if rec is not None else 0.0
-            n_steps, n_segments = sched.plan(self._n_decodable(),
-                                             bool(self._chunked), unit)
-            if rec is not None:
-                rec.note("decide", time.perf_counter() - t0)
+            with phase(rec, "decide"):
+                n_steps, n_segments = sched.plan(self._n_decodable(),
+                                                 bool(self._chunked), unit)
         if self._chunked:
             # segmented prefill rides the same device queue as the decode
             # chunk — its program-launch cost is launch time of this pass
-            t0 = time.perf_counter() if rec is not None else 0.0
-            self._advance_chunked(n_segments if sched is not None else 1)
-            if rec is not None:
-                rec.note("launch", time.perf_counter() - t0)
+            with phase(rec, "launch"):
+                self._advance_chunked(n_segments if sched is not None else 1)
             if not self._decodable():
                 return  # everything live is still mid-prefill
         # Pending first tokens -> ONE 1-step mini-chunk so they surface a
@@ -2944,105 +2960,88 @@ class Generator:
                 # row, so it must always dispatch.
                 self.drain()
                 return
-        t_asm = time.perf_counter() if rec is not None else 0.0
+        spec = bool(self.spec_k and use_spec)
+        kind = (("specwin" if spec else "window") if win
+                else "spec" if spec else "chunk")
         with self._mesh_ctx():
             if self.page_size:
                 # page growth + the (cached) table upload are host-side
                 # batch ASSEMBLY, not program launch — split out so the
                 # launch number names only the dispatch machinery
-                self._grow_pages()
-                table = self._table_device()
-                if rec is not None:
-                    rec.note("assemble", time.perf_counter() - t_asm)
-            t_launch = time.perf_counter() if rec is not None else 0.0
-            if win and self.spec_k and use_spec:
-                (row0, emits, counts, realized, self._tok_dev, self.cache,
-                 self._tokens_dev, self._draft_cache) = fn(
-                    self.params, self._tok_dev, self.cache,
-                    self._tokens_dev, self._draft_cache, spec_mask,
-                    active0, step_cap, table)
-                kind = "specwin"
-                item: Any = (row0, emits, counts, realized)
-                meta: Any = (n_steps, active0, spec_mask)
-            elif win:
-                (block, n_out, realized, self._tok_dev, self.cache) = fn(
-                    self.params, self._tok_dev, self.cache,
-                    np.int32(self.steps), self._base_key, active0,
-                    step_cap, table)
-                kind = "window"
-                item = (block, n_out, realized)
-                meta = (n_steps, active0)
-            elif self.spec_k and use_spec:
-                if self.page_size:
+                with phase(rec, "assemble"):
+                    self._grow_pages()
+                    table = self._table_device()
+            # the record and the launch annotation say what runs: the
+            # program's kind, its decode steps, the rows producing tokens
+            with phase(rec, "launch", kind="mini" if mini else kind,
+                       steps=n_steps, rows=self._n_decodable()):
+                if kind == "specwin":
+                    (row0, emits, counts, realized, self._tok_dev,
+                     self.cache, self._tokens_dev, self._draft_cache) = fn(
+                        self.params, self._tok_dev, self.cache,
+                        self._tokens_dev, self._draft_cache, spec_mask,
+                        active0, step_cap, table)
+                    item: Any = (row0, emits, counts, realized)
+                    meta: Any = (n_steps, active0, spec_mask)
+                elif kind == "window":
+                    (block, n_out, realized, self._tok_dev,
+                     self.cache) = fn(
+                        self.params, self._tok_dev, self.cache,
+                        np.int32(self.steps), self._base_key, active0,
+                        step_cap, table)
+                    item = (block, n_out, realized)
+                    meta = (n_steps, active0)
+                elif kind == "spec":
                     (row0, emits, counts, self._tok_dev, self.cache,
                      self._tokens_dev, self._draft_cache) = fn(
                         self.params, self._tok_dev, self.cache,
                         self._tokens_dev, self._draft_cache, spec_mask,
-                        table)
+                        *((table,) if self.page_size else ()))
+                    item = (row0, emits, counts)
+                    meta = spec_mask
                 else:
-                    (row0, emits, counts, self._tok_dev, self.cache,
-                     self._tokens_dev, self._draft_cache) = fn(
+                    item, self._tok_dev, self.cache = fn(
                         self.params, self._tok_dev, self.cache,
-                        self._tokens_dev, self._draft_cache, spec_mask)
-                kind = "spec"
-                item = (row0, emits, counts)
-                meta = spec_mask
-            elif self.page_size:
-                toks, self._tok_dev, self.cache = fn(
-                    self.params, self._tok_dev, self.cache,
-                    np.int32(self.steps), self._base_key, table,
-                )
-                kind, item, meta = "chunk", toks, None
-            else:
-                toks, self._tok_dev, self.cache = fn(
-                    self.params, self._tok_dev, self.cache,
-                    np.int32(self.steps), self._base_key,
-                )
-                kind, item, meta = "chunk", toks, None
-        self.steps += n_steps
-        if self.spec_k and not use_spec:
-            # a plain dispatch leaves the device drafting rows behind the
-            # host mirror; repair before the next spec dispatch
-            self._spec_rows_stale = True
-        if rec is not None:
-            rec.note("launch", time.perf_counter() - t_launch)
-        t_d2h = time.perf_counter() if rec is not None else 0.0
-        try:
-            # best-effort prefetch; where this is itself a blocking
-            # transfer the cost is the same as the np.asarray in _process,
-            # so it stays — the pipeline depth below is what keeps the
-            # device busy while the host reads.
-            for arr in (item if isinstance(item, tuple) else (item,)):
-                arr.copy_to_host_async()
-        except Exception as exc:
-            # losing the prefetch only costs latency (the blocking asarray
-            # in _process still lands the tokens), but a transport whose
-            # prefetch path broke should be visible, not silent: count
-            # every failure, log the first once per generator
-            self.prefetch_errors += 1
-            if not self._prefetch_warned:
-                self._prefetch_warned = True
-                _log.debug(
-                    "token prefetch (copy_to_host_async) failed; falling "
-                    "back to blocking reads [%s: %s]",
-                    type(exc).__name__, exc)
+                        np.int32(self.steps), self._base_key,
+                        *((table,) if self.page_size else ()))
+                    meta = None
+                self.steps += n_steps
+                if self.spec_k and not use_spec:
+                    # a plain dispatch leaves the device drafting rows
+                    # behind the host mirror; repair before the next spec
+                    # dispatch
+                    self._spec_rows_stale = True
+        # issuing the async D2H of the token block — the other half of
+        # what used to be one "dispatch" phase (the blocking read-back is
+        # device_wait, in _pop_process)
+        with phase(rec, "d2h_issue") as issue:
+            try:
+                # best-effort prefetch; where this is itself a blocking
+                # transfer the cost is the same as the np.asarray in
+                # _pop_process — the pipeline depth below is what keeps
+                # the device busy while the host reads.
+                for arr in (item if isinstance(item, tuple) else (item,)):
+                    arr.copy_to_host_async()
+            except Exception as exc:
+                # losing the prefetch only costs latency, but a transport
+                # whose prefetch path broke should be visible: count
+                # every failure, log the first once per generator
+                self.prefetch_errors += 1
+                if not self._prefetch_warned:
+                    self._prefetch_warned = True
+                    _log.debug(
+                        "token prefetch (copy_to_host_async) failed; "
+                        "falling back to blocking reads [%s: %s]",
+                        type(exc).__name__, exc)
         stamp = None
         if rec is not None:
-            # launch stamp for the overlap accounting: when this dispatch
-            # was issued, how many dispatches were already outstanding,
-            # and its planned device positions — settled back into the
-            # recorder's device-idle estimate in _pop_process
-            stamp = (t_d2h, len(self._inflight), n_steps * unit)
+            # launch stamp: when this dispatch was issued, how many were
+            # already outstanding (the record's ``overlap`` dim) and its
+            # planned device positions — settled back into the recorder's
+            # host-idle estimate in _pop_process
+            stamp = (issue.t0, len(self._inflight), n_steps * unit)
+            rec.note_overlap(len(self._inflight))
         self._inflight.append((kind, item, meta, stamp))
-        if rec is not None:
-            # issuing the async D2H of the token block — the other half of
-            # what used to be one "dispatch" phase (the blocking read-back
-            # is device_wait, in _pop_process)
-            rec.note("d2h_issue", time.perf_counter() - t_d2h)
-            # the record's ``overlap`` dim: how many in-flight dispatches
-            # this launch rode on top of (1 = the classic lag-one
-            # pipeline, 2 = double-buffered under GOFR_ML_PIPELINE)
-            rec.note_overlap(len(self._inflight) - 1)
         if mini:
             # TTFT: the chunk carrying new requests' first tokens is read
             # back NOW instead of lagging one dispatch — one blocking
@@ -3068,43 +3067,31 @@ class Generator:
             self._pop_process()
 
     def _pop_process(self) -> None:
+        """Settle the oldest dispatch in flight: the blocking read-back is
+        ``device_wait``, and its launch stamp (when the recorder was armed
+        at launch) feeds the launch→settle span into the recorder's
+        host-idle estimate."""
         kind, item, meta, stamp = self._inflight.popleft()
         rec = self.recorder
-        t0 = time.perf_counter() if rec is not None else 0.0
+        with phase(rec, "device_wait") as wait:
+            host = (np.asarray(item) if kind == "chunk"
+                    else tuple(np.asarray(x) for x in item))
+        if rec is not None and stamp is not None:
+            t_launch, depth0, steps = stamp
+            rec.note_settle(wait.t1 - t_launch, depth0, steps,
+                            wait.t1 - wait.t0)
         if kind == "chunk":
-            toks = np.asarray(item)
-            if rec is not None:
-                self._note_settle(rec, stamp, t0)
-            self._process(toks)
+            self._process(host)
         elif kind == "spec":
-            row0, emits, counts = (np.asarray(x) for x in item)
-            if rec is not None:
-                self._note_settle(rec, stamp, t0)
-            self._process_spec(row0, emits, counts, meta)
+            self._process_spec(*host, meta)
         elif kind == "window":
-            block, n_out, realized = (np.asarray(x) for x in item)
-            if rec is not None:
-                self._note_settle(rec, stamp, t0)
+            block, n_out, realized = host
             self._process_window(block, n_out, int(realized), meta)
         else:  # "specwin"
-            row0, emits, counts, realized = (np.asarray(x) for x in item)
-            if rec is not None:
-                self._note_settle(rec, stamp, t0)
+            row0, emits, counts, realized = host
             planned, active0, mask = meta
             self._process_spec(row0, emits, counts, mask, planned=planned,
                                active0=active0, realized_w=int(realized))
-
-    @staticmethod
-    def _note_settle(rec, stamp, t0: float) -> None:
-        """Close the books on one settled dispatch: the blocking read-back
-        is ``device_wait``, and the launch stamp (when the recorder was
-        armed at launch) feeds the recorder's launch→settle span into its
-        device-idle estimate."""
-        now = time.perf_counter()
-        rec.note("device_wait", now - t0)
-        if stamp is not None:
-            t_launch, depth0, steps = stamp
-            rec.note_settle(now - t_launch, depth0, steps, now - t0)
 
     def _apply_burst(self, i: int, s: _Slot, col: np.ndarray,
                      bursts: dict) -> int:
@@ -3381,19 +3368,20 @@ class Generator:
         """Double-buffer block for /debug/serving (None when
         GOFR_ML_PIPELINE is off): the depth, how many passes actually
         ended with two dispatches outstanding, the speculative
-        re-dispatch bill, and the flight recorder's device-idle estimate
-        (None when the recorder is off)."""
+        re-dispatch bill, and the flight recorder's estimate of the
+        device's idle share from the host's clock (None when the recorder
+        is off)."""
         if not self.pipeline:
             return None
         idle = None
         rec = self.recorder
         if rec is not None:
-            idle = rec.snapshot().get("device_idle_share")
+            idle = rec.snapshot().get("host_idle_estimate")
         return {
             "depth": 2,
             "windows_overlapped": self.pipeline_windows,
             "overshoot_tokens": self.pipeline_overshoot,
-            "device_idle_share": idle,
+            "host_idle_estimate": idle,
         }
 
     def _process(self, toks: np.ndarray) -> None:
@@ -3426,14 +3414,13 @@ class Generator:
         """Deliver each slot's token burst to its callback — the emit
         phase of the dispatch breakdown (in the serving stack every call
         is a ``call_soon_threadsafe`` wakeup of the consumer's loop)."""
-        rec = self.recorder
-        t0 = time.perf_counter() if rec is not None and bursts else 0.0
-        for i, burst in bursts.items():
-            cb = self.slots[i].callback
-            if cb is not None:
-                cb(i, burst)
-        if rec is not None and bursts:
-            rec.note("emit", time.perf_counter() - t0)
+        if not bursts:
+            return
+        with phase(self.recorder, "emit"):
+            for i, burst in bursts.items():
+                cb = self.slots[i].callback
+                if cb is not None:
+                    cb(i, burst)
 
     def release(self, i: int) -> None:
         """Return a finished slot to the free pool (its tokens are consumed)."""

@@ -44,7 +44,7 @@ from ..tracing import current_context
 from .capture import sampler_snapshot, traffic_capture
 from .errors import (DeadlineExceeded, GeneratorCrashed, Overloaded,
                      ServerClosed)
-from ..flight_recorder import (AutoProfiler, DispatchRecorder,
+from ..flight_recorder import (AutoProfiler, DispatchRecorder, phase,
                               autoprof_enabled, crash_vault, event_log,
                               recorder_enabled)
 from .generate import PagePoolExhausted, PrefixEvicted
@@ -351,6 +351,8 @@ class LLMServer:
             self._flush_on_close()
 
     def _serve(self) -> None:
+        if self.recorder is not None:
+            self.recorder.reset()  # the first pass starts here
         while not self._closed:
             # WATCHDOG: every device dispatch this pass makes (step, drain,
             # batched/chunked/suffix prefill, offload spill/restore) plus
@@ -363,19 +365,11 @@ class LLMServer:
             try:
                 self._run_setup_tasks()
                 self._reap_cancelled()
-                if rec is not None:
-                    # assemble: admission-wave work — validation, radix
-                    # split, batch build, and the prefill dispatches.
-                    # _admit_waiting's internal gen.drain() notes its own
-                    # device_wait/emit; subtract that nested share so the
-                    # record's phases still sum to (not past) its wall
-                    t0 = time.perf_counter()
-                    nested0 = rec.pending_total
-                    self._admit_waiting()
-                    nested = rec.pending_total - nested0
-                    rec.note("assemble", max(
-                        0.0, time.perf_counter() - t0 - nested))
-                else:
+                # assemble: admission-wave work — validation, radix
+                # split, batch build, and the prefill dispatches. The
+                # device_wait/emit of _admit_waiting's internal
+                # gen.drain() nest inside and come off its self time
+                with phase(rec, "assemble"):
                     self._admit_waiting()
                 if self._closed:
                     return
@@ -410,49 +404,53 @@ class LLMServer:
                     # a phantom "other" stall
                     rec.reset()
                 continue
-            t_pop = time.perf_counter()
-            try:  # idle: block briefly for the next request, backing
-                # off toward 50 ms so an idle server doesn't spin at
-                # hundreds of wakeups/s (admission latency cost is at
-                # most one backoff interval, well under a prefill)
-                req = self._requests.get(timeout=self._idle_backoff)
-            except _queue.Empty:
-                # floor keeps idle_wait_s=0 from spinning; ceiling never
-                # clamps below a caller's own (larger) configured wait
-                self._idle_backoff = min(
-                    max(self._idle_backoff * 2, 0.001),
-                    max(0.05, self._idle_wait),
-                )
-                if rec is not None:
-                    # pure idle: nothing arrived, no dispatch to charge
-                    # the wait to — drop the pass from the attribution
-                    rec.reset()
-                continue
-            self._idle_backoff = self._idle_wait
-            if req is None:
+            # queue pop: blocking for the arrival that wakes us plus the
+            # burst-collection window before the admission wave
+            with phase(rec, "queue_pop"):
+                arrived = self._await_burst()
+            if arrived is None:
                 return
-            self._enqueue_waiting(req)
-            # collect the rest of the burst before admitting: concurrent
-            # clients arrive over a few ms, and one wave (one batched
-            # prefill + one mini-chunk) gives every stream the first
-            # wave's TTFT instead of the second's
-            deadline = time.perf_counter() + self._admit_window
-            while True:
-                remaining = deadline - time.perf_counter()
-                if remaining <= 0:
-                    break
-                try:
-                    more = self._requests.get(timeout=remaining)
-                except _queue.Empty:
-                    break
-                if more is None:
-                    self._closed = True
-                    return
-                self._enqueue_waiting(more)
-            if rec is not None:
-                # queue pop: blocking for the arrival that woke us plus
-                # the burst-collection window before the admission wave
-                rec.note("queue_pop", time.perf_counter() - t_pop)
+            if not arrived and rec is not None:
+                # pure idle: nothing arrived, no dispatch to charge
+                # the wait to — drop the pass from the attribution
+                rec.reset()
+
+    def _await_burst(self) -> bool | None:
+        """Block briefly for the next request, then collect the rest of
+        its burst. False when nothing arrived, None at the close."""
+        try:  # idle: backing off toward 50 ms so an idle server doesn't
+            # spin at hundreds of wakeups/s (admission latency cost is at
+            # most one backoff interval, well under a prefill)
+            req = self._requests.get(timeout=self._idle_backoff)
+        except _queue.Empty:
+            # floor keeps idle_wait_s=0 from spinning; ceiling never
+            # clamps below a caller's own (larger) configured wait
+            self._idle_backoff = min(
+                max(self._idle_backoff * 2, 0.001),
+                max(0.05, self._idle_wait),
+            )
+            return False
+        self._idle_backoff = self._idle_wait
+        if req is None:
+            return None
+        self._enqueue_waiting(req)
+        # collect the rest of the burst before admitting: concurrent
+        # clients arrive over a few ms, and one wave (one batched
+        # prefill + one mini-chunk) gives every stream the first
+        # wave's TTFT instead of the second's
+        deadline = time.perf_counter() + self._admit_window
+        while True:
+            remaining = deadline - time.perf_counter()
+            if remaining <= 0:
+                return True
+            try:
+                more = self._requests.get(timeout=remaining)
+            except _queue.Empty:
+                return True
+            if more is None:
+                self._closed = True
+                return None
+            self._enqueue_waiting(more)
 
     def _run_setup_tasks(self) -> None:
         """Drain device-touching setup work (e.g. register_prefix) onto
@@ -544,26 +542,24 @@ class LLMServer:
                     or getattr(gen, "host_kv", None) is None:
                 return None
             ids = tuple(int(t) for t in prefix_ids)
-            t0 = time.perf_counter()
-            try:
-                pid = gen.register_prefix(ids)
-            except (PagePoolExhausted, ValueError):
-                return None  # pool too tight / shape-impossible: fall back
-            try:
-                spilled = gen.drop_prefix(pid, spill=True)
-            except Exception:
-                # the spill path failed mid-handoff (e.g. an armed
-                # ``spill`` fault): the registration is still idle
-                # device-side — discard it so its pages return to the
-                # pool instead of parking until a reclaim pass
-                if gen.has_prefix(pid):
-                    gen.drop_prefix(pid)
-                raise
-            entry = gen.host_kv.take(ids) if spilled else None
-            if self._fault is not None:
-                self._fault("ship")  # chaos: pages lost mid-handoff
-            if self.recorder is not None:
-                self.recorder.note("ship", time.perf_counter() - t0)
+            with phase(self.recorder, "ship"):
+                try:
+                    pid = gen.register_prefix(ids)
+                except (PagePoolExhausted, ValueError):
+                    return None  # pool too tight / shape-impossible
+                try:
+                    spilled = gen.drop_prefix(pid, spill=True)
+                except Exception:
+                    # the spill path failed mid-handoff (e.g. an armed
+                    # ``spill`` fault): the registration is still idle
+                    # device-side — discard it so its pages return to the
+                    # pool instead of parking until a reclaim pass
+                    if gen.has_prefix(pid):
+                        gen.drop_prefix(pid)
+                    raise
+                entry = gen.host_kv.take(ids) if spilled else None
+                if self._fault is not None:
+                    self._fault("ship")  # chaos: pages lost mid-handoff
             if entry is None:
                 return None
             return ids, entry[0], entry[1]
@@ -591,27 +587,25 @@ class LLMServer:
                     or getattr(gen, "host_kv", None) is None:
                 return None
             ids = tuple(int(t) for t in prefix_ids)
-            t0 = time.perf_counter()
-            if self._fault is not None:
-                self._fault("migrate")  # chaos: export lost mid-handoff
-            if pid is not None and gen.has_prefix(pid):
-                info = gen._prefixes[pid]
-                if info["refs"] > 0:
-                    return None  # borrowed: drains with its slots
-                key = tuple(int(t) for t in info["ids_full"])
-                spilled = gen.drop_prefix(pid, spill=True)
+            with phase(self.recorder, "ship"):
+                if self._fault is not None:
+                    self._fault("migrate")  # chaos: export lost mid-handoff
+                if pid is not None and gen.has_prefix(pid):
+                    info = gen._prefixes[pid]
+                    if info["refs"] > 0:
+                        return None  # borrowed: drains with its slots
+                    key = tuple(int(t) for t in info["ids_full"])
+                    spilled = gen.drop_prefix(pid, spill=True)
+                    if self.prefix_cache is not None:
+                        # registered → offloaded in the trie bookkeeping
+                        # (cleared again below once the entry leaves)
+                        self.prefix_cache.invalidate(pid)
+                    if not spilled:
+                        return None  # host budget rejected it: discarded
+                    ids = key
+                entry = gen.host_kv.take(ids)
                 if self.prefix_cache is not None:
-                    # registered → offloaded in the trie bookkeeping
-                    # (cleared again below once the entry leaves)
-                    self.prefix_cache.invalidate(pid)
-                if not spilled:
-                    return None  # host budget rejected it: discarded
-                ids = key
-            entry = gen.host_kv.take(ids)
-            if self.prefix_cache is not None:
-                self.prefix_cache.forget_offloaded(ids)
-            if self.recorder is not None:
-                self.recorder.note("ship", time.perf_counter() - t0)
+                    self.prefix_cache.forget_offloaded(ids)
             if entry is None:
                 return None
             return ids, entry[0], entry[1]
@@ -634,14 +628,12 @@ class LLMServer:
             if getattr(gen, "host_kv", None) is None:
                 return False
             ids = tuple(int(t) for t in key)
-            t0 = time.perf_counter()
-            if self._fault is not None:
-                self._fault("land")  # chaos: arrival dropped on the floor
-            ok = gen.host_kv.receive(ids, arrays, dict(meta))
-            if ok and self.prefix_cache is not None:
-                self.prefix_cache.adopt_offloaded(ids)
-            if self.recorder is not None:
-                self.recorder.note("land", time.perf_counter() - t0)
+            with phase(self.recorder, "land"):
+                if self._fault is not None:
+                    self._fault("land")  # chaos: arrival dropped
+                ok = gen.host_kv.receive(ids, arrays, dict(meta))
+                if ok and self.prefix_cache is not None:
+                    self.prefix_cache.adopt_offloaded(ids)
             return ok
 
         return self._run_on_serving(work, timeout_s, "import_prefix_kv")

@@ -24,6 +24,12 @@ restart?". This module is the shared recording machinery:
   persistent cache" (``scheduler.maybe_enable_compilation_cache``) vs "already in
   the in-process jit cache" becomes a per-row fact instead of folklore.
 
+Outside a window the same listeners count a **late compile**: whatever
+jax compiles after warm-up, on the serving thread or any other, adds to a
+process-wide count and emits one ``compile`` event into the fleet event
+log (seconds, persistent-cache hit or miss, thread name), so
+``/debug/events?kind=compile`` answers which dispatch recompiled.
+
 Aggregates export as ``app_ml_compile_seconds_total`` /
 ``app_ml_compile_cache_hits_total`` counters and the ``app_ml_programs``
 gauge (the sampler pass publishes deltas per model); the full inventory
@@ -39,7 +45,7 @@ import contextlib
 import threading
 import time
 
-__all__ = ["ProgramLog", "watch_compiles", "abstractify"]
+__all__ = ["ProgramLog", "watch_compiles", "abstractify", "late_compiles"]
 
 # thread-local compile-attribution window (one level deep: program
 # compiles never nest across our record sites)
@@ -52,11 +58,35 @@ _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
 
+# compiles no watch_compiles window claimed, process-wide
+_late = {"compiles": 0, "seconds": 0.0}
+
+
+def late_compiles() -> dict:
+    """Count and seconds of the compiles that ran outside every
+    ``watch_compiles`` window since the listeners were installed."""
+    with _install_lock:
+        return dict(_late)
+
+
+def _note_late(secs: float, cache: str) -> None:
+    from ..flight_recorder import event_log
+
+    with _install_lock:
+        _late["compiles"] += 1
+        _late["seconds"] += secs
+    event_log().emit("compile", seconds=round(secs, 6), cache=cache,
+                     thread=threading.current_thread().name)
+
+
 def _ensure_listeners() -> bool:
-    """Install the process-wide jax monitoring listeners once. The
-    listeners are no-ops (one thread-local getattr) outside a
-    ``watch_compiles`` window, so they cost nothing on unrelated
-    compiles. False when jax's monitoring API is unavailable."""
+    """Install the process-wide jax monitoring listeners once. Outside a
+    ``watch_compiles`` window they cost one thread-local getattr per
+    event, and a compile there is a late one (``_note_late``), counted
+    once at its ``backend_compile_duration``: jax times the whole
+    compile-or-load under that event, and a load from the persistent
+    cache says ``cache_hits`` on the same thread just before it. False
+    when jax's monitoring API is unavailable."""
     global _installed
     with _install_lock:
         if _installed:
@@ -65,16 +95,22 @@ def _ensure_listeners() -> bool:
             import jax.monitoring as mon
 
             def on_duration(name: str, secs: float, **kw) -> None:
+                if name != _COMPILE_DURATION_EVENT:
+                    return
                 acc = getattr(_local, "acc", None)
-                if acc is not None and name == _COMPILE_DURATION_EVENT:
+                if acc is None:
+                    hit = _local.__dict__.pop("late_hit", False)
+                    _note_late(secs, "hit" if hit else "miss")
+                else:
                     acc["backend_compile_s"] += secs
                     acc["compiles"] += 1
 
             def on_event(name: str, **kw) -> None:
                 acc = getattr(_local, "acc", None)
                 if acc is None:
-                    return
-                if name == _CACHE_HIT_EVENT:
+                    if name == _CACHE_HIT_EVENT:
+                        _local.late_hit = True  # read by on_duration
+                elif name == _CACHE_HIT_EVENT:
                     acc["cache_hits"] += 1
                 elif name == _CACHE_MISS_EVENT:
                     acc["cache_misses"] += 1
@@ -237,4 +273,7 @@ class ProgramLog:
                 "compile_s": round(self.compile_s_total, 6),
                 "backend_compile_s": round(self.backend_compile_s_total, 6),
                 "cache_hits": self.cache_hits_total,
+                # process-wide, not this owner's: a compile after warm-up
+                # belongs to no recorded program
+                "late_compiles": _late["compiles"],
             }

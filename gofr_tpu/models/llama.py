@@ -444,35 +444,37 @@ def _layer(cfg: LlamaConfig, x, lp, cos, sin, *, kv_len=None, full_seq=True,
     b, s, _ = x.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = _mm(h, lp["wq"]).reshape(b, s, H, hd)
-    k = _mm(h, lp["wk"]).reshape(b, s, KV, hd)
-    v = _mm(h, lp["wv"]).reshape(b, s, KV, hd)
-    q = constrain(q, P("dp", None, "tp", None))
-    k = constrain(k, P("dp", None, "tp", None))
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    with jax.named_scope("attention"):
+        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        q = _mm(h, lp["wq"]).reshape(b, s, H, hd)
+        k = _mm(h, lp["wk"]).reshape(b, s, KV, hd)
+        v = _mm(h, lp["wv"]).reshape(b, s, KV, hd)
+        q = constrain(q, P("dp", None, "tp", None))
+        k = constrain(k, P("dp", None, "tp", None))
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
 
-    kf, vf = repeat_kv(k, cfg.n_rep), repeat_kv(v, cfg.n_rep)
-    if cfg.sequence_parallel and mesh is not None:
-        # long-context: exact sequence-parallel attention over sp — K/V
-        # blocks never leave their shard (ring) or reshard once (ulysses)
-        from ..parallel.ring import ring_attention
-        from ..parallel.ulysses import ulysses_attention
+        kf, vf = repeat_kv(k, cfg.n_rep), repeat_kv(v, cfg.n_rep)
+        if cfg.sequence_parallel and mesh is not None:
+            # long-context: exact sequence-parallel attention over sp — K/V
+            # blocks never leave their shard (ring) or reshard once (ulysses)
+            from ..parallel.ring import ring_attention
+            from ..parallel.ulysses import ulysses_attention
 
-        sp_attn = (ring_attention if cfg.attn_impl == "ring"
-                   else ulysses_attention)
-        o = sp_attn(q, kf, vf, mesh, kv_len=kv_len, causal=True)
-    elif cfg.use_flash:
-        o = flash_attention(q, kf, vf, causal=True, kv_len=kv_len)
-    else:
-        o = attention(q, kf, vf, causal=True, kv_len=kv_len)
+            sp_attn = (ring_attention if cfg.attn_impl == "ring"
+                       else ulysses_attention)
+            o = sp_attn(q, kf, vf, mesh, kv_len=kv_len, causal=True)
+        elif cfg.use_flash:
+            o = flash_attention(q, kf, vf, causal=True, kv_len=kv_len)
+        else:
+            o = attention(q, kf, vf, causal=True, kv_len=kv_len)
 
-    o = o.reshape(b, s, H * hd)
-    x = x + constrain(_mm(o, lp["wo"]), P("dp", "sp", None))
+        o = o.reshape(b, s, H * hd)
+        x = x + constrain(_mm(o, lp["wo"]), P("dp", "sp", None))
 
-    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    x = x + constrain(_swiglu(h, lp), P("dp", "sp", None))
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+        x = x + constrain(_swiglu(h, lp), P("dp", "sp", None))
     return x, k, v
 
 
@@ -491,64 +493,66 @@ def _decode_layer(cfg: LlamaConfig, x, lp, cos, sin, arrays, layer,
     b = x.shape[0]
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = _mm(h, lp["wq"]).reshape(b, 1, H, hd)
-    k = _mm(h, lp["wk"]).reshape(b, 1, KV, hd)
-    v = _mm(h, lp["wv"]).reshape(b, 1, KV, hd)
-    q = constrain(q, P("dp", None, "tp", None))
-    k = constrain(k, P("dp", None, "tp", None))
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    with jax.named_scope("attention"):
+        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        q = _mm(h, lp["wq"]).reshape(b, 1, H, hd)
+        k = _mm(h, lp["wk"]).reshape(b, 1, KV, hd)
+        v = _mm(h, lp["wv"]).reshape(b, 1, KV, hd)
+        q = constrain(q, P("dp", None, "tp", None))
+        k = constrain(k, P("dp", None, "tp", None))
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
 
-    if cfg.kv_quant:
-        from ..ops import quantize_kv
+        if cfg.kv_quant:
+            from ..ops import quantize_kv
 
-        kq, k_sc = quantize_kv(k[:, 0])
-        vq, v_sc = quantize_kv(v[:, 0])
-        # int8 values scatter flat ([B, KV*D] rows); scales are
-        # [L, B, KV, S]: scatter the [B, KV] token scales at each row's
-        # position via full advanced indexing
-        kv_idx = jnp.arange(KV)[None, :]
-        arrays = {
-            "k": arrays["k"].at[layer, rows, pos].set(kq.reshape(b, KV * hd)),
-            "v": arrays["v"].at[layer, rows, pos].set(vq.reshape(b, KV * hd)),
-            "k_scale": arrays["k_scale"].at[
-                layer, rows[:, None], kv_idx, pos[:, None]].set(k_sc),
-            "v_scale": arrays["v_scale"].at[
-                layer, rows[:, None], kv_idx, pos[:, None]].set(v_sc),
-        }
-        if cfg.sequence_parallel and mesh is not None:
-            from ..parallel.ring import sp_decode_attention
+            kq, k_sc = quantize_kv(k[:, 0])
+            vq, v_sc = quantize_kv(v[:, 0])
+            # int8 values scatter flat ([B, KV*D] rows); scales are
+            # [L, B, KV, S]: scatter the [B, KV] token scales at each row's
+            # position via full advanced indexing
+            kv_idx = jnp.arange(KV)[None, :]
+            arrays = {
+                "k": arrays["k"].at[layer, rows, pos].set(kq.reshape(b, KV * hd)),
+                "v": arrays["v"].at[layer, rows, pos].set(vq.reshape(b, KV * hd)),
+                "k_scale": arrays["k_scale"].at[
+                    layer, rows[:, None], kv_idx, pos[:, None]].set(k_sc),
+                "v_scale": arrays["v_scale"].at[
+                    layer, rows[:, None], kv_idx, pos[:, None]].set(v_sc),
+            }
+            if cfg.sequence_parallel and mesh is not None:
+                from ..parallel.ring import sp_decode_attention
 
-            o = sp_decode_attention(
-                q, arrays["k"], arrays["v"], pos + 1, mesh, layer=layer,
-                k_scale=arrays["k_scale"], v_scale=arrays["v_scale"])
+                o = sp_decode_attention(
+                    q, arrays["k"], arrays["v"], pos + 1, mesh, layer=layer,
+                    k_scale=arrays["k_scale"], v_scale=arrays["v_scale"])
+            else:
+                o = cached_decode_attention(
+                    q, arrays["k"], arrays["v"], pos + 1, layer=layer,
+                    use_kernel=cfg.use_flash,
+                    k_scale=arrays["k_scale"], v_scale=arrays["v_scale"])
         else:
-            o = cached_decode_attention(
-                q, arrays["k"], arrays["v"], pos + 1, layer=layer,
-                use_kernel=cfg.use_flash,
-                k_scale=arrays["k_scale"], v_scale=arrays["v_scale"])
-    else:
-        arrays = {
-            "k": arrays["k"].at[layer, rows, pos].set(k[:, 0]),
-            "v": arrays["v"].at[layer, rows, pos].set(v[:, 0]),
-        }
-        if cfg.sequence_parallel and mesh is not None:
-            # S-sharded cache: grouped online-softmax per shard + one
-            # pmax/psum combine (parallel/ring.py) — no cache all-gather
-            from ..parallel.ring import sp_decode_attention
+            arrays = {
+                "k": arrays["k"].at[layer, rows, pos].set(k[:, 0]),
+                "v": arrays["v"].at[layer, rows, pos].set(v[:, 0]),
+            }
+            if cfg.sequence_parallel and mesh is not None:
+                # S-sharded cache: grouped online-softmax per shard + one
+                # pmax/psum combine (parallel/ring.py) — no cache all-gather
+                from ..parallel.ring import sp_decode_attention
 
-            o = sp_decode_attention(q, arrays["k"], arrays["v"], pos + 1,
-                                    mesh, layer=layer)
-        else:
-            o = cached_decode_attention(q, arrays["k"], arrays["v"], pos + 1,
-                                        layer=layer,
-                                        use_kernel=cfg.use_flash)
+                o = sp_decode_attention(q, arrays["k"], arrays["v"], pos + 1,
+                                        mesh, layer=layer)
+            else:
+                o = cached_decode_attention(q, arrays["k"], arrays["v"], pos + 1,
+                                            layer=layer,
+                                            use_kernel=cfg.use_flash)
 
-    x = x + constrain(_mm(o.reshape(b, 1, H * hd), lp["wo"]),
-                      P("dp", "sp", None))
-    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    x = x + constrain(_swiglu(h, lp), P("dp", "sp", None))
+        x = x + constrain(_mm(o.reshape(b, 1, H * hd), lp["wo"]),
+                          P("dp", "sp", None))
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+        x = x + constrain(_swiglu(h, lp), P("dp", "sp", None))
     return x, arrays
 
 
@@ -576,7 +580,8 @@ def forward(params: dict, tokens: jnp.ndarray, cfg: LlamaConfig,
         body = jax.checkpoint(body)
     x, _ = jax.lax.scan(body, x, params["layers"])
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = _mm(x, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        logits = _mm(x, params["lm_head"]).astype(jnp.float32)
     return constrain(logits, P("dp", "sp", None))
 
 
@@ -677,7 +682,8 @@ def prefill(params: dict, tokens: jnp.ndarray, seq_lens: jnp.ndarray,
     # gather each row's last valid position, then project only that row
     rows = jnp.arange(b)
     last = x[rows, seq_lens - 1]  # [B, D]
-    logits = _mm(last, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        logits = _mm(last, params["lm_head"]).astype(jnp.float32)
 
     S_max = cache["k"].shape[2]
     pad = S_max - s
@@ -843,7 +849,8 @@ def prefill_segment_into(params: dict, tokens: jnp.ndarray,
         body, (x, arrays0, jnp.int32(0)), params["layers"])
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     last = x[0, seg_len[0] - 1]                           # [D]
-    logits = _mm(last[None], params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        logits = _mm(last[None], params["lm_head"]).astype(jnp.float32)
     return logits, {**arrays,
                     "len": cache["len"].at[slot].set(new_len)}
 
@@ -878,7 +885,8 @@ def decode_step(params: dict, tokens: jnp.ndarray, cache: dict,
     (x, arrays, _), _ = jax.lax.scan(
         body, (x, arrays0, jnp.int32(0)), params["layers"])
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = _mm(x[:, 0], params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        logits = _mm(x[:, 0], params["lm_head"]).astype(jnp.float32)
     # cap len at capacity: rows past the end keep decoding garbage (their
     # cache writes are dropped as out-of-bounds) but never index OOB.
     S_max = cache["k"].shape[2]
@@ -997,62 +1005,64 @@ def paged_suffix_prefill(params: dict, tokens: jnp.ndarray,
 
     def body(carry, lp):
         x, arrays, layer = carry
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q = _mm(h, lp["wq"]).reshape(b, s, H, hd)
-        k = _mm(h, lp["wk"]).reshape(b, s, KV, hd)
-        v = _mm(h, lp["wv"]).reshape(b, s, KV, hd)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        if cfg.kv_quant:
-            kq, k_pl = kv_encode(cfg, k[0])  # [S, KV, W] + planes [S, KV]
-            vq, v_pl = kv_encode(cfg, v[0])
-            w_kv = kq.shape[-1]
-            kv_i = jnp.arange(KV)[None, :]
-            arrays = dict(arrays)
-            arrays["k"] = arrays["k"].at[layer, page, off].set(
-                kq.reshape(s, KV * w_kv))
-            arrays["v"] = arrays["v"].at[layer, page, off].set(
-                vq.reshape(s, KV * w_kv))
-            for base, planes in (("k", k_pl), ("v", v_pl)):
-                for pl, val in planes.items():
-                    key = f"{base}_{pl}"
-                    arrays[key] = arrays[key].at[
-                        layer, page[:, None], kv_i, off[:, None]].set(val)
+        with jax.named_scope("attention"):
+            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+            q = _mm(h, lp["wq"]).reshape(b, s, H, hd)
+            k = _mm(h, lp["wk"]).reshape(b, s, KV, hd)
+            v = _mm(h, lp["wv"]).reshape(b, s, KV, hd)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+            if cfg.kv_quant:
+                kq, k_pl = kv_encode(cfg, k[0])  # [S, KV, W] + planes [S, KV]
+                vq, v_pl = kv_encode(cfg, v[0])
+                w_kv = kq.shape[-1]
+                kv_i = jnp.arange(KV)[None, :]
+                arrays = dict(arrays)
+                arrays["k"] = arrays["k"].at[layer, page, off].set(
+                    kq.reshape(s, KV * w_kv))
+                arrays["v"] = arrays["v"].at[layer, page, off].set(
+                    vq.reshape(s, KV * w_kv))
+                for base, planes in (("k", k_pl), ("v", v_pl)):
+                    for pl, val in planes.items():
+                        key = f"{base}_{pl}"
+                        arrays[key] = arrays[key].at[
+                            layer, page[:, None], kv_i, off[:, None]].set(val)
 
-            def virt(name):
-                q8 = jnp.take(jax.lax.dynamic_index_in_dim(
-                    arrays[name], layer, 0, keepdims=False),
-                    table_row, axis=0).reshape(1, -1, KV, w_kv)
-                planes = {}
-                for pl in kv_plane_names(cfg):
-                    p = jnp.take(jax.lax.dynamic_index_in_dim(
-                        arrays[f"{name}_{pl}"], layer, 0, keepdims=False),
-                        table_row, axis=0)          # [P, KV, ps]
-                    planes[pl] = jnp.swapaxes(p, -1, -2).reshape(1, -1, KV)
-                return kv_decode(cfg, q8, planes, cfg.dtype)
+                def virt(name):
+                    q8 = jnp.take(jax.lax.dynamic_index_in_dim(
+                        arrays[name], layer, 0, keepdims=False),
+                        table_row, axis=0).reshape(1, -1, KV, w_kv)
+                    planes = {}
+                    for pl in kv_plane_names(cfg):
+                        p = jnp.take(jax.lax.dynamic_index_in_dim(
+                            arrays[f"{name}_{pl}"], layer, 0, keepdims=False),
+                            table_row, axis=0)          # [P, KV, ps]
+                        planes[pl] = jnp.swapaxes(p, -1, -2).reshape(1, -1, KV)
+                    return kv_decode(cfg, q8, planes, cfg.dtype)
 
-            k_virt, v_virt = virt("k"), virt("v")
-        else:
-            dt = arrays["k"].dtype
-            arrays = {
-                "k": arrays["k"].at[layer, page, off].set(k[0].astype(dt)),
-                "v": arrays["v"].at[layer, page, off].set(v[0].astype(dt)),
-            }
-            k_l = jax.lax.dynamic_index_in_dim(arrays["k"], layer, 0,
-                                               keepdims=False)
-            v_l = jax.lax.dynamic_index_in_dim(arrays["v"], layer, 0,
-                                               keepdims=False)
-            # virtual sequence for this ONE slot: [1, P_max*page_s, KV, hd]
-            k_virt = jnp.take(k_l, table_row, axis=0).reshape(1, -1, KV, hd)
-            v_virt = jnp.take(v_l, table_row, axis=0).reshape(1, -1, KV, hd)
-        # causal from the segment's absolute offset: suffix token t
-        # attends every prefix position plus the window up to itself
-        o = attention(q, repeat_kv(k_virt, cfg.n_rep),
-                      repeat_kv(v_virt, cfg.n_rep),
-                      causal=True, q_offset=start)
-        x = x + _mm(o.reshape(b, s, H * hd), lp["wo"])
-        h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + _swiglu(h2, lp)
+                k_virt, v_virt = virt("k"), virt("v")
+            else:
+                dt = arrays["k"].dtype
+                arrays = {
+                    "k": arrays["k"].at[layer, page, off].set(k[0].astype(dt)),
+                    "v": arrays["v"].at[layer, page, off].set(v[0].astype(dt)),
+                }
+                k_l = jax.lax.dynamic_index_in_dim(arrays["k"], layer, 0,
+                                                   keepdims=False)
+                v_l = jax.lax.dynamic_index_in_dim(arrays["v"], layer, 0,
+                                                   keepdims=False)
+                # virtual sequence for this ONE slot: [1, P_max*page_s, KV, hd]
+                k_virt = jnp.take(k_l, table_row, axis=0).reshape(1, -1, KV, hd)
+                v_virt = jnp.take(v_l, table_row, axis=0).reshape(1, -1, KV, hd)
+            # causal from the segment's absolute offset: suffix token t
+            # attends every prefix position plus the window up to itself
+            o = attention(q, repeat_kv(k_virt, cfg.n_rep),
+                          repeat_kv(v_virt, cfg.n_rep),
+                          causal=True, q_offset=start)
+            x = x + _mm(o.reshape(b, s, H * hd), lp["wo"])
+        with jax.named_scope("mlp"):
+            h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+            x = x + _swiglu(h2, lp)
         return (x, arrays, layer + 1), None
 
     arrays0 = {key: cache[key] for key in cache if key != "len"}
@@ -1060,7 +1070,8 @@ def paged_suffix_prefill(params: dict, tokens: jnp.ndarray,
         body, (x, arrays0, jnp.int32(0)), params["layers"])
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     last = x[jnp.arange(b), seq_lens - 1]                 # [1, D]
-    logits = _mm(last, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        logits = _mm(last, params["lm_head"]).astype(jnp.float32)
     return logits, {**arrays, "len": cache["len"]}
 
 
@@ -1098,68 +1109,71 @@ def paged_decode_step(params: dict, tokens: jnp.ndarray, cache: dict,
 
     def body(carry, lp):
         x, arrays, layer = carry
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q = _mm(h, lp["wq"]).reshape(b, 1, H, hd)
-        k = _mm(h, lp["wk"]).reshape(b, 1, KV, hd)
-        v = _mm(h, lp["wv"]).reshape(b, 1, KV, hd)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        if cfg.kv_quant:
-            kq, k_pl = kv_encode(cfg, k[:, 0])  # [B, KV, W] + [B, KV]
-            vq, v_pl = kv_encode(cfg, v[:, 0])
-            w_kv = kq.shape[-1]
-            arrays = dict(arrays)
-            arrays["k"] = arrays["k"].at[layer, page, off].set(
-                kq.reshape(b, KV * w_kv))
-            arrays["v"] = arrays["v"].at[layer, page, off].set(
-                vq.reshape(b, KV * w_kv))
-            for base, planes in (("k", k_pl), ("v", v_pl)):
-                for pl, val in planes.items():
-                    key = f"{base}_{pl}"
-                    arrays[key] = arrays[key].at[
-                        layer, page[:, None], kv_idx, off[:, None]].set(val)
+        with jax.named_scope("attention"):
+            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+            q = _mm(h, lp["wq"]).reshape(b, 1, H, hd)
+            k = _mm(h, lp["wk"]).reshape(b, 1, KV, hd)
+            v = _mm(h, lp["wv"]).reshape(b, 1, KV, hd)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+            if cfg.kv_quant:
+                kq, k_pl = kv_encode(cfg, k[:, 0])  # [B, KV, W] + [B, KV]
+                vq, v_pl = kv_encode(cfg, v[:, 0])
+                w_kv = kq.shape[-1]
+                arrays = dict(arrays)
+                arrays["k"] = arrays["k"].at[layer, page, off].set(
+                    kq.reshape(b, KV * w_kv))
+                arrays["v"] = arrays["v"].at[layer, page, off].set(
+                    vq.reshape(b, KV * w_kv))
+                for base, planes in (("k", k_pl), ("v", v_pl)):
+                    for pl, val in planes.items():
+                        key = f"{base}_{pl}"
+                        arrays[key] = arrays[key].at[
+                            layer, page[:, None], kv_idx, off[:, None]].set(val)
 
-            def virt(name):
-                q8 = jnp.take(jax.lax.dynamic_index_in_dim(
-                    arrays[name], layer, 0, keepdims=False), table, axis=0)
-                q8 = q8.reshape(b, -1, KV, w_kv)    # [B, P*ps, KV, W]
-                planes = {}
-                for pl in kv_plane_names(cfg):
-                    p = jnp.take(jax.lax.dynamic_index_in_dim(
-                        arrays[f"{name}_{pl}"], layer, 0, keepdims=False),
-                        table, axis=0)              # [B, P, KV, ps]
-                    planes[pl] = jnp.swapaxes(p, -1, -2).reshape(b, -1, KV)
-                return kv_decode(cfg, q8, planes, cfg.dtype)
+                def virt(name):
+                    q8 = jnp.take(jax.lax.dynamic_index_in_dim(
+                        arrays[name], layer, 0, keepdims=False), table, axis=0)
+                    q8 = q8.reshape(b, -1, KV, w_kv)    # [B, P*ps, KV, W]
+                    planes = {}
+                    for pl in kv_plane_names(cfg):
+                        p = jnp.take(jax.lax.dynamic_index_in_dim(
+                            arrays[f"{name}_{pl}"], layer, 0, keepdims=False),
+                            table, axis=0)              # [B, P, KV, ps]
+                        planes[pl] = jnp.swapaxes(p, -1, -2).reshape(b, -1, KV)
+                    return kv_decode(cfg, q8, planes, cfg.dtype)
 
-            k_virt, v_virt = virt("k"), virt("v")
-        else:
-            dt = arrays["k"].dtype
-            arrays = {
-                "k": arrays["k"].at[layer, page, off].set(
-                    k[:, 0].astype(dt)),
-                "v": arrays["v"].at[layer, page, off].set(
-                    v[:, 0].astype(dt)),
-            }
-            k_l = jax.lax.dynamic_index_in_dim(arrays["k"], layer, 0,
-                                               keepdims=False)
-            v_l = jax.lax.dynamic_index_in_dim(arrays["v"], layer, 0,
-                                               keepdims=False)
-            # virtual sequence: gather this row's pages in table order
-            k_virt = jnp.take(k_l, table, axis=0).reshape(b, -1, KV, hd)
-            v_virt = jnp.take(v_l, table, axis=0).reshape(b, -1, KV, hd)
-        o = attention(q, repeat_kv(k_virt, cfg.n_rep),
-                      repeat_kv(v_virt, cfg.n_rep),
-                      causal=False, kv_len=pos + 1)
-        x = x + _mm(o.reshape(b, 1, H * hd), lp["wo"])
-        h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + _swiglu(h2, lp)
+                k_virt, v_virt = virt("k"), virt("v")
+            else:
+                dt = arrays["k"].dtype
+                arrays = {
+                    "k": arrays["k"].at[layer, page, off].set(
+                        k[:, 0].astype(dt)),
+                    "v": arrays["v"].at[layer, page, off].set(
+                        v[:, 0].astype(dt)),
+                }
+                k_l = jax.lax.dynamic_index_in_dim(arrays["k"], layer, 0,
+                                                   keepdims=False)
+                v_l = jax.lax.dynamic_index_in_dim(arrays["v"], layer, 0,
+                                                   keepdims=False)
+                # virtual sequence: gather this row's pages in table order
+                k_virt = jnp.take(k_l, table, axis=0).reshape(b, -1, KV, hd)
+                v_virt = jnp.take(v_l, table, axis=0).reshape(b, -1, KV, hd)
+            o = attention(q, repeat_kv(k_virt, cfg.n_rep),
+                          repeat_kv(v_virt, cfg.n_rep),
+                          causal=False, kv_len=pos + 1)
+            x = x + _mm(o.reshape(b, 1, H * hd), lp["wo"])
+        with jax.named_scope("mlp"):
+            h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+            x = x + _swiglu(h2, lp)
         return (x, arrays, layer + 1), None
 
     arrays0 = {key: cache[key] for key in cache if key != "len"}
     (x, arrays, _), _ = jax.lax.scan(
         body, (x, arrays0, jnp.int32(0)), params["layers"])
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = _mm(x[:, 0], params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        logits = _mm(x[:, 0], params["lm_head"]).astype(jnp.float32)
     S_virt = table.shape[1] * page_s
     return logits, {**arrays, "len": jnp.minimum(pos + 1, S_virt)}
 
@@ -1360,68 +1374,71 @@ def paged_decode_window(params: dict, toks: jnp.ndarray, cache: dict,
 
     def body(carry, lp):
         x, arrays, layer = carry
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q = _mm(h, lp["wq"]).reshape(b, w, H, hd)
-        k = _mm(h, lp["wk"]).reshape(b, w, KV, hd)
-        v = _mm(h, lp["wv"]).reshape(b, w, KV, hd)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        if cfg.kv_quant:
-            # quantized page layouts (init_paged_cache): values flat
-            # [L, N, ps, KV*W], scale/zero planes [L, N, KV, ps]
-            kq, k_pl = kv_encode(cfg, k)  # [B, W, KV, Wd] + [B, W, KV]
-            vq, v_pl = kv_encode(cfg, v)
-            w_kv = kq.shape[-1]
-            arrays = dict(arrays)
-            arrays["k"] = arrays["k"].at[layer, page, off].set(
-                kq.reshape(b, w, KV * w_kv))
-            arrays["v"] = arrays["v"].at[layer, page, off].set(
-                vq.reshape(b, w, KV * w_kv))
-            for base, planes in (("k", k_pl), ("v", v_pl)):
-                for pl, val in planes.items():
-                    key = f"{base}_{pl}"
-                    arrays[key] = arrays[key].at[
-                        layer, page[:, :, None], kv_idx3,
-                        off[:, :, None]].set(val)
+        with jax.named_scope("attention"):
+            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+            q = _mm(h, lp["wq"]).reshape(b, w, H, hd)
+            k = _mm(h, lp["wk"]).reshape(b, w, KV, hd)
+            v = _mm(h, lp["wv"]).reshape(b, w, KV, hd)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+            if cfg.kv_quant:
+                # quantized page layouts (init_paged_cache): values flat
+                # [L, N, ps, KV*W], scale/zero planes [L, N, KV, ps]
+                kq, k_pl = kv_encode(cfg, k)  # [B, W, KV, Wd] + [B, W, KV]
+                vq, v_pl = kv_encode(cfg, v)
+                w_kv = kq.shape[-1]
+                arrays = dict(arrays)
+                arrays["k"] = arrays["k"].at[layer, page, off].set(
+                    kq.reshape(b, w, KV * w_kv))
+                arrays["v"] = arrays["v"].at[layer, page, off].set(
+                    vq.reshape(b, w, KV * w_kv))
+                for base, planes in (("k", k_pl), ("v", v_pl)):
+                    for pl, val in planes.items():
+                        key = f"{base}_{pl}"
+                        arrays[key] = arrays[key].at[
+                            layer, page[:, :, None], kv_idx3,
+                            off[:, :, None]].set(val)
 
-            def virt(name):
-                q8 = jnp.take(jax.lax.dynamic_index_in_dim(
-                    arrays[name], layer, 0, keepdims=False), table, axis=0)
-                q8 = q8.reshape(b, -1, KV, w_kv)    # [B, P*ps, KV, W]
-                planes = {}
-                for pl in kv_plane_names(cfg):
-                    p = jnp.take(jax.lax.dynamic_index_in_dim(
-                        arrays[f"{name}_{pl}"], layer, 0, keepdims=False),
-                        table, axis=0)              # [B, P, KV, ps]
-                    planes[pl] = jnp.swapaxes(p, -1, -2).reshape(b, -1, KV)
-                return kv_decode(cfg, q8, planes, cfg.dtype)
+                def virt(name):
+                    q8 = jnp.take(jax.lax.dynamic_index_in_dim(
+                        arrays[name], layer, 0, keepdims=False), table, axis=0)
+                    q8 = q8.reshape(b, -1, KV, w_kv)    # [B, P*ps, KV, W]
+                    planes = {}
+                    for pl in kv_plane_names(cfg):
+                        p = jnp.take(jax.lax.dynamic_index_in_dim(
+                            arrays[f"{name}_{pl}"], layer, 0, keepdims=False),
+                            table, axis=0)              # [B, P, KV, ps]
+                        planes[pl] = jnp.swapaxes(p, -1, -2).reshape(b, -1, KV)
+                    return kv_decode(cfg, q8, planes, cfg.dtype)
 
-            k_virt, v_virt = virt("k"), virt("v")
-        else:
-            dt = arrays["k"].dtype
-            arrays = {
-                "k": arrays["k"].at[layer, page, off].set(k.astype(dt)),
-                "v": arrays["v"].at[layer, page, off].set(v.astype(dt)),
-            }
-            k_l = jax.lax.dynamic_index_in_dim(arrays["k"], layer, 0,
-                                               keepdims=False)
-            v_l = jax.lax.dynamic_index_in_dim(arrays["v"], layer, 0,
-                                               keepdims=False)
-            k_virt = jnp.take(k_l, table, axis=0).reshape(b, -1, KV, hd)
-            v_virt = jnp.take(v_l, table, axis=0).reshape(b, -1, KV, hd)
-        o = attention(q, repeat_kv(k_virt, cfg.n_rep),
-                      repeat_kv(v_virt, cfg.n_rep),
-                      causal=True, q_offset=pos0)  # per-row offsets
-        x = x + _mm(o.reshape(b, w, H * hd), lp["wo"])
-        h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + _swiglu(h2, lp)
+                k_virt, v_virt = virt("k"), virt("v")
+            else:
+                dt = arrays["k"].dtype
+                arrays = {
+                    "k": arrays["k"].at[layer, page, off].set(k.astype(dt)),
+                    "v": arrays["v"].at[layer, page, off].set(v.astype(dt)),
+                }
+                k_l = jax.lax.dynamic_index_in_dim(arrays["k"], layer, 0,
+                                                   keepdims=False)
+                v_l = jax.lax.dynamic_index_in_dim(arrays["v"], layer, 0,
+                                                   keepdims=False)
+                k_virt = jnp.take(k_l, table, axis=0).reshape(b, -1, KV, hd)
+                v_virt = jnp.take(v_l, table, axis=0).reshape(b, -1, KV, hd)
+            o = attention(q, repeat_kv(k_virt, cfg.n_rep),
+                          repeat_kv(v_virt, cfg.n_rep),
+                          causal=True, q_offset=pos0)  # per-row offsets
+            x = x + _mm(o.reshape(b, w, H * hd), lp["wo"])
+        with jax.named_scope("mlp"):
+            h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+            x = x + _swiglu(h2, lp)
         return (x, arrays, layer + 1), None
 
     arrays0 = {key: cache[key] for key in cache if key != "len"}
     (x, arrays, _), _ = jax.lax.scan(
         body, (x, arrays0, jnp.int32(0)), params["layers"])
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = _mm(x, params["lm_head"]).astype(jnp.float32)  # [B, W, V]
+    with jax.named_scope("lm_head"):
+        logits = _mm(x, params["lm_head"]).astype(jnp.float32)  # [B, W, V]
     return logits, {**arrays, "len": cache["len"]}
 
 
@@ -1460,68 +1477,71 @@ def decode_window(params: dict, toks: jnp.ndarray, cache: dict,
 
     def body(carry, lp):
         x, arrays, layer = carry
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q = _mm(h, lp["wq"]).reshape(b, w, H, hd)
-        k = _mm(h, lp["wk"]).reshape(b, w, KV, hd)
-        v = _mm(h, lp["wv"]).reshape(b, w, KV, hd)
-        q = constrain(q, P("dp", None, "tp", None))
-        k = constrain(k, P("dp", None, "tp", None))
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        if cfg.kv_quant:
-            # same layouts as _decode_layer: int8 values FLAT [L,B,S,KV*D],
-            # scales [L,B,KV,S] — W rows scatter at their own positions
-            kq, k_sc = quantize_kv(k)      # [B,W,KV,hd] -> sc [B,W,KV]
-            vq, v_sc = quantize_kv(v)
-            r_i = rows[:, None, None]
-            kv_i = jnp.arange(KV)[None, None, :]
-            p_i = positions[:, :, None]
-            arrays = {
-                "k": arrays["k"].at[layer, rows[:, None], positions].set(
-                    kq.reshape(b, w, KV * hd), mode="drop"),
-                "v": arrays["v"].at[layer, rows[:, None], positions].set(
-                    vq.reshape(b, w, KV * hd), mode="drop"),
-                "k_scale": arrays["k_scale"].at[layer, r_i, kv_i, p_i].set(
-                    k_sc, mode="drop"),
-                "v_scale": arrays["v_scale"].at[layer, r_i, kv_i, p_i].set(
-                    v_sc, mode="drop"),
-            }
-            idx = lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0,
-                                                         keepdims=False)
-            s_max = arrays["k"].shape[2]
-            deq = lambda qv, sc: dequantize_kv(
-                idx(qv).reshape(b, s_max, KV, hd),
-                idx(sc).transpose(0, 2, 1), cfg.dtype)
-            k_row = deq(arrays["k"], arrays["k_scale"])
-            v_row = deq(arrays["v"], arrays["v_scale"])
-        else:
-            dt = arrays["k"].dtype
-            arrays = {
-                "k": arrays["k"].at[layer, rows[:, None], positions].set(
-                    k.astype(dt), mode="drop"),
-                "v": arrays["v"].at[layer, rows[:, None], positions].set(
-                    v.astype(dt), mode="drop"),
-            }
-            k_row = jax.lax.dynamic_index_in_dim(arrays["k"], layer, 0,
-                                                 keepdims=False)
-            v_row = jax.lax.dynamic_index_in_dim(arrays["v"], layer, 0,
-                                                 keepdims=False)
-        # per-row causal offset: query t of row i attends positions
-        # <= pos0[i]+t — its prefix plus the window so far; stale cells
-        # past the window are unreachable
-        o = attention(q, repeat_kv(k_row, cfg.n_rep),
-                      repeat_kv(v_row, cfg.n_rep),
-                      causal=True, q_offset=pos0)
-        x = x + _mm(o.reshape(b, w, H * hd), lp["wo"])
-        h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + _swiglu(h2, lp)
+        with jax.named_scope("attention"):
+            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+            q = _mm(h, lp["wq"]).reshape(b, w, H, hd)
+            k = _mm(h, lp["wk"]).reshape(b, w, KV, hd)
+            v = _mm(h, lp["wv"]).reshape(b, w, KV, hd)
+            q = constrain(q, P("dp", None, "tp", None))
+            k = constrain(k, P("dp", None, "tp", None))
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+            if cfg.kv_quant:
+                # same layouts as _decode_layer: int8 values FLAT [L,B,S,KV*D],
+                # scales [L,B,KV,S] — W rows scatter at their own positions
+                kq, k_sc = quantize_kv(k)      # [B,W,KV,hd] -> sc [B,W,KV]
+                vq, v_sc = quantize_kv(v)
+                r_i = rows[:, None, None]
+                kv_i = jnp.arange(KV)[None, None, :]
+                p_i = positions[:, :, None]
+                arrays = {
+                    "k": arrays["k"].at[layer, rows[:, None], positions].set(
+                        kq.reshape(b, w, KV * hd), mode="drop"),
+                    "v": arrays["v"].at[layer, rows[:, None], positions].set(
+                        vq.reshape(b, w, KV * hd), mode="drop"),
+                    "k_scale": arrays["k_scale"].at[layer, r_i, kv_i, p_i].set(
+                        k_sc, mode="drop"),
+                    "v_scale": arrays["v_scale"].at[layer, r_i, kv_i, p_i].set(
+                        v_sc, mode="drop"),
+                }
+                idx = lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0,
+                                                             keepdims=False)
+                s_max = arrays["k"].shape[2]
+                deq = lambda qv, sc: dequantize_kv(
+                    idx(qv).reshape(b, s_max, KV, hd),
+                    idx(sc).transpose(0, 2, 1), cfg.dtype)
+                k_row = deq(arrays["k"], arrays["k_scale"])
+                v_row = deq(arrays["v"], arrays["v_scale"])
+            else:
+                dt = arrays["k"].dtype
+                arrays = {
+                    "k": arrays["k"].at[layer, rows[:, None], positions].set(
+                        k.astype(dt), mode="drop"),
+                    "v": arrays["v"].at[layer, rows[:, None], positions].set(
+                        v.astype(dt), mode="drop"),
+                }
+                k_row = jax.lax.dynamic_index_in_dim(arrays["k"], layer, 0,
+                                                     keepdims=False)
+                v_row = jax.lax.dynamic_index_in_dim(arrays["v"], layer, 0,
+                                                     keepdims=False)
+            # per-row causal offset: query t of row i attends positions
+            # <= pos0[i]+t — its prefix plus the window so far; stale cells
+            # past the window are unreachable
+            o = attention(q, repeat_kv(k_row, cfg.n_rep),
+                          repeat_kv(v_row, cfg.n_rep),
+                          causal=True, q_offset=pos0)
+            x = x + _mm(o.reshape(b, w, H * hd), lp["wo"])
+        with jax.named_scope("mlp"):
+            h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+            x = x + _swiglu(h2, lp)
         return (x, arrays, layer + 1), None
 
     arrays0 = {key: cache[key] for key in cache if key != "len"}
     (x, arrays, _), _ = jax.lax.scan(
         body, (x, arrays0, jnp.int32(0)), params["layers"])
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = _mm(x, params["lm_head"]).astype(jnp.float32)  # [B, W, V]
+    with jax.named_scope("lm_head"):
+        logits = _mm(x, params["lm_head"]).astype(jnp.float32)  # [B, W, V]
     return logits, {**arrays, "len": cache["len"]}
 
 

@@ -19,7 +19,7 @@ from aiohttp.test_utils import TestClient, TestServer
 from gofr_tpu.app import App
 from gofr_tpu.config import MapConfig
 from gofr_tpu.container import Container
-from gofr_tpu.flight_recorder import (DispatchRecorder, EventLog,
+from gofr_tpu.flight_recorder import (DispatchRecorder, EventLog, dispatch_log,
                                       crash_vault, event_log)
 from gofr_tpu.ml.errors import DeadlineExceeded, GeneratorCrashed, Overloaded
 from gofr_tpu.ml.generate import Generator
@@ -169,7 +169,9 @@ def test_server_phase_breakdown_covers_step_wall(model, run):
     assert snap["dispatches"] >= 1
     assert snap["window"]["records"] >= 1
     # the acceptance criterion: attributed phases explain the step wall
-    for record in list(rec._ring):
+    records = [r for r in dispatch_log().records()
+               if r["model"] == "fr-phases"]
+    for record in records:
         total = sum(record["phases"].values())
         assert total == pytest.approx(record["wall_s"], abs=1e-6)
     assert snap["attributed_share"] is not None

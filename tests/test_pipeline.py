@@ -13,7 +13,7 @@ raggedness); a crash with two windows in flight fails only the active
 slots and recovers with zero dispatches outstanding; the deadline
 reaper works mid-overlap; journey decode marks carry the in-flight
 depth; and the flight recorder stamps the ``overlap`` dim and
-estimates ``device_idle_share``.
+estimates ``host_idle_estimate``.
 """
 
 import asyncio
@@ -317,13 +317,13 @@ def test_recorder_overlap_dim_and_idle_share(model):
     assert any(r.get("busy_s", 0.0) > 0.0 for r in tail)
     snap = rec.snapshot()
     assert snap["overlapped_dispatches"] >= 1
-    idle = snap["device_idle_share"]
+    idle = snap["host_idle_estimate"]
     assert idle is None or 0.0 <= idle <= 1.0
     # the per-generator stats block surfaces the same estimate
     stats = gen.pipeline_stats()
     assert set(stats) == {"depth", "windows_overlapped",
-                          "overshoot_tokens", "device_idle_share"}
-    assert stats["device_idle_share"] == idle
+                          "overshoot_tokens", "host_idle_estimate"}
+    assert stats["host_idle_estimate"] == idle
 
 
 def test_serving_snapshot_pipeline_block(model, run):
