@@ -306,10 +306,9 @@ async def phase_serve(cfg, params, sizes: Sizes) -> dict:
             "decode_attention", _sds(cfg, B, 1, H, D),
             _sds(cfg, cfg.n_layers, B, S, KV, D)): sizes.kernels}
         for bucket in gen.prefill_buckets:
-            for rows in (1, gen._admit_cap):
-                q = _sds(cfg, rows, bucket, H, D)
-                expected[ops.branch_key("flash_attention", q, q)] = (
-                    sizes.kernels if bucket >= 128 else "xla")
+            q = _sds(cfg, 1, bucket, H, D)
+            expected[ops.branch_key("flash_attention", q, q)] = (
+                sizes.kernels if bucket >= 128 else "xla")
         stats["branches"] = _branches(snap, expected)
         stats["prefill_buckets"] = list(gen.prefill_buckets)
     check = make_reference(cfg, sizes.tol)

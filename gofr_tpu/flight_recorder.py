@@ -175,6 +175,8 @@ class DispatchRecorder:
         # the decode programs this pass launched: the last one's kind,
         # their steps, their rows x steps (see phase)
         self._pending_launch = _NO_LAUNCH
+        # the prefill programs this pass launched: their launch metadata
+        self._pending_prefills: list[dict] = []
         # (planned K, realized steps, windows settled), see note_window
         self._pending_window: tuple[int, int, int] | None = None
         self._pending_overlap = 0  # see note_overlap
@@ -210,13 +212,18 @@ class DispatchRecorder:
         (``chunk``, ``mini``, ``window``, ``spec``, ``specwin``),
         ``steps=`` and ``rows=`` (the rows producing tokens): they go on
         the record and, with the ``seq`` the pass will commit under, on
-        the annotation."""
+        the annotation. The ``launch`` of a whole-prompt prefill program
+        says ``kind="prefill"``, ``rows=``, ``seq=`` (here the program's
+        sequence length) and ``real_tokens=`` (the prompt's): the record
+        lists them under ``prefills``, one entry a program."""
         if "steps" in meta:
             steps, rows = int(meta["steps"]), int(meta["rows"])
             _, steps0, row_steps0 = self._pending_launch
             self._pending_launch = (meta["kind"], steps0 + steps,
                                     row_steps0 + rows * steps)
             meta.update(seq=self.dispatches + 1, steps=steps, rows=rows)
+        elif meta.get("kind") == "prefill":
+            self._pending_prefills.append(meta)
         return _Span(self, name, meta)
 
     def note(self, phase: str, seconds: float) -> None:
@@ -276,6 +283,7 @@ class DispatchRecorder:
         self._spans.clear()
         self._pending_rids.clear()
         self._pending_launch = _NO_LAUNCH
+        self._pending_prefills = []
         self._pending_window = None
         self._pending_overlap = 0
         self._pending_busy = 0.0
@@ -307,6 +315,8 @@ class DispatchRecorder:
             # stable de-dup (a slot may burst twice in one pass): the
             # record names every request this dispatch served
             rec["rids"] = list(dict.fromkeys(self._pending_rids))
+        if self._pending_prefills:
+            rec["prefills"] = self._pending_prefills
         if self._pending_window is not None:
             k, realized, n = self._pending_window
             rec["window"] = {"k": k, "realized": realized, "n": n}

@@ -59,6 +59,22 @@ def _chunk_ladder(chunk: int) -> tuple[int, ...]:
     return tuple(ladder)
 
 
+def _prefill_ladder(cap: int) -> tuple[int, ...]:
+    """Sequence lengths of the dense layout's one-row prefill programs:
+    128, 256, 512, 768, 1024, then powers of two, as far as ``cap`` (the
+    longest whole prompt), and ``cap`` itself. A prompt takes the smallest
+    entry that holds it. Every entry below ``cap`` is 128 or a multiple of
+    256, which ``ops.flash_attention`` needs for its kernel (384 would
+    fall to the XLA path). 2048 -> (128, 256, 512, 768, 1024, 2048);
+    512 -> (128, 256, 512); 64 -> (64,)."""
+    ladder = [n for n in (128, 256, 512, 768) if n < cap]
+    n = 1024
+    while n < cap:
+        ladder.append(n)
+        n *= 2
+    return (*ladder, cap)
+
+
 def _env_int(name: str, default: int, *, minimum: int = 0) -> int:
     """Loudly-validated integer env knob (the PR-6 drain/replicas
     pattern): malformed or out-of-range values fail at construction
@@ -285,6 +301,14 @@ class Generator:
         gen = Generator(params, cfg, batch_slots=8, max_seq=2048)
         out = gen.generate(prompt_ids, max_new_tokens=64)   # single request
         # or: slot = gen.add_request(ids, n, cb); gen.step() in a loop
+
+    Admission is one prompt a prefill program, whatever the size of the
+    wave: in the dense layout (the default) on a ladder of lengths fixed
+    by ``max_seq`` (``_prefill_ladder``; ``gen.prefill_buckets`` holds
+    it), in the paged and SP layouts on the ``prefill_buckets`` given.
+    ``warmup()`` builds every one of them. ``prefill_tokens_real`` and
+    ``prefill_tokens_padded`` count what the dense programs were sent and
+    what they computed.
     """
 
     def __init__(self, params: Any, cfg, *, batch_slots: int = 8,
@@ -426,6 +450,16 @@ class Generator:
             sp, cfg=cfg, mesh=mesh, prefill_buckets=self.prefill_buckets,
             max_seq=max_seq, page_size=int(page_size), spec_k=self.spec_k,
             shard_cache=shard_cache)
+        if not page_size and self._sp is None:
+            # the dense layout prefills every prompt in a one-row program
+            # of its own, on a ladder of lengths that follows from max_seq
+            # alone (the ``prefill_buckets`` argument is the paged and SP
+            # layouts'). Segmented prefill takes every prompt past the
+            # chunk, so no longer whole prompt exists — but for a draft
+            # model, which ingests a segmented prompt's history whole.
+            whole = (min(int(prefill_chunk), max_seq)
+                     if prefill_chunk and draft_params is None else max_seq)
+            self.prefill_buckets = _prefill_ladder(whole)
         if self._sp is not None:
             mesh = self._sp.mesh
             self._mesh_ctx = lambda: mesh
@@ -602,6 +636,10 @@ class Generator:
         self.prefetch_errors = 0
         self._prefetch_warned = False
         self.prefill_segments_run = 0  # chunked-prefill segments dispatched
+        # prompt tokens sent to, and rows x seq computed by, the dense
+        # whole-prompt prefill programs: their ratio is the pad share
+        self.prefill_tokens_real = 0
+        self.prefill_tokens_padded = 0
 
         sampler_cfg = self.sampler
         host_visible = self._host_visible
@@ -893,37 +931,6 @@ class Generator:
                                                new_len, mesh=mesh),
                     donate_argnums=(3,),
                 )
-
-        def post_prefill_many(tok_dev, logits, prefill_key, n_req0, slots,
-                              valid):
-            """Batched first-token sampling for an admission wave: one key
-            per wave (categorical samples rows independently), sequential
-            unrolled scatter so identity writes for padding rows can never
-            clobber a real row written earlier in the same wave."""
-            key = jax.random.fold_in(prefill_key, n_req0)
-            firsts = _sample_impl(logits, key, sampler_cfg)
-            for i in range(slots.shape[0]):
-                cur = tok_dev[slots[i]]
-                tok_dev = tok_dev.at[slots[i]].set(
-                    jnp.where(valid[i], firsts[i], cur))
-            return host_visible(tok_dev)
-
-        self._post_prefill_many = jax.jit(post_prefill_many,
-                                          donate_argnums=(0,))
-        self._prefill_many = _jit(
-            "prefill_many",
-            lambda p, t, l, c, slots, valid: llama.prefill_into_many(
-                p, t, l, cfg, c, slots, valid, mesh=mesh),
-            donate_argnums=(3,),
-        )
-        # admission-wave shape buckets: 1 (the common trickle) and
-        # _admit_cap (bursts). Waves of 2..cap-1 pad to cap with masked
-        # rows — a little extra MXU work instead of a fresh compile.
-        # Paged mode admits per-request (each prefill scatters into its
-        # own page set); SP mode does too — the dual-path threshold is
-        # per-prompt, and one sequence-parallel wave serves one prompt.
-        self._admit_cap = (1 if (self.page_size or self._sp is not None)
-                           else min(8, batch_slots))
 
         # -- speculative decoding (device-resident prompt lookup) ----------
         # (self.spec_k was parsed and validated at the top of __init__)
@@ -1248,27 +1255,6 @@ class Generator:
         self._spec_post_prefill = jax.jit(spec_post_prefill,
                                           donate_argnums=(0, 1))
 
-        def spec_post_prefill_many(tok_dev, tokens_dev, logits, prompts,
-                                   lens, slots, valid):
-            firsts = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            bucket = prompts.shape[1]
-            arb = jnp.arange(bucket)
-            for i in range(slots.shape[0]):
-                tok_dev = tok_dev.at[slots[i]].set(
-                    jnp.where(valid[i], firsts[i], tok_dev[slots[i]]))
-                cur = jax.lax.dynamic_slice(
-                    tokens_dev, (slots[i], jnp.int32(0)), (1, bucket))[0]
-                row = jnp.where(valid[i] & (arb < lens[i]), prompts[i], cur)
-                tokens_dev = jax.lax.dynamic_update_slice(
-                    tokens_dev, row[None], (slots[i], jnp.int32(0)))
-                tokens_dev = tokens_dev.at[slots[i], lens[i]].set(
-                    jnp.where(valid[i], firsts[i],
-                              tokens_dev[slots[i], lens[i]]))
-            return host_visible(tok_dev), host_visible(tokens_dev)
-
-        self._spec_post_prefill_many = jax.jit(spec_post_prefill_many,
-                                               donate_argnums=(0, 1))
-
         def spec_prefix_post(tok_dev, tokens_dev, logits, row, length,
                              slot):
             """Prefixed admission under speculation: the slot's history
@@ -1294,42 +1280,23 @@ class Generator:
                 lambda p, t, l, c, s: llama.prefill_into(
                     p, t, l, draft_cfg, c, s),
                 donate_argnums=(3,))
-            self._draft_prefill_many = _jit(
-                "draft_prefill_many",
-                lambda p, t, l, c, s, v: llama.prefill_into_many(
-                    p, t, l, draft_cfg, c, s, v),
-                donate_argnums=(3,))
 
-    def _after_prefill(self, logits, tokens, lens, slots, valid=None) -> None:
-        """Route prefill logits into first-token state — spec mode also
-        records prompt + first into the history rows. One site for the
-        single-slot (valid=None) and wave shapes, shared by warmup and
-        admission so compiled shapes always stay warm."""
-        if valid is None:
-            if self.spec_k:
-                self._tok_dev, self._tokens_dev = self._spec_post_prefill(
-                    self._tok_dev, self._tokens_dev, logits, tokens, lens,
-                    slots)
-                if self.draft_params is not None:
-                    _, self._draft_cache = self._draft_prefill_into(
-                        self.draft_params, tokens, lens, self._draft_cache,
-                        slots)
-            else:
-                self._tok_dev = self._post_prefill(
-                    self._tok_dev, logits, self._prefill_key,
-                    np.uint32(self._n_requests), slots)
-        elif self.spec_k:
-            self._tok_dev, self._tokens_dev = self._spec_post_prefill_many(
-                self._tok_dev, self._tokens_dev, logits, tokens, lens,
-                slots, valid)
+    def _after_prefill(self, logits, tokens, lens, slot) -> None:
+        """Route one prompt's prefill logits into first-token state — spec
+        mode also records prompt + first into the history row. One site,
+        shared by warmup and admission so compiled shapes always stay
+        warm."""
+        if self.spec_k:
+            self._tok_dev, self._tokens_dev = self._spec_post_prefill(
+                self._tok_dev, self._tokens_dev, logits, tokens, lens, slot)
             if self.draft_params is not None:
-                _, self._draft_cache = self._draft_prefill_many(
+                _, self._draft_cache = self._draft_prefill_into(
                     self.draft_params, tokens, lens, self._draft_cache,
-                    slots, valid)
+                    slot)
         else:
-            self._tok_dev = self._post_prefill_many(
+            self._tok_dev = self._post_prefill(
                 self._tok_dev, logits, self._prefill_key,
-                np.uint32(self._n_requests), slots, valid)
+                np.uint32(self._n_requests), slot)
 
     # -- paged-pool bookkeeping (page_size > 0) ------------------------------
     def _pop_free_page(self) -> int | None:
@@ -1451,6 +1418,8 @@ class Generator:
             "evictions": self.evictions,
             "chunked_prefills": len(self._chunked),
             "prefill_segments": self.prefill_segments_run,
+            "prefill_tokens_real": self.prefill_tokens_real,
+            "prefill_tokens_padded": self.prefill_tokens_padded,
             "prefetch_errors": self.prefetch_errors,
             "restarts": self.restarts,
         }
@@ -2133,7 +2102,9 @@ class Generator:
 
     def warmup(self) -> None:
         """Compile the decode programs (full chunk + TTFT mini-chunk) and
-        the prefill buckets before the first request — a lazy first-use
+        a one-row prefill program for every length of ``prefill_buckets``
+        (inventory rows ``prefill/1x<L>``) before the first request, so
+        that no admissible prompt compiles afterwards — a lazy first-use
         compile would land on exactly the TTFT path the mini-chunk exists
         to shorten. All slots are dead during warmup, so the sampled
         garbage never reaches bookkeeping; admission overwrites slot state.
@@ -2213,10 +2184,10 @@ class Generator:
             for bucket in self.prefill_buckets:
                 padded = np.zeros((1, bucket), np.int32)
                 ones = np.array([1], np.int32)
-                # the bucket's whole warm block (prefill + first-token
-                # sampling [+ the wave shapes]) is one inventory row: its
-                # wall is what a cold restart pays for this bucket; the
-                # lazy cost analysis covers the main prefill program
+                # the length's whole warm block (prefill + first-token
+                # sampling) is one inventory row: its wall is what a cold
+                # restart pays for it; the lazy cost analysis covers the
+                # prefill program
                 t0 = time.perf_counter()
                 with watch_compiles() as acc:
                     if self.page_size:
@@ -2232,25 +2203,11 @@ class Generator:
                     abstract = abstractify(args)
                     logits, self.cache = fn(*args)
                     self._after_prefill(logits, padded, ones, np.int32(0))
-                    if self._admit_cap > 1:  # the wave-admission shapes too
-                        b = self._admit_cap
-                        toks_b = np.zeros((b, bucket), np.int32)
-                        lens_b = np.ones((b,), np.int32)
-                        slots_b = np.zeros((b,), np.int32)
-                        dead = np.zeros((b,), bool)  # all masked: no writes
-                        logits, self.cache = self._prefill_many(
-                            self.params, toks_b, lens_b, self.cache,
-                            slots_b, dead,
-                        )
-                        self._after_prefill(logits, toks_b, lens_b, slots_b,
-                                            dead)
                 self.programs.record(
-                    f"prefill/b{bucket}",
+                    f"prefill/1x{bucket}",
                     wall_s=time.perf_counter() - t0, acc=acc,
-                    shapes={"tokens": [1, bucket],
-                            "wave": (self._admit_cap
-                                     if self._admit_cap > 1 else None)},
-                    fn=fn, abstract=abstract)
+                    shapes={"tokens": [1, bucket]}, fn=fn,
+                    abstract=abstract)
             if self._sp is not None:
                 # the SP prefill program for every bucket the dual-path
                 # threshold can route to: a cold first long prompt must
@@ -2394,11 +2351,13 @@ class Generator:
 
     def add_requests(self, requests) -> list[int]:
         """Admit a WAVE of requests — ``[(prompt_ids, max_new, callback)]``
-        — with as few device programs as possible. Remote transports charge
-        ~100 ms dispatch overhead per program; N per-request prefills ahead
-        of the first decode chunk cost N× that in TTFT, a batched wave pays
-        it once (llama.prefill_into_many). Waves larger than the admission
-        cap split; a wave of 2..cap-1 pads to cap with masked rows.
+        — all or none. Every prompt is prefilled by a one-row program of
+        its own, in the smallest of ``prefill_buckets`` that holds it (the
+        dense layout's ladder: 128, 256, 512, 768, 1024, powers of two up
+        to ``max_seq``); the programs are launched back to back, so the
+        device queue holds the whole wave while the host goes on. A
+        program costs some 5 ms on the chip, a padded token some 40 us:
+        padding a wave to its longest prompt costs more than it saves.
 
         Admission stays fully ASYNC: sampled first tokens stay on device in
         ``_tok_dev`` and their values reach the host in row 0 of the next
@@ -2445,7 +2404,6 @@ class Generator:
                     for r in requests]
 
         out: list[int] = []
-        slots: list[int] = []
         try:
             return self._admit_waves(prepped, out)
         except Exception:
@@ -2668,55 +2626,45 @@ class Generator:
                 return
 
     def _admit_waves(self, prepped, out: list[int]) -> list[int]:
+        """Admit a wave prompt by prompt: reserve a slot, launch the
+        prompt's own one-row prefill and its first-token sampler in the
+        smallest of ``prefill_buckets`` that holds it, and go on to the
+        next with no host sync: the device queue holds the wave."""
         if self.fault is not None and prepped:
             self.fault("prefill")
-        for start in range(0, len(prepped), self._admit_cap):
-            wave = prepped[start:start + self._admit_cap]
-            sp_used = False  # this wave prefilled sequence-parallel
-            slots = []
-            for _ in wave:
-                i = self.free_slot()
-                if i is None:  # unreachable after the capacity pre-check
-                    for j in slots:
-                        self.slots[j].live = False
-                    raise RuntimeError("no free generation slot")
-                slots.append(i)
-                self.slots[i].live = True  # reserve within this wave
-            b = 1 if len(wave) == 1 else self._admit_cap
-            s_bucket = next(
-                (s for s in self.prefill_buckets
-                 if all(n <= s for _, n, _, _ in wave)), self.max_seq)
-            tokens = np.zeros((b, s_bucket), np.int32)
-            lens = np.ones((b,), np.int32)
-            valid = np.zeros((b,), bool)
-            slot_arr = np.full((b,), slots[0], np.int32)
-            for row, (ids, n, _, _) in enumerate(wave):
-                tokens[row, :n] = ids
-                lens[row] = n
-                valid[row] = True
-                slot_arr[row] = slots[row]
+        for ids, n, max_new, callback in prepped:
+            slot = self.free_slot()
+            if slot is None:  # unreachable after the capacity pre-check
+                raise RuntimeError("no free generation slot")
+            self.slots[slot].live = True  # reserved
+            sp_used = False  # this prompt prefilled sequence-parallel
+            s_bucket = next((s for s in self.prefill_buckets if n <= s),
+                            self.max_seq)
+            tokens = np.zeros((1, s_bucket), np.int32)
+            tokens[0, :n] = ids
+            lens = np.array([n], np.int32)
             try:
                 with self._mesh_ctx():
+                    logits = None
                     if self.page_size:
-                        if self._slot_shared[slots[0]]:
+                        if self._slot_shared[slot]:
                             # previous occupant borrowed prefix pages:
                             # reusing its list would write INTO the shared
                             # prefix — reset to a fresh own-page list
-                            self._free_slot_pages(slots[0])
+                            self._free_slot_pages(slot)
                         # admission control: no pages, no slot — the
                         # caller requeues on PagePoolExhausted instead of
                         # risking a silent mid-generation eviction. The
                         # estimate never exceeds the request's own budget.
-                        upto = min(int(lens[0]) + 2 * self.chunk,
-                                   int(lens[0]) + wave[0][2],
+                        upto = min(n + 2 * self.chunk, n + max_new,
                                    self.max_seq)
-                        if not self._alloc_pages_to(slots[0], upto):
+                        if not self._alloc_pages_to(slot, upto):
                             # reclaim idle prefixes before declaring
                             # back-pressure (see _admit_prefixed)
                             missing = (-(-upto // self.page_size)
-                                       - len(self._slot_pages[slots[0]]))
+                                       - len(self._slot_pages[slot]))
                             self._reclaim_prefix_pages(max(missing, 1))
-                        if not self._alloc_pages_to(slots[0], upto):
+                        if not self._alloc_pages_to(slot, upto):
                             need = -(-upto // self.page_size)
                             if need > self._pages_ever_free():
                                 raise ValueError(
@@ -2728,68 +2676,58 @@ class Generator:
                                 f"({self.free_pages} pages free)")
                         row = np.zeros((s_bucket // self.page_size,),
                                        np.int32)
-                        pages = self._slot_pages[slots[0]]
+                        pages = self._slot_pages[slot]
                         row[:min(len(pages), len(row))] = \
                             pages[:len(row)]
-                        logits = None
-                        if self._sp_eligible(int(lens[0])):
+                        if self._sp_eligible(n):
                             logits = self._run_sp_prefill(
-                                tokens, lens, row, slots[0])
+                                tokens, lens, row, slot)
                             sp_used = logits is not None
                         if logits is None:
                             logits, self.cache = self._prefill_paged(
                                 self.params, tokens, lens, self.cache,
-                                row, np.int32(slots[0]),
+                                row, np.int32(slot),
                             )
-                        self._after_prefill(logits, tokens, lens,
-                                            np.int32(slots[0]))
-                    elif b == 1:
-                        logits = None
-                        if self._sp_eligible(int(lens[0])):
+                    else:
+                        if self._sp_eligible(n):
                             logits = self._run_sp_prefill(
-                                tokens, lens, None, slots[0])
+                                tokens, lens, None, slot)
                             sp_used = logits is not None
                         if logits is None:
-                            logits, self.cache = self._prefill_into(
-                                self.params, tokens, lens, self.cache,
-                                np.int32(slots[0]),
-                            )
-                        self._after_prefill(logits, tokens, lens,
-                                            np.int32(slots[0]))
-                    else:
-                        logits, self.cache = self._prefill_many(
-                            self.params, tokens, lens, self.cache, slot_arr,
-                            valid,
-                        )
-                        self._after_prefill(logits, tokens, lens, slot_arr,
-                                            valid)
+                            with phase(self.recorder, "launch",
+                                       kind="prefill", rows=1, seq=s_bucket,
+                                       real_tokens=n):
+                                logits, self.cache = self._prefill_into(
+                                    self.params, tokens, lens, self.cache,
+                                    np.int32(slot),
+                                )
+                            self.prefill_tokens_real += n
+                            self.prefill_tokens_padded += s_bucket
+                    self._after_prefill(logits, tokens, lens, np.int32(slot))
             except Exception:
-                for j in slots:  # unwind this wave's reservations
-                    self.slots[j].live = False
-                    if self.page_size:
-                        self._free_slot_pages(j)
+                self.slots[slot].live = False  # unwind the reservation
+                if self.page_size:
+                    self._free_slot_pages(slot)
                 raise
-            self._n_requests += len(wave)
-            for slot, (_ids, n, max_new, callback) in zip(slots, wave,
-                                                           strict=True):
-                self._pending_first.append(slot)
-                s = _Slot()
-                s.live = True
-                s.tokens = []
-                s.max_new = max_new
-                s.produced = 1  # the pending first token counts as sampled
-                s.prompt_len = n
-                s.eos_hit = False
-                s.callback = callback
-                if sp_used:
-                    # journey marks and the sp debug block read the shard
-                    # count off the slot — admission is the one moment
-                    # the SP-vs-plain decision is known
-                    s.sp_shards = self._sp.shards
-                if self._plain_armed:
-                    s.hist = [int(t) for t in _ids]
-                self.slots[slot] = s
-            out.extend(slots)
+            self._n_requests += 1
+            self._pending_first.append(slot)
+            s = _Slot()
+            s.live = True
+            s.tokens = []
+            s.max_new = max_new
+            s.produced = 1  # the pending first token counts as sampled
+            s.prompt_len = n
+            s.eos_hit = False
+            s.callback = callback
+            if sp_used:
+                # journey marks and the sp debug block read the shard
+                # count off the slot — admission is the one moment
+                # the SP-vs-plain decision is known
+                s.sp_shards = self._sp.shards
+            if self._plain_armed:
+                s.hist = [int(t) for t in ids]
+            self.slots[slot] = s
+            out.append(slot)
         return out
 
     def _resolve_first(self, tok_in_row: np.ndarray) -> None:

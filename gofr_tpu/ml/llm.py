@@ -435,8 +435,8 @@ class LLMServer:
             return None
         self._enqueue_waiting(req)
         # collect the rest of the burst before admitting: concurrent
-        # clients arrive over a few ms, and one wave (one batched
-        # prefill + one mini-chunk) gives every stream the first
+        # clients arrive over a few ms, and one wave (its prefills
+        # back to back + one mini-chunk) gives every stream the first
         # wave's TTFT instead of the second's
         deadline = time.perf_counter() + self._admit_window
         while True:
@@ -1039,8 +1039,9 @@ class LLMServer:
             # slots, never consume the ones we just saw.
             self.gen.drain()
             self._finish_dead_slots()
-            # admit everything that fits as ONE wave: a batched prefill pays
-            # the per-program dispatch overhead once for the whole burst.
+            # admit everything that fits as ONE wave: the generator launches
+            # one prefill program a prompt, back to back, and the device
+            # queue holds them all ahead of the next decode chunk.
             # Paged mode admits one request per call instead — add_requests
             # is all-or-nothing, so a multi-request batch that hit
             # PagePoolExhausted on its LAST member would unwind the

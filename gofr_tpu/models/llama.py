@@ -733,37 +733,6 @@ def prefill_into(params: dict, tokens: jnp.ndarray, seq_lens: jnp.ndarray,
     return logits, new_cache
 
 
-def prefill_into_many(params: dict, tokens: jnp.ndarray,
-                      seq_lens: jnp.ndarray, cfg: LlamaConfig, cache: dict,
-                      slots: jnp.ndarray, valid: jnp.ndarray, mesh=None
-                      ) -> tuple[jnp.ndarray, dict]:
-    """Prefill a WAVE of B prompts [B, S_pad] into rows ``slots`` [B] of the
-    shared cache in ONE program. Remote transports charge ~100 ms of
-    dispatch overhead per execution, so admitting N requests as N separate
-    prefill programs serializes N×overhead ahead of the first decode chunk
-    — batching the wave pays the overhead once. ``valid`` masks padding
-    rows (B is a shape bucket): an invalid row writes its target slot's
-    existing contents back, so it clobbers nothing.
-    """
-    b = tokens.shape[0]
-    logits, filled = prefill(params, tokens, seq_lens, cfg,
-                             init_cache(cfg, b, cache["k"].shape[2]),
-                             mesh=mesh)
-    arrays = {key: cache[key] for key in cache if key != "len"}
-    lens = cache["len"]
-    for i in range(b):  # static B: unrolled scatter, one row per request
-        slot = slots[i]
-        for key, arr in arrays.items():
-            row = jnp.where(valid[i], filled[key][:, i],
-                            jax.lax.dynamic_index_in_dim(arr, slot, axis=1,
-                                                         keepdims=False))
-            arrays[key] = jax.lax.dynamic_update_index_in_dim(
-                arr, row, slot, axis=1)
-        lens = lens.at[slot].set(
-            jnp.where(valid[i], seq_lens[i], lens[slot]))
-    return logits, {**arrays, "len": lens}
-
-
 def prefill_segment_into(params: dict, tokens: jnp.ndarray,
                          seg_len: jnp.ndarray, cfg: LlamaConfig,
                          cache: dict, slot: jnp.ndarray, start: jnp.ndarray,

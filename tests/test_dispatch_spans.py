@@ -258,22 +258,21 @@ def _jitted(gen):
 def test_every_serving_program_has_a_name_of_its_own(model, kw):
     gen = _gen(model, **kw)
     names = {fn.__name__ for fn in _jitted(gen)}
-    assert len(names) >= 5
+    assert len(names) >= 3  # dense: chunk_fn, prefill_into, post_prefill
     for name in names:
         assert "lambda" not in name and name != "f", names
     prefill = {n for n in names if "prefill" in n}
     assert not any("chunk_fn" in n for n in prefill)
     assert ("paged_chunk_fn" if kw.get("page_size") else "chunk_fn") in names
     want = ({"paged_prefill", "suffix_prefill", "prefix_prefill"}
-            if kw.get("page_size") else set()) | {"prefill_into",
-                                                  "prefill_many"}
+            if kw.get("page_size") else set()) | {"prefill_into"}
     if kw.get("prefill_chunk"):
         want.add("paged_segment_prefill" if kw.get("page_size")
                  else "segment_prefill")
     assert want <= prefill, (want, prefill)
     # the name is the compiled module's, so the trace's: jit_<name>
-    fn = gen._prefill_many
-    assert fn.__name__ == "prefill_many"
+    fn = gen._prefill_into
+    assert fn.__name__ == "prefill_into"
 
 
 def test_model_parts_carry_named_scopes(model):

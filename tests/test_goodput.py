@@ -415,7 +415,7 @@ def test_programs_inventory_ladder_and_buckets(model):
     gen.warmup()
     rows = {r["name"]: r for r in gen.programs.snapshot()}
     assert "decode/chunk4" in rows and "decode/chunk1" in rows
-    assert "prefill/b8" in rows and "prefill/b16" in rows
+    assert "prefill/1x64" in rows  # dense: the ladder of max_seq 64
     for row in rows.values():
         assert row["wall_s"] > 0
         assert row["cache"] in ("compiled", "persistent_cache", "cached",

@@ -132,7 +132,9 @@ def test_unset_env_builds_no_sp_machinery(setup):
     assert gen._sp is None
     assert gen.sp_stats() is None
     assert not hasattr(gen, "_sp_prefill_into")
-    assert gen._admit_cap > 1  # the wave-admission path is untouched
+    # the dense layout's own one-row ladder, not the buckets given
+    assert gen.prefill_buckets == (64,)
+    assert gen._prefill_into.__name__ == "prefill_into"
     # sp=False wins over an armed env (explicit opt-out)
     import os
     os.environ["GOFR_ML_SP"] = "ring"
