@@ -292,6 +292,18 @@ def _jit(name: str, fn, **jit_kwargs):
     return jax.jit(fn, **jit_kwargs)
 
 
+def _model_of(cfg):
+    """The model module that ``cfg`` belongs to, by its type: the one
+    place the serving loop chooses between families. The dense slot
+    layout takes ``init_cache``, ``prefill_into`` and ``decode_step`` from
+    it; every other layout is the llama block's."""
+    from ..models import llama, qwen3_next
+
+    if isinstance(cfg, qwen3_next.Qwen3NextConfig):
+        return qwen3_next
+    return llama
+
+
 class Generator:
     """Continuous-batching decode loop over a fixed slot batch.
 
@@ -328,7 +340,7 @@ class Generator:
 
         from ..models import llama
 
-        self._m = llama
+        model = self._m = _model_of(cfg)
         self._mesh_ctx = (lambda: mesh) if mesh is not None else contextlib.nullcontext
         self.params = params
         self.cfg = cfg
@@ -430,7 +442,8 @@ class Generator:
         self.spec_reprobes = 0    # cooldown expiries re-arming a slot
         self._plain_armed = False  # set in _init_spec (lookup mode only)
         self._spec_rows_stale = False  # device history lags the mirror
-        if getattr(cfg, "kv_bits", 16) == 4 and not page_size:
+        if (model is llama and getattr(cfg, "kv_bits", 16) == 4
+                and not page_size):
             raise ValueError(
                 "kv_bits=4 (int4 KV) requires the paged cache — set "
                 "page_size > 0")
@@ -444,8 +457,25 @@ class Generator:
         # mesh (built over the visible devices) and is validated loudly
         # HERE: shard bounds, bucket/max_seq divisibility, Ulysses head
         # divisibility, and mode conflicts all reject at construction.
+        from .sp_serving import SPConfig
         from .sp_serving import resolve as _resolve_sp
 
+        if model is not llama:
+            # another family serves from the dense slot layout alone; what
+            # else was asked for, here or in the environment, is refused
+            # with what it would take
+            asked = {"page_size": page_size,
+                     "sp": sp or (sp is None
+                                  and SPConfig.from_env() is not None),
+                     "spec_k": self.spec_k or draft_params is not None,
+                     "kv_bits": getattr(cfg, "kv_bits", 16) != 16,
+                     "prefill_chunk": prefill_chunk,
+                     "mesh": mesh is not None or shard_cache}
+            for what, on in asked.items():
+                if on:
+                    raise ValueError(
+                        f"{type(cfg).__name__} is not served with {what} "
+                        f"yet: {model.UNSUPPORTED[what]}")
         self._sp = _resolve_sp(
             sp, cfg=cfg, mesh=mesh, prefill_buckets=self.prefill_buckets,
             max_seq=max_seq, page_size=int(page_size), spec_k=self.spec_k,
@@ -609,6 +639,8 @@ class Generator:
         self._inflight: collections.deque = collections.deque()  # [chunk, B] arrays
         self._pending_first: collections.deque = collections.deque()  # (slot, dev scalar)
         self.steps = 0
+        self.settled = 0  # dispatches read back (_pop_process): the serving
+        # loop reads its queue where this moved, after a wait on the device
         self.restarts = 0  # successful crash recoveries (recover())
         # chaos hook (testutil/faults.py): the serving layer installs a
         # FaultInjector here when GOFR_ML_FAULT is set; every instrumented
@@ -665,7 +697,7 @@ class Generator:
 
                 def body(carry, j):
                     tok, cache = carry
-                    logits, cache = llama.decode_step(params, tok, cache,
+                    logits, cache = model.decode_step(params, tok, cache,
                                                       decode_cfg, mesh=mesh)
                     key = jax.random.fold_in(base_key, step0 + j)
                     nxt = _sample_impl(logits, key, sampler_cfg)
@@ -852,6 +884,11 @@ class Generator:
             return host_visible(tok_dev.at[slot].set(first))
 
         self._post_prefill = jax.jit(post_prefill, donate_argnums=(0,))
+        if "moe_counts" in self.cache:
+            self._copy_counts = _jit("copy_counts", lambda a: jnp.copy(a))
+            # what ``pool_stats()`` reads until the first dispatch: zeros
+            self._counts_dev = np.zeros(self.cache["moe_counts"].shape,
+                                        np.uint32)
         if self.page_size:
             ps = self.page_size
             self._prefill_paged = _jit(
@@ -876,7 +913,7 @@ class Generator:
                                                        False)
         self._prefill_into = _jit(
             "prefill_into",
-            lambda p, t, l, c, slot: llama.prefill_into(p, t, l, cfg, c, slot,
+            lambda p, t, l, c, slot: model.prefill_into(p, t, l, cfg, c, slot,
                                                         mesh=mesh),
             donate_argnums=(3,),
         )
@@ -1450,7 +1487,32 @@ class Generator:
                 kv_restore_fallbacks=self.kv_restore_fallbacks,
                 prefix_prefills=self.prefix_prefills,
             )
+        if "moe_counts" in self.cache:
+            # a family with recurrent state and routed experts: how the
+            # slots' memory divides, and what routing did so far
+            cache = dict(self.cache)
+            out.update(
+                recurrent_state_bytes=int(cache["state"].nbytes
+                                          + cache["conv"].nbytes),
+                kv_cache_bytes=int(cache["k"].nbytes + cache["v"].nbytes),
+                **self._expert_counts())
         return out
+
+    def _keep_counts(self) -> None:
+        """After a dispatch, on the serving thread: a copy of the model's
+        routing counters in a buffer of its own. The cache they are summed
+        in is donated to every program, so a reader on another thread
+        could never hold it; the copy is a program of a few bytes a
+        dispatch, and no transfer."""
+        self._counts_dev = self._copy_counts(self.cache["moe_counts"])
+
+    def _expert_counts(self) -> dict:
+        """The model's routing counters (its ``MOE_COUNTERS``) as the last
+        dispatch left them: fetched from the device only here."""
+        words = np.asarray(self._counts_dev)
+        return {name: int(low) | int(high) << 32
+                for name, (low, high) in zip(self._m.MOE_COUNTERS, words,
+                                             strict=True)}
 
     # -- shared-prefix prefill (paged mode) ----------------------------------
     def register_prefix(self, prefix_ids, pinned: bool = False) -> int:
@@ -2238,6 +2300,8 @@ class Generator:
                         shapes={"tokens": [1, bucket],
                                 "shards": self._sp.shards},
                         fn=fn, abstract=abstract)
+        if "moe_counts" in self.cache:
+            self._keep_counts()  # builds the counters' copy program
         # a real device->host fetch: it returns only once every queued
         # warm-up dispatch has drained, so the first live request's token
         # fetch cannot absorb the warm-up queue — the TTFT hit warmup
@@ -2943,6 +3007,8 @@ class Generator:
                         np.int32(self.steps), self._base_key,
                         *((table,) if self.page_size else ()))
                     meta = None
+                    if "moe_counts" in self.cache:
+                        self._keep_counts()
                 self.steps += n_steps
                 if self.spec_k and not use_spec:
                     # a plain dispatch leaves the device drafting rows
@@ -3010,6 +3076,7 @@ class Generator:
         at launch) feeds the launch→settle span into the recorder's
         host-idle estimate."""
         kind, item, meta, stamp = self._inflight.popleft()
+        self.settled += 1
         rec = self.recorder
         with phase(rec, "device_wait") as wait:
             host = (np.asarray(item) if kind == "chunk"

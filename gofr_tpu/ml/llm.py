@@ -192,6 +192,7 @@ class LLMServer:
                    if isinstance(prefix_cache, PrefixCacheConfig) else None)
             self.prefix_cache = RadixPrefixCache(
                 generator, cfg, metrics=metrics, model=name)
+        self._ended_at = -1  # gen.settled when a stream was last ended
         self._idle_wait = idle_wait_s
         self._idle_backoff = idle_wait_s
         self._admit_window = admit_window_s
@@ -375,6 +376,7 @@ class LLMServer:
                     return
                 if self.gen.n_live:
                     self.gen.step()
+                    self._take_arrivals()
                     self._finish_dead_slots()
                     self._steer()
                     if rec is not None:
@@ -437,10 +439,16 @@ class LLMServer:
         # collect the rest of the burst before admitting: concurrent
         # clients arrive over a few ms, and one wave (its prefills
         # back to back + one mini-chunk) gives every stream the first
-        # wave's TTFT instead of the second's
-        deadline = time.perf_counter() + self._admit_window
-        while True:
-            remaining = deadline - time.perf_counter()
+        # wave's TTFT instead of the second's. The burst is over when
+        # nothing has come for the admit window, when every free slot has
+        # a taker, or at the idle backoff's ceiling: a fixed window from
+        # the first arrival cut a burst of 128 at 41 to 66, as the
+        # transport happened to deliver
+        free = sum(not s.live for s in self.gen.slots)
+        deadline = time.perf_counter() + max(0.05, self._idle_wait)
+        while len(self._waiting) < free:
+            remaining = min(self._admit_window,
+                            deadline - time.perf_counter())
             if remaining <= 0:
                 return True
             try:
@@ -451,6 +459,7 @@ class LLMServer:
                 self._closed = True
                 return None
             self._enqueue_waiting(more)
+        return True
 
     def _run_setup_tasks(self) -> None:
         """Drain device-touching setup work (e.g. register_prefix) onto
@@ -1008,17 +1017,34 @@ class LLMServer:
         retry — scheduler.retry_after_s over this instance's window."""
         return retry_after_s(self._admit_times, len(self._waiting))
 
-    def _admit_waiting(self) -> None:
-        # pull everything queued, then admit as long as slots are free
+    def _take_arrivals(self) -> None:
+        """Move what has arrived from the queue to the waiting list: at
+        the top of a pass and, in a busy one, once more after the step's
+        wait on the device and BEFORE the finished streams are ended. Where a
+        stream was ended and the device has not been waited for since,
+        the queue is left alone: a client that answers the end of its
+        stream at once is seen after the next wait on the device, a
+        program's run later, in every pass alike. Read a millisecond
+        after the finish markers, whether such a request caught the very
+        next launch was a race between this thread and the transport's,
+        and at 128 streams it came out either way, run by run."""
+        if self._ended_at == self.gen.settled:
+            return
         while True:
             try:
                 req = self._requests.get_nowait()
             except _queue.Empty:
-                break
+                return
             if req is None:
                 self._closed = True
                 return
             self._enqueue_waiting(req)
+
+    def _admit_waiting(self) -> None:
+        # pull what is queued, then admit as long as slots are free
+        self._take_arrivals()
+        if self._closed:
+            return
         while len(self._waiting):
             if self._draining:
                 # graceful drain (close(drain_s=)): in-flight decode keeps
@@ -1461,6 +1487,7 @@ class LLMServer:
         for slot, req in list(self._active.items()):
             s = self.gen.slots[slot]
             if not s.live:
+                self._ended_at = self.gen.settled  # see _take_arrivals
                 if req.deadline_hit:
                     # cancelled mid-generation by its deadline: free the
                     # slot (pages with it) and complete with the typed

@@ -492,6 +492,10 @@ def _decode_layer(cfg: LlamaConfig, x, lp, cos, sin, arrays, layer,
     """
     b = x.shape[0]
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    # a row that has sat at capacity (decode_step caps its ``len`` at
+    # S_max) must not ask the kernels for S_max + 1 keys: the Pallas decode
+    # kernel would fetch a block past the end of the cache
+    kv_len = jnp.minimum(pos + 1, arrays["k"].shape[2])
 
     with jax.named_scope("attention"):
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
@@ -524,11 +528,11 @@ def _decode_layer(cfg: LlamaConfig, x, lp, cos, sin, arrays, layer,
                 from ..parallel.ring import sp_decode_attention
 
                 o = sp_decode_attention(
-                    q, arrays["k"], arrays["v"], pos + 1, mesh, layer=layer,
+                    q, arrays["k"], arrays["v"], kv_len, mesh, layer=layer,
                     k_scale=arrays["k_scale"], v_scale=arrays["v_scale"])
             else:
                 o = cached_decode_attention(
-                    q, arrays["k"], arrays["v"], pos + 1, layer=layer,
+                    q, arrays["k"], arrays["v"], kv_len, layer=layer,
                     use_kernel=cfg.use_flash,
                     k_scale=arrays["k_scale"], v_scale=arrays["v_scale"])
         else:
@@ -541,10 +545,10 @@ def _decode_layer(cfg: LlamaConfig, x, lp, cos, sin, arrays, layer,
                 # pmax/psum combine (parallel/ring.py) — no cache all-gather
                 from ..parallel.ring import sp_decode_attention
 
-                o = sp_decode_attention(q, arrays["k"], arrays["v"], pos + 1,
+                o = sp_decode_attention(q, arrays["k"], arrays["v"], kv_len,
                                         mesh, layer=layer)
             else:
-                o = cached_decode_attention(q, arrays["k"], arrays["v"], pos + 1,
+                o = cached_decode_attention(q, arrays["k"], arrays["v"], kv_len,
                                             layer=layer,
                                             use_kernel=cfg.use_flash)
 

@@ -13,6 +13,15 @@ the MXU wants batched matmuls, not per-token gathers:
   projection. Sharding rule ``P("ep", ...)`` puts experts on their own mesh
   axis and the dispatch/combine einsums become XLA all_to_alls over ICI;
 - combine: weighted scatter back, zeros for dropped tokens.
+
+Serving uses the dropless layer beside it (``route_top_k``,
+``dropless_experts``, ``gated_shared_expert``): no capacity, no dropped
+token. It is told which experts this chip ``held``: the router keeps its
+full width, top-k and the renormalisation run over every expert, and only
+the held experts' terms are computed and added (one chip's share of an
+expert-parallel layer, without the exchange). Tokens are sorted by expert
+and each projection is one grouped product over the held experts
+(``jax.lax.ragged_dot``, a native grouped matmul on the TPU).
 """
 
 from __future__ import annotations
@@ -24,7 +33,8 @@ import jax.numpy as jnp
 
 from ..parallel import P, constrain
 
-__all__ = ["MoEConfig", "init_moe_params", "moe_layer", "MOE_SHARDING_RULES"]
+__all__ = ["MoEConfig", "init_moe_params", "moe_layer", "MOE_SHARDING_RULES",
+           "route_top_k", "dropless_experts", "gated_shared_expert"]
 
 
 class MoEConfig:
@@ -121,3 +131,73 @@ def moe_layer(params: dict, x: jnp.ndarray, cfg: MoEConfig
     out = constrain(out, P("ep", None, None))
     y = jnp.einsum("ecd,nec->nd", out.astype(jnp.float32), combine)
     return y.reshape(b, s, d).astype(x.dtype), aux
+
+
+# ------------------------------------------------------- dropless (serving)
+def route_top_k(x: jnp.ndarray, router: jnp.ndarray, top_k: int):
+    """x [N, D], router [D, E] -> (weights [N, k] float32 summing to 1,
+    experts [N, k] int32). Logits and softmax in float32 at full matmul
+    precision: with weights this close, a bfloat16 pass flips members of
+    the top-k."""
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    vals, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    return vals / vals.sum(-1, keepdims=True), idx
+
+
+def dropless_experts(x: jnp.ndarray, weights: jnp.ndarray, idx: jnp.ndarray,
+                     w_gate_up: jnp.ndarray, w_down: jnp.ndarray,
+                     held: tuple[int, int], valid: jnp.ndarray | None = None,
+                     layer=None):
+    """The held experts' part of ``sum_e w_e * down_e(silu(gate_e x) *
+    up_e x)``. ``x`` [N, D]; ``weights``, ``idx`` [N, k] from
+    ``route_top_k``; ``w_gate_up`` [held, D, 2F] (gate | up), ``w_down``
+    [held, F, D]; ``held`` = (first, count) of the router's experts whose
+    weights these are; ``valid`` [N] leaves padding out. With ``layer`` (a
+    traced index) the weights are a whole stack's, [layers * held, ...]:
+    the grouped product takes the stack as it lies and finds every other
+    layer's groups empty, where a slice of one layer would first be copied
+    out (the product is a kernel; a slice does not fuse into it). Returns
+    ``y`` [N, D] and int32 counts (pairs routed, pairs that fell on held
+    experts, held experts with at least one token)."""
+    n, k = idx.shape
+    first, count = held
+    pairs = n * k
+    local = idx.reshape(pairs) - first
+    real = (jnp.ones((n,), bool) if valid is None else valid)
+    mine = (local >= 0) & (local < count) & jnp.repeat(real, k)
+    # pairs sorted by held expert; everything else goes behind the last
+    key = jnp.where(mine, local, count)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0,
+                    dtype=jnp.int32)
+    groups = sizes
+    if layer is not None:
+        groups = jax.lax.dynamic_update_slice(
+            jnp.zeros((w_gate_up.shape[0],), jnp.int32), sizes,
+            (layer * count,))
+    xs = x[order // k]
+    h = jax.lax.ragged_dot(xs, w_gate_up, groups)
+    f = w_down.shape[1]
+    y = jax.lax.ragged_dot(jax.nn.silu(h[:, :f]) * h[:, f:], w_down, groups)
+    # rows behind the last group belong to no expert: whatever the grouped
+    # product left there is not a term of the sum
+    n_mine = jnp.sum(sizes)
+    y = jnp.where((jnp.arange(pairs) < n_mine)[:, None], y, 0)
+    back = jnp.zeros((pairs,), jnp.int32).at[order].set(jnp.arange(pairs))
+    y = jnp.einsum("nkd,nk->nd", y[back].reshape(n, k, -1).astype(jnp.float32),
+                   weights * mine.reshape(n, k))
+    stats = (jnp.sum(real, dtype=jnp.int32) * k, n_mine,
+             jnp.sum(sizes > 0, dtype=jnp.int32))
+    return y.astype(x.dtype), stats
+
+
+def gated_shared_expert(x: jnp.ndarray, s_gate_up: jnp.ndarray,
+                        s_down: jnp.ndarray, s_mix: jnp.ndarray):
+    """``sigmoid(x . s_mix) * down(silu(gate x) * up x)``: the expert every
+    token meets, computed whole on every chip."""
+    h = x @ s_gate_up
+    f = s_down.shape[0]
+    y = (jax.nn.silu(h[:, :f]) * h[:, f:]) @ s_down
+    mix = jax.nn.sigmoid(x.astype(jnp.float32) @ s_mix.astype(jnp.float32))
+    return (y * mix[:, None].astype(y.dtype)).astype(x.dtype)

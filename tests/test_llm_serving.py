@@ -117,6 +117,95 @@ def test_chunked_decode_slot_reuse_no_hang(model, run):
         assert got == want
 
 
+def test_answer_to_a_stream_end_is_seen_at_the_next_settle(model, run):
+    """A client that answers the end of its stream at once (here: from
+    inside the serving thread, the moment the finish marker is sent) is
+    admitted after the next dispatch has settled, never in the pass that
+    follows the finish: the queue is read before streams end, so which
+    launch such a request catches does not hang on a race between the
+    serving thread and the transport's."""
+    from gofr_tpu.ml.llm import _Finish, _Request
+
+    cfg, params = model
+    expect = _expected(params, cfg, [5, 6], 4)
+
+    async def scenario():
+        gen = Generator(params, cfg, batch_slots=2, max_seq=64,
+                        prefill_buckets=(8,), chunk=2)
+        server = LLMServer(gen)
+        loop = asyncio.get_running_loop()
+        answer_q: asyncio.Queue = asyncio.Queue()
+        seen = {}
+        finish, add = server._finish_dead_slots, gen.add_requests
+
+        def finish_then_answer():
+            served = server.served
+            finish()
+            if server.served > served and "sent_at" not in seen:
+                seen["sent_at"] = gen.steps
+                server._requests.put(_Request([5, 6], 4, answer_q, loop))
+
+        def add_and_note(requests):
+            if "sent_at" in seen and "admitted_at" not in seen:
+                seen["admitted_at"] = gen.steps
+            return add(requests)
+
+        server._finish_dead_slots = finish_then_answer
+        gen.add_requests = add_and_note
+        try:
+            long = asyncio.ensure_future(server.generate([1, 2, 3], 24))
+            short = await server.generate([7, 8], 3)
+            tokens = []
+            while True:
+                item = await asyncio.wait_for(answer_q.get(), timeout=60)
+                if isinstance(item, _Finish):
+                    break
+                tokens.extend(item)
+            await long
+            return short, tokens, seen
+        finally:
+            server.close()
+
+    short, tokens, seen = run(scenario())
+    assert len(short) == 3
+    assert tokens == expect
+    assert seen["admitted_at"] > seen["sent_at"]
+
+
+def test_idle_burst_is_collected_until_it_is_over(model, run):
+    """Requests that reach an idle server one after another, each within
+    the admit window of the last but over a longer span than one window,
+    are one admission wave: the burst ends when nothing has come for a
+    window (or every free slot has a taker), not a window after its first
+    request."""
+    cfg, params = model
+    prompts = [[i + 1, i + 2] for i in range(4)]
+
+    async def scenario():
+        gen = Generator(params, cfg, batch_slots=4, max_seq=64,
+                        prefill_buckets=(8,))
+        server = LLMServer(gen, idle_wait_s=1.0, admit_window_s=0.25)
+        waves, add = [], gen.add_requests
+
+        def add_and_note(requests):
+            waves.append(len(requests))
+            return add(requests)
+
+        gen.add_requests = add_and_note
+
+        async def later(i, p):
+            await asyncio.sleep(0.1 * i)  # 0.3 s in all, over one window
+            return await server.generate(p, 3)
+
+        try:
+            await asyncio.gather(*(later(i, p) for i, p in enumerate(prompts)))
+            return waves
+        finally:
+            server.close()
+
+    assert run(scenario()) == [4]
+
+
 def test_bad_prompt_raises_not_hangs(model, run):
     cfg, params = model
 

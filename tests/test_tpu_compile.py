@@ -107,3 +107,39 @@ def test_decode_step_compiles_with_kernel(chip, monkeypatch):
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < 16 * 2**30)
+
+
+def test_hybrid_decode_step_compiles_with_kernel_and_one_loop(chip,
+                                                              monkeypatch):
+    """``qwen3_next.decode_step`` at the published widths (two periods, 16
+    held experts a layer): the attention layers take the Pallas kernel at
+    head size 256 with a group of 8; the step is ONE loop (what the
+    benchmark counts decode steps by); and no layer's expert weights are
+    copied out of their stacks (a dynamic slice does not fuse into the
+    grouped product's kernel, nor a static slice of a period's slab into a
+    matmul: the temporaries were a period's weights wide, 3.8 GB at the
+    benchmark's size, until the tree was laid out against it)."""
+    import re
+
+    from gofr_tpu.models import qwen3_next
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    cfg = qwen3_next.Qwen3NextConfig(vocab_size=4096, num_hidden_layers=8,
+                                     held=(0, 16))
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+            tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: qwen3_next.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(lambda: qwen3_next.init_cache(cfg, B, S)))
+    compiled = jax.jit(
+        lambda p, t, c: qwen3_next.decode_step(p, t, c, cfg),
+        donate_argnums=(2,),
+    ).lower(params, _shape(chip, jnp.int32, B), cache).compile()
+    text = compiled.as_text()
+    assert "gqa_decode_attention_tpu" in text
+    assert len(re.findall(r" while\(", text)) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
