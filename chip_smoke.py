@@ -52,7 +52,7 @@ class Sizes:
     prompt_lens: tuple[int, int]
     prefix_len: int
     max_new: int
-    kernels: str             # branch the dense path must take: "pallas" | "xla"
+    kernels: str             # branch decode attention must take: "pallas" | "xla"
     tol: float = LOGIT_TOL
     devices: tuple = ()      # phase ``replicas``: one replica per device
 
@@ -367,13 +367,13 @@ async def phase_paged(cfg, params, sizes: Sizes) -> dict:
         stats["prefix_cache"] = {k: cache[k] for k in
                                  ("hits", "misses", "prefill_tokens_saved")}
         stats["prefill_segments"] = gen.prefill_segments_run
-        # S2's finding: there is no paged decode kernel, so this is XLA
-        # (a jnp.take gather of every row's pages) on any platform
+        # full-precision pages at these widths: the kernel that walks the
+        # page table on a TPU, the gather of every row's pages elsewhere
         stats["branches"] = _branches(snap, {ops.branch_key(
             "paged_decode_attention",
-            _sds(cfg, sizes.batch_slots, 1, cfg.dim),
+            _sds(cfg, sizes.batch_slots, 1, cfg.n_heads, cfg.head_dim),
             _sds(cfg, cfg.n_layers, gen.n_pages, sizes.page_size,
-                 cfg.n_kv_heads, cfg.head_dim)): "xla"})
+                 cfg.n_kv_heads, cfg.head_dim)): sizes.kernels})
     check = make_reference(cfg, sizes.tol)
     stats["requests"] = {"http": 8, "failed": 0}
     stats["max_logit_shortfall"] = round(max(

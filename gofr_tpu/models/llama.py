@@ -1054,10 +1054,12 @@ def paged_decode_step(params: dict, tokens: jnp.ndarray, cache: dict,
     """One token per row against the paged pool. ``table`` [B, P_max]
     maps each row's virtual pages (in order, so virtual positions are
     contiguous and kv_len masking is exact). The new token's kv row
-    writes at (table[b, pos//page_s], pos % page_s); attention gathers
-    the row's pages back into a virtual [P_max * page_s] sequence.
+    writes at (table[b, pos//page_s], pos % page_s); attention over a
+    full-precision pool is ``ops.paged_decode_attention`` (on a TPU a
+    kernel that walks the row's live pages), over int8/int4 pages a
+    dequantising gather of the row's virtual [P_max * page_s] sequence.
     """
-    from ..ops import (apply_rope, attention, dequantize_kv, quantize_kv,
+    from ..ops import (apply_rope, attention, paged_decode_attention,
                        record_branch, repeat_kv, rms_norm, rope_table)
 
     b = tokens.shape[0]
@@ -1074,11 +1076,14 @@ def paged_decode_step(params: dict, tokens: jnp.ndarray, cache: dict,
     x = params["embed"][tokens][:, None, :].astype(cfg.dtype)
     cos, sin = rope_table(pos[:, None], cfg.head_dim, cfg.rope_theta,
                           scaling=cfg.rope_scaling)
-    rows = jnp.arange(b)
     kv_idx = jnp.arange(KV)[None, :]
-    # there is no paged kernel: the gathered virtual sequence always goes
-    # through XLA, and the dispatch record says so
-    record_branch("paged_decode_attention", False, x, cache["k"])
+    # an over-capacity row attends its whole table and no further: one
+    # past it would send the kernel's page walk off the table's end
+    new_len = jnp.minimum(pos + 1, p_max * page_s)
+    # a freed slot keeps decoding (its output is dropped) and its ``len``
+    # keeps counting, but its row of the table is all scratch page 0: it
+    # attends one position of that page, not 2,048 of them
+    kv_len = jnp.where(table[:, 0] == 0, 1, new_len)
 
     def body(carry, lp):
         x, arrays, layer = carry
@@ -1116,7 +1121,11 @@ def paged_decode_step(params: dict, tokens: jnp.ndarray, cache: dict,
                         planes[pl] = jnp.swapaxes(p, -1, -2).reshape(b, -1, KV)
                     return kv_decode(cfg, q8, planes, cfg.dtype)
 
-                k_virt, v_virt = virt("k"), virt("v")
+                # quantised pages have no kernel yet
+                record_branch("paged_decode_attention", False, q, arrays["k"])
+                o = attention(q, repeat_kv(virt("k"), cfg.n_rep),
+                              repeat_kv(virt("v"), cfg.n_rep),
+                              causal=False, kv_len=kv_len)
             else:
                 dt = arrays["k"].dtype
                 arrays = {
@@ -1125,16 +1134,8 @@ def paged_decode_step(params: dict, tokens: jnp.ndarray, cache: dict,
                     "v": arrays["v"].at[layer, page, off].set(
                         v[:, 0].astype(dt)),
                 }
-                k_l = jax.lax.dynamic_index_in_dim(arrays["k"], layer, 0,
-                                                   keepdims=False)
-                v_l = jax.lax.dynamic_index_in_dim(arrays["v"], layer, 0,
-                                                   keepdims=False)
-                # virtual sequence: gather this row's pages in table order
-                k_virt = jnp.take(k_l, table, axis=0).reshape(b, -1, KV, hd)
-                v_virt = jnp.take(v_l, table, axis=0).reshape(b, -1, KV, hd)
-            o = attention(q, repeat_kv(k_virt, cfg.n_rep),
-                          repeat_kv(v_virt, cfg.n_rep),
-                          causal=False, kv_len=pos + 1)
+                o = paged_decode_attention(q, arrays["k"], arrays["v"],
+                                           table, kv_len, layer=layer)
             x = x + _mm(o.reshape(b, 1, H * hd), lp["wo"])
         with jax.named_scope("mlp"):
             h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
@@ -1147,8 +1148,7 @@ def paged_decode_step(params: dict, tokens: jnp.ndarray, cache: dict,
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     with jax.named_scope("lm_head"):
         logits = _mm(x[:, 0], params["lm_head"]).astype(jnp.float32)
-    S_virt = table.shape[1] * page_s
-    return logits, {**arrays, "len": jnp.minimum(pos + 1, S_virt)}
+    return logits, {**arrays, "len": new_len}
 
 
 def sp_paged_decode_step(params: dict, tokens: jnp.ndarray, cache: dict,

@@ -31,6 +31,7 @@ __all__ = [
     "decode_attention",
     "gqa_decode_attention",
     "cached_decode_attention",
+    "paged_decode_attention",
     "quantize_kv",
     "dequantize_kv",
     "quantize_kv4",
@@ -345,6 +346,37 @@ def cached_decode_attention(q, k_cache, v_cache, kv_len, *, layer=None,
         v_cache = dequantize_kv(unflat(v_cache),
                                 v_scale.transpose(0, 2, 1), q.dtype)
     return gqa_decode_attention(q, k_cache, v_cache, kv_len=kv_len)
+
+
+def paged_decode_attention(q, k_pool, v_pool, table, kv_len, *, layer):
+    """Decode attention for the paged layout: one query token a row
+    against the row's pages of the full-precision pool.
+
+    q: [B, 1, H, D]; pools: the stacked ``[L, N, page_s, KV, D]`` planes
+    with ``layer`` a traced index; table: [B, P_max] page ids in virtual
+    order; kv_len: [B]. On a TPU, where the widths meet the kernel's
+    tiling, the Pallas kernel reads the live pages where they lie
+    (``ops/paged_attention.py``); everywhere else each row's whole virtual
+    sequence is gathered and masked.
+    """
+    from .paged_attention import block_pages, paged_decode_attention_tpu
+
+    page_s, kv, d = k_pool.shape[2:]
+    kernel = (_on_tpu() and q.shape[1] == 1
+              and jnp.issubdtype(k_pool.dtype, jnp.floating)
+              and block_pages(page_s, kv, d, k_pool.dtype.itemsize) is not None)
+    record_branch("paged_decode_attention", kernel, q, k_pool)
+    if kernel:
+        return paged_decode_attention_tpu(q, k_pool, v_pool, table, kv_len,
+                                          layer=layer)
+    b, n_rep = q.shape[0], q.shape[2] // kv
+    k_l = jax.lax.dynamic_index_in_dim(k_pool, layer, 0, keepdims=False)
+    v_l = jax.lax.dynamic_index_in_dim(v_pool, layer, 0, keepdims=False)
+    # virtual sequence: gather this row's pages in table order
+    k_virt = jnp.take(k_l, table, axis=0).reshape(b, -1, kv, d)
+    v_virt = jnp.take(v_l, table, axis=0).reshape(b, -1, kv, d)
+    return attention(q, repeat_kv(k_virt, n_rep), repeat_kv(v_virt, n_rep),
+                     causal=False, kv_len=kv_len)
 
 
 def swiglu(x: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray,

@@ -183,3 +183,177 @@ def test_rope_scaling_linear_and_unsupported():
     np.testing.assert_allclose(np.asarray(out), np.asarray(freqs) / 4.0)
     with pytest.raises(ValueError, match="rope_scaling"):
         scale_rope_freqs(freqs, {"rope_type": "yarn", "factor": 2.0})
+
+
+# ------------------------------------------------- paged decode attention
+PAGE_S, P_MAX, HEAD_D = 16, 40, 128   # a row's table holds 640 positions
+
+
+def _paged_case(heads, kv_heads, lens, dtype=jnp.float32, idle=()):
+    """q, a two-layer pool whose pages a row's table names in shuffled,
+    non-contiguous order, and the table: entries past a row's length are
+    scratch page 0, as are all of an idle row's."""
+    b = len(lens)
+    n_pool = b * P_MAX + 1
+    keys = jax.random.split(jax.random.PRNGKey(heads + kv_heads), 3)
+    q = jax.random.normal(keys[0], (b, 1, heads, HEAD_D), dtype)
+    k_pool, v_pool = (
+        jax.random.normal(kk, (2, n_pool, PAGE_S, kv_heads, HEAD_D), dtype)
+        for kk in keys[1:])
+    table = (np.random.RandomState(7).permutation(n_pool - 1)[:b * P_MAX]
+             + 1).reshape(b, P_MAX).astype(np.int32)
+    for row, n in enumerate(lens):
+        table[row, -(-min(n, P_MAX * PAGE_S) // PAGE_S):] = 0
+    for row in idle:
+        table[row] = 0
+    return q, k_pool, v_pool, jnp.asarray(table)
+
+
+def _block_tokens(kv_heads, dtype=jnp.float32):
+    from gofr_tpu.ops.paged_attention import block_pages
+
+    return PAGE_S * block_pages(PAGE_S, kv_heads, HEAD_D,
+                                jnp.dtype(dtype).itemsize)
+
+
+@pytest.mark.parametrize("lens", ["short", "block_edges", "ragged", "capacity"])
+@pytest.mark.parametrize("heads,kv_heads", [(32, 32), (32, 8)],
+                         ids=["mha32", "gqa32x8"])
+def test_paged_kernel_matches_gather(heads, kv_heads, lens):
+    """The kernel that walks the page table (interpret mode) against the
+    gather of every row's whole virtual sequence, the dispatcher's branch
+    off the TPU: one token and an idle row; exactly a block, one past it
+    and one short of it; ragged rows; a row at capacity handed one more
+    than capacity (``pos + 1`` with ``pos`` pinned), which the kernel must
+    clamp and never walk past the table."""
+    from gofr_tpu.ops import paged_decode_attention
+    from gofr_tpu.ops.paged_attention import paged_decode_attention_tpu
+
+    block = _block_tokens(kv_heads)
+    capacity = P_MAX * PAGE_S
+    assert block + 1 <= capacity
+    handed = {
+        "short": [1, 17, 1],
+        "block_edges": [block, block + 1, block - 1],
+        "ragged": [5, 300, 77, capacity - 3],
+        "capacity": [capacity + 1, capacity, 3],
+    }[lens]
+    q, k_pool, v_pool, table = _paged_case(
+        heads, kv_heads, handed, idle=(2,) if lens == "short" else ())
+    attended = jnp.asarray(np.minimum(handed, capacity), jnp.int32)
+    want = paged_decode_attention(q, k_pool, v_pool, table, attended, layer=1)
+    got = paged_decode_attention_tpu(
+        q, k_pool, v_pool, table, jnp.asarray(handed, jnp.int32), layer=1,
+        interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+def test_paged_kernel_bf16_pool():
+    """The serving dtype: products in bfloat16, softmax and sums float32."""
+    from gofr_tpu.ops import paged_decode_attention
+    from gofr_tpu.ops.paged_attention import paged_decode_attention_tpu
+
+    block = _block_tokens(8, jnp.bfloat16)
+    lens = jnp.asarray([block // 2 + 3, 9, min(block + 40, P_MAX * PAGE_S)],
+                       jnp.int32)
+    q, k_pool, v_pool, table = _paged_case(32, 8, lens.tolist(), jnp.bfloat16)
+    want = paged_decode_attention(q, k_pool, v_pool, table, lens, layer=0)
+    got = paged_decode_attention_tpu(q, k_pool, v_pool, table, lens, layer=0,
+                                     interpret=True)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=3e-2, rtol=3e-2)
+
+
+@pytest.mark.parametrize("page_s,kv_heads,head_dim,dtype,takes", [
+    (16, 32, 128, jnp.bfloat16, True),    # deepseek7b-sessions
+    (16, 8, 128, jnp.bfloat16, True),     # Mistral through the paged layout
+    (8, 2, 256, jnp.bfloat16, True),      # a page of one packed tile
+    (16, 2, 16, jnp.float32, False),      # toy head size: lanes not whole
+    (4, 2, 128, jnp.bfloat16, False),     # a page smaller than a tile
+])
+def test_paged_block_pages(page_s, kv_heads, head_dim, dtype, takes):
+    """Which pools the kernel takes, and that a block of what it takes is
+    whole pages, whole lane tiles, and small beside the scoped VMEM."""
+    from gofr_tpu.ops.paged_attention import block_pages
+
+    itemsize = jnp.dtype(dtype).itemsize
+    pages = block_pages(page_s, kv_heads, head_dim, itemsize)
+    assert (pages is not None) == takes
+    if takes:
+        block_rows = pages * page_s * kv_heads
+        assert block_rows % 128 == 0
+        assert 4 * block_rows * head_dim * itemsize <= 4 * 2**20
+
+
+def test_paged_dispatcher_records_gather_off_tpu():
+    from gofr_tpu import ops
+
+    q, k_pool, v_pool, table = _paged_case(32, 8, [40, 3])
+    ops.paged_decode_attention(q, k_pool, v_pool, table,
+                               jnp.asarray([40, 3], jnp.int32), layer=0)
+    key = ops.branch_key("paged_decode_attention", q, k_pool)
+    assert ops.kernel_branches()[key] == "xla"
+
+
+def test_paged_decode_step_matches_dense_and_hands_over_live_lengths(
+        monkeypatch):
+    """``paged_decode_step`` through the dispatcher's gather serves what
+    the dense layout serves from the same rows (shuffled pages, rows at
+    different lengths), and the length it hands the dispatcher stops at
+    the table's capacity for a row whose ``len`` is pinned there and is 1
+    for a freed slot (its row of the table all scratch page 0, its ``len``
+    still counting): the kernel's cost follows what it is handed."""
+    from gofr_tpu import ops
+    from gofr_tpu.models import llama
+
+    cfg = llama.tiny_llama(use_flash=False, dtype=jnp.float32)
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    page_s, p_max, b = 8, 4, 4
+    capacity = page_s * p_max
+    lens = np.array([capacity, 13, 0, 20], np.int32)
+    freed = 3
+    dense = llama.init_cache(cfg, b, capacity)
+    keys = jax.random.split(jax.random.PRNGKey(1), 2)
+    dense["k"], dense["v"] = (
+        jax.random.normal(kk, dense["k"].shape, dense["k"].dtype)
+        for kk in keys)
+    dense["len"] = jnp.asarray(lens)
+    table = (np.random.RandomState(3).permutation(b * p_max) + 1
+             ).reshape(b, p_max).astype(np.int32)
+    paged = llama.init_paged_cache(cfg, b, b * p_max + 1, page_s)
+    for name in ("k", "v"):   # [L, B, S, KV, D] -> pages [L, N, ps, KV, D]
+        rows = np.asarray(dense[name]).reshape(
+            cfg.n_layers, b * p_max, page_s, cfg.n_kv_heads, cfg.head_dim)
+        pool = np.array(paged[name])
+        pool[:, table.reshape(-1)] = rows
+        paged[name] = jnp.asarray(pool)
+    paged["len"] = jnp.asarray(lens)
+    table[freed] = 0
+
+    seen = []
+    inner = ops.paged_decode_attention
+
+    def spy(q, k_pool, v_pool, tbl, kv_len, **kw):
+        jax.debug.callback(lambda v: seen.append(np.asarray(v)), kv_len)
+        return inner(q, k_pool, v_pool, tbl, kv_len, **kw)
+
+    monkeypatch.setattr(ops, "paged_decode_attention", spy)
+    tokens = np.array([5, 9, 2, 7], np.int32)
+    got, new = jax.jit(lambda p, t, c, tb: llama.paged_decode_step(
+        p, t, c, tb, cfg))(params, tokens, paged, jnp.asarray(table))
+    jax.effects_barrier()
+    assert len(seen) == cfg.n_layers
+    for handed in seen:
+        np.testing.assert_array_equal(handed, [capacity, 14, 1, 1])
+    np.testing.assert_array_equal(np.asarray(new["len"]),
+                                  [capacity, 14, 1, 21])
+    want, _ = llama.decode_step(params, tokens, dense, cfg)
+    # (the row at capacity drops its token's write in both layouts and
+    # attends the same full table; the freed slot's output is no one's)
+    np.testing.assert_allclose(np.asarray(got[:freed]),
+                               np.asarray(want[:freed]),
+                               atol=1e-4, rtol=1e-4)
+    np.testing.assert_array_equal(np.argmax(got[:freed], -1),
+                                  np.argmax(want[:freed], -1))

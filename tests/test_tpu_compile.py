@@ -47,6 +47,12 @@ def _shape(chip, dtype, *shape):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
 
+def _on_chip(chip, tree):
+    """The shapes of ``tree``'s arrays, placed on the described chip."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), tree)
+
+
 def _cache_shapes(chip, layout):
     if layout == "bf16-stacked":
         kv = _shape(chip, jnp.bfloat16, L, B, S, KV, D)
@@ -92,14 +98,9 @@ def test_decode_step_compiles_with_kernel(chip, monkeypatch):
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
     cfg = llama.llama3_8b(n_layers=2)
 
-    def on_chip(tree):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
-            tree)
-
-    params = on_chip(jax.eval_shape(
+    params = _on_chip(chip, jax.eval_shape(
         lambda: llama.init_params(cfg, jax.random.PRNGKey(0))))
-    cache = on_chip(jax.eval_shape(lambda: llama.init_cache(cfg, B, S)))
+    cache = _on_chip(chip, jax.eval_shape(lambda: llama.init_cache(cfg, B, S)))
     compiled = jax.jit(
         lambda p, t, c: llama.decode_step(p, t, c, cfg)
     ).lower(params, _shape(chip, jnp.int32, B), cache).compile()
@@ -127,14 +128,9 @@ def test_hybrid_decode_step_compiles_with_kernel_and_one_loop(chip,
     cfg = qwen3_next.Qwen3NextConfig(vocab_size=4096, num_hidden_layers=8,
                                      held=(0, 16))
 
-    def on_chip(tree):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
-            tree)
-
-    params = on_chip(jax.eval_shape(
+    params = _on_chip(chip, jax.eval_shape(
         lambda: qwen3_next.init_params(cfg, jax.random.PRNGKey(0))))
-    cache = on_chip(jax.eval_shape(lambda: qwen3_next.init_cache(cfg, B, S)))
+    cache = _on_chip(chip, jax.eval_shape(lambda: qwen3_next.init_cache(cfg, B, S)))
     compiled = jax.jit(
         lambda p, t, c: qwen3_next.decode_step(p, t, c, cfg),
         donate_argnums=(2,),
@@ -143,3 +139,42 @@ def test_hybrid_decode_step_compiles_with_kernel_and_one_loop(chip,
     assert "gqa_decode_attention_tpu" in text
     assert len(re.findall(r" while\(", text)) == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
+@pytest.mark.parametrize("rows,kv_heads,vocab,ffn", [
+    (8, 32, 102400, 11008),   # deepseek7b-sessions: 8 slots, MHA
+    (32, 8, 32768, 14336),    # mistral7b-x4-sessions' replica: 32 slots, GQA
+], ids=["deepseek-8rows", "mistral-32rows"])
+def test_paged_decode_step_compiles_with_kernel_and_no_pool_copy(
+        chip, monkeypatch, rows, kv_heads, vocab, ffn):
+    """``llama.paged_decode_step`` (the body of ``jit_paged_chunk_fn``) at
+    the paged cells' own sizes, depth 16, a pool of ``rows`` x 128 pages
+    of 16: the kernel that walks the page table is in the program and fits
+    VMEM (the compiler refuses one that does not); the step is ONE loop,
+    the scan over layers (what ``decode_step_ms`` counts steps by: a page
+    walk written as an HLO loop would make every layer a step); and the
+    temporaries hold no copy of a layer's slab of the pool (134 MB a plane
+    at either size: a slice that feeds a custom call is copied, so the
+    kernel takes the stacked pool and the layer's index)."""
+    import re
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    cfg = llama.LlamaConfig(vocab_size=vocab, dim=4096, n_layers=16,
+                            n_heads=32, n_kv_heads=kv_heads, ffn_dim=ffn,
+                            max_seq_len=2048)
+    page_s, p_max = 16, 128
+
+    params = _on_chip(chip, jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = _on_chip(chip, jax.eval_shape(
+        lambda: llama.init_paged_cache(cfg, rows, rows * p_max + 1, page_s)))
+    slab = (rows * p_max + 1) * page_s * kv_heads * cfg.head_dim * 2
+    compiled = jax.jit(
+        lambda p, t, c, tb: llama.paged_decode_step(p, t, c, tb, cfg),
+        donate_argnums=(2,),
+    ).lower(params, _shape(chip, jnp.int32, rows), cache,
+            _shape(chip, jnp.int32, rows, p_max)).compile()
+    text = compiled.as_text()
+    assert "paged_decode_attention_tpu" in text
+    assert len(re.findall(r" while\(", text)) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < slab // 2
