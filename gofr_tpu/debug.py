@@ -131,8 +131,7 @@ def _histogram_percentiles(manager, model_names) -> dict:
 def serving_snapshot(container) -> dict:
     """Structured state of the inference plane (the /debug/serving body)."""
     # the runtime fingerprint — the SAME dict a capture bundle's header
-    # snapshots (jax/backend/device kind+count, armed GOFR_ML_* knobs):
-    # the bench used to infer backend provenance from discovery strings
+    # snapshots (jax/backend/device kind+count, armed GOFR_ML_* knobs)
     from .ml.capture import runtime_fingerprint
 
     snap: dict = {"ts": time.time(), "runtime": runtime_fingerprint()}

@@ -99,7 +99,7 @@ _HOST_PHASES = tuple(p for p in PHASES if p != "device_wait")
 
 def recorder_enabled() -> bool:
     """``GOFR_ML_FLIGHT_RECORDER`` (default on): 0 disables the dispatch
-    recorder — the overhead A/B knob the bench stall arm flips."""
+    recorder (what it costs when on: PERF.md §6, PR 27)."""
     return os.environ.get("GOFR_ML_FLIGHT_RECORDER", "1").strip() != "0"
 
 

@@ -157,7 +157,7 @@ class _Histogram(_Metric):
 
     def percentile(self, q: float, labels: Mapping[str, str] | None = None) -> float:
         """Approximate percentile from bucket boundaries (for in-process SLO
-        checks and the bench harness; Prometheus does the real math server-side)."""
+        checks; Prometheus does the real math server-side)."""
         key = _label_key(labels or {})
         with self._lock:
             series = self._series.get(key)

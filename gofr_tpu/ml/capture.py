@@ -330,8 +330,8 @@ class TrafficCapture:
             return self._requests.get(rid)
 
     def clear(self) -> None:
-        """Drop every record and restart the epoch (bench windows re-arm
-        between A/B arms in one process)."""
+        """Drop every record and restart the epoch (a process that
+        captures several windows re-arms between them)."""
         with self._lock:
             self._requests.clear()
             self.epoch = time.perf_counter()
@@ -437,8 +437,8 @@ def traffic_capture() -> TrafficCapture | None:
     """The process-global capture, or ``None`` when ``GOFR_ML_CAPTURE``
     is unset/0 — call sites get the is-not-None guard free, and a
     disabled process never constructs the machinery at all. Re-arming
-    the knob with a DIFFERENT ring size starts a fresh store (the bench
-    arms re-pin the knob between in-process app boots; a silently-kept
+    the knob with a DIFFERENT ring size starts a fresh store (a process may
+    re-pin the knob between in-process app boots; a silently-kept
     old ring would ignore the new bound AND leak the previous window's
     records into the next bundle) — serving fronts built before the
     re-arm keep writing their old handle, so re-size between boots, not

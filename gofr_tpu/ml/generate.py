@@ -539,8 +539,8 @@ class Generator:
             # Block-paged KV cache (llama.init_paged_cache): a shared page
             # pool + host-owned page tables instead of a dense [B, S_max]
             # rectangle per slot. HBM holds ACTUAL tokens, not worst case,
-            # so the same memory serves more concurrent long-context slots
-            # (config7). n_pages defaults to the dense-equivalent so the
+            # so the same memory serves more concurrent long-context
+            # slots. n_pages defaults to the dense-equivalent so the
             # operator dials capacity down explicitly.
             if shard_cache or (
                     self._sp is None and mesh is not None
@@ -675,30 +675,46 @@ class Generator:
 
         sampler_cfg = self.sampler
         host_visible = self._host_visible
-        # decode programs under a dense SP plan trace with the sp config
-        # clone (attn_impl set) so _decode_layer picks sp_decode_attention
-        # over the S-sharded cache; the striped-pool plan routes through
-        # sp_paged_decode_step below instead
         sp_plan = self._sp
-        decode_cfg = (sp_plan.sp_cfg
-                      if (sp_plan is not None and not self.page_size)
-                      else cfg)
+        # THE decode step of this (model, layout, SP plan), chosen here and
+        # nowhere else: ``decode_one(params, tok, cache, table)``. The
+        # dense layout takes its model's ``decode_step`` and no table —
+        # under a dense SP plan traced with the plan's config clone
+        # (attn_impl set), so the step attends the S-sharded cache through
+        # sp_decode_attention; the page pool routes through the page
+        # table, and a pool striped across the sp mesh through the
+        # cross-device combine of ``sp_paged_decode_step``.
+        if not self.page_size:
+            decode_cfg = sp_plan.sp_cfg if sp_plan is not None else cfg
+
+            def decode_one(params, tok, cache, table):
+                return model.decode_step(params, tok, cache, decode_cfg,
+                                         mesh=mesh)
+        elif sp_plan is not None:
+            def decode_one(params, tok, cache, table):
+                return llama.sp_paged_decode_step(params, tok, cache, table,
+                                                  cfg, mesh)
+        else:
+            def decode_one(params, tok, cache, table):
+                return llama.paged_decode_step(params, tok, cache, table,
+                                               cfg)
 
         def make_chunk_fn(n_chunk: int):
-            def chunk_fn(params, tok, cache, step0, base_key):
+            def chunk_fn(params, tok, cache, step0, base_key, table=None):
                 """``n_chunk`` fused decode+sample steps. Returns
                 [n_chunk+1, B] tokens: row 0 is the INPUT token row (how
                 newly-admitted slots' first sampled tokens reach the host — a
                 separate per-admission transfer would cost a synchronous
                 D2H of its own; this way firsts ride the chunk fetch that
                 happens anyway), rows 1..n_chunk are this chunk's
-                samples; plus the final carry."""
+                samples; plus the final carry. The paged layouts pass their
+                page table (constant across the chunk — growth
+                pre-allocates); the dense layout passes none."""
                 tok_in = tok
 
                 def body(carry, j):
                     tok, cache = carry
-                    logits, cache = model.decode_step(params, tok, cache,
-                                                      decode_cfg, mesh=mesh)
+                    logits, cache = decode_one(params, tok, cache, table)
                     key = jax.random.fold_in(base_key, step0 + j)
                     nxt = _sample_impl(logits, key, sampler_cfg)
                     return (nxt, cache), nxt
@@ -709,40 +725,16 @@ class Generator:
                 block = jnp.concatenate([tok_in[None], toks], axis=0)
                 return host_visible(block), host_visible(tok), cache
 
-            def paged_chunk_fn(params, tok, cache, step0, base_key, table):
-                # identical shape contract; decode routes through the page
-                # table (constant across the chunk — growth pre-allocates)
-                tok_in = tok
-
-                def body(carry, j):
-                    tok, cache = carry
-                    if sp_plan is not None:
-                        # striped pool: cross-device page gather via the
-                        # sp_decode_attention combine (models/llama.py)
-                        logits, cache = llama.sp_paged_decode_step(
-                            params, tok, cache, table, cfg, mesh)
-                    else:
-                        logits, cache = llama.paged_decode_step(
-                            params, tok, cache, table, cfg)
-                    key = jax.random.fold_in(base_key, step0 + j)
-                    nxt = _sample_impl(logits, key, sampler_cfg)
-                    return (nxt, cache), nxt
-
-                (tok, cache), toks = jax.lax.scan(
-                    body, (tok, cache), jnp.arange(n_chunk)
-                )
-                block = jnp.concatenate([tok_in[None], toks], axis=0)
-                return block, tok, cache
-
             # donate the cache AND the input token row: in-place KV update
             # on device, no copy per step, and the token-row buffer is
             # reused across dispatches instead of reallocated (part of the
             # dispatch-launch fusion — fewer allocator round-trips per
             # program). The page table (last arg, paged mode) is NOT
             # donated: it is a device-cached host upload reused until the
-            # table actually changes (_table_device).
-            return jax.jit(paged_chunk_fn if self.page_size else chunk_fn,
-                           donate_argnums=(1, 2))
+            # table actually changes (_table_device). The trace readers
+            # find decode programs by these two names.
+            return _jit("paged_chunk_fn" if self.page_size else "chunk_fn",
+                        chunk_fn, donate_argnums=(1, 2))
 
         # EOS membership as a host constant the jitted window programs
         # embed — the device-side mirror of _apply_burst's np.isin, so the
@@ -781,12 +773,7 @@ class Generator:
 
                 def run(carry, j):
                     tok, cache0, active, n_out, realized = carry
-                    if sp_plan is not None:
-                        logits, cache2 = llama.sp_paged_decode_step(
-                            params, tok, cache0, table, cfg, mesh)
-                    else:
-                        logits, cache2 = llama.paged_decode_step(
-                            params, tok, cache0, table, cfg)
+                    logits, cache2 = decode_one(params, tok, cache0, table)
                     key = jax.random.fold_in(base_key, step0 + j)
                     nxt = _sample_impl(logits, key, sampler_cfg)
                     # freeze finished rows: token and len stop advancing
@@ -1006,7 +993,8 @@ class Generator:
         a bad draft costs speed, never correctness. One "window" replaces
         one decode step and emits 1..K+1 tokens for the same weight sweep
         out of HBM."""
-        llama = self._m
+        from ..models import llama  # speculation is the llama block's
+
         cfg = self.cfg
         mesh = self.mesh
         if self.sampler.temperature > 0:
@@ -1055,7 +1043,25 @@ class Generator:
             src = jnp.where(start >= 0, start + npick, h - 1)
             return jax.lax.dynamic_slice(td_row, (src,), (K,))
 
-        paged = bool(self.page_size)
+        # the verify window of this layout, chosen once: ``verify(params,
+        # window, cache, table) -> (logits, cache, capacity)``; ``len`` is
+        # capped at ``capacity`` (a row's virtual pages, or the dense row).
+        # Paged mode routes window writes/reads through the page table.
+        if self.page_size:
+            page_s = self.page_size
+
+            def verify(params, window, cache, table):
+                logits, cache = llama.paged_decode_window(
+                    params, window, cache, table, cfg)
+                return logits, cache, table.shape[1] * page_s
+        else:
+            def verify(params, window, cache, table):
+                logits, cache = llama.decode_window(
+                    params, window, cache, cfg, mesh=mesh)
+                return logits, cache, cache["k"].shape[2]
+
+        def draft_one(tok, dcache):
+            return llama.decode_step(draft_params, tok, dcache, draft_cfg)
 
         def run_draft_model(tok, dcache):
             """Propose K tokens with the draft model: K sequential greedy
@@ -1066,15 +1072,13 @@ class Generator:
             target's single big sweep still dominates."""
             def dstep(carry, _):
                 t, dc = carry
-                dlogits, dc = llama.decode_step(draft_params, t, dc,
-                                                draft_cfg)
+                dlogits, dc = draft_one(t, dc)
                 nxt = jnp.argmax(dlogits, axis=-1).astype(jnp.int32)
                 return (nxt, dc), nxt
 
             (last, dcache), drafts = jax.lax.scan(
                 dstep, (tok, dcache), None, length=K)
-            _, dcache = llama.decode_step(draft_params, last, dcache,
-                                          draft_cfg)
+            _, dcache = draft_one(last, dcache)
             return jnp.moveaxis(drafts, 0, 1), dcache
 
         windowed = bool(self.decode_window)
@@ -1107,9 +1111,8 @@ class Generator:
                     else:
                         draft = jax.vmap(draft_row)(td, h)       # [B, K]
                     window = jnp.concatenate([tok[:, None], draft], axis=1)
-                    logits, cache = llama.paged_decode_window(
-                        params, window, cache, table, cfg)
-                    S_max = table.shape[1] * self.page_size
+                    logits, cache, S_max = verify(params, window, cache,
+                                                  table)
                     greedy_t = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                     match = (draft == greedy_t[:, :K]).astype(jnp.int32)
                     n_acc = jnp.sum(jnp.cumprod(match, axis=1), axis=1)
@@ -1180,8 +1183,7 @@ class Generator:
                 disable: a masked row accepts nothing, so it emits exactly
                 its verified next token per window — plain greedy decode
                 at window cadence, bit-identical (the window's position-0
-                logits depend only on the prefix + input token). Paged
-                mode routes window writes/reads through the page table."""
+                logits depend only on the prefix + input token)."""
                 tok_in = tok
                 ar = jnp.arange(K + 1)[None, :]
                 rows = jnp.arange(B)
@@ -1194,14 +1196,8 @@ class Generator:
                     else:
                         draft = jax.vmap(draft_row)(td, h)       # [B, K]
                     window = jnp.concatenate([tok[:, None], draft], axis=1)
-                    if paged:
-                        logits, cache = llama.paged_decode_window(
-                            params, window, cache, table, cfg)
-                        S_max = table.shape[1] * self.page_size
-                    else:
-                        logits, cache = llama.decode_window(
-                            params, window, cache, cfg, mesh=mesh)
-                        S_max = cache["k"].shape[2]
+                    logits, cache, S_max = verify(params, window, cache,
+                                                  table)
                     greedy_t = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                     match = (draft == greedy_t[:, :K]).astype(jnp.int32)
                     n_acc = jnp.sum(jnp.cumprod(match, axis=1), axis=1)
@@ -1921,11 +1917,11 @@ class Generator:
         parallel / dense). Shared by ``__init__`` and ``recover()``: a
         crashed dispatch may have consumed the donated cache buffers, and
         rebuilding must produce exactly the construction-time layout."""
-        llama = self._m
+        model = self._m
         cfg = self.cfg
         if self.page_size:
             with jax.default_device(self._device):
-                self.cache = llama.init_paged_cache(
+                self.cache = model.init_paged_cache(
                     cfg, self.batch_slots, self.n_pages, self.page_size)
             if self._sp is not None:
                 # stripe the POOL across the sp mesh: the page axis
@@ -1976,7 +1972,7 @@ class Generator:
             specs = self._serving_cache_specs()
             self.cache = _jit(
                 "init_cache",
-                lambda: llama.init_cache(cfg, self.batch_slots, self.max_seq),
+                lambda: model.init_cache(cfg, self.batch_slots, self.max_seq),
                 out_shardings={
                     key: NamedSharding(self.mesh, s)
                     for key, s in specs.items()
@@ -1991,7 +1987,7 @@ class Generator:
             from ..parallel import NamedSharding
             from ..parallel import P as _P
 
-            cache = llama.init_cache(cfg, self.batch_slots, self.max_seq)
+            cache = model.init_cache(cfg, self.batch_slots, self.max_seq)
             if getattr(cfg, "kv_quant", False):
                 # int8 layout (models/llama.init_cache): flat values
                 # [L, B, S, KV*D], seq-MINOR scales [L, B, KV, S]
@@ -2011,7 +2007,7 @@ class Generator:
             }
             return
         with jax.default_device(self._device):
-            self.cache = llama.init_cache(cfg, self.batch_slots, self.max_seq)
+            self.cache = model.init_cache(cfg, self.batch_slots, self.max_seq)
 
     def quarantine_borrowed(self) -> list[int]:
         """Invalidate the prefix registrations BORROWED by live slots and

@@ -46,9 +46,9 @@ answer) or one of the wasted reasons:
 
 The ledger **balances by construction**: every classification point
 increments exactly one reason, so ``delivered + sum(wasted reasons) ==
-device-computed tokens`` — the invariant the bench goodput arm asserts
-under a chaos run with speculation, deadlines, failover, and migration
-all active. Aggregated per model (a replica pool's cores roll up under
+device-computed tokens`` — the invariant tests/test_goodput.py asserts
+for each reason in turn (speculation, deadlines, disconnects, crashes,
+failover). Aggregated per model (a replica pool's cores roll up under
 the pool name via the same ``pool/idx`` prefix match the event log uses)
 and fleet-wide; served at ``GET /debug/goodput``, as a ``goodput`` block
 in ``/debug/serving``, and as ``app_llm_tokens_wasted_total{model,

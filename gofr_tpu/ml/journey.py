@@ -91,8 +91,8 @@ def _ring_size() -> int:
     except ValueError:
         n = 512
     # "0" means DISABLED, not "tiny ring": the process-global log is
-    # sized at import, and a later in-process enable (the bench's A/B
-    # arms re-pin the knob) must find the default ring, not a 16-slot one
+    # sized at import, and a later in-process enable (a process that
+    # re-pins the knob between app boots) must find the default ring, not a 16-slot one
     return max(16, n) if n > 0 else 512
 
 

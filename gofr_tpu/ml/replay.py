@@ -25,8 +25,8 @@ bundle (``curl /debug/crash/<id>``) — crash bundles embed the capture
 tail, so a crash replays offline. Without ``--selftest`` the CLI
 inspects: it prints the bundle summary and the fingerprint drift (a
 replay needs a model, which a bundle deliberately does not carry — drive
-``ReplayHarness`` programmatically against your server, as the bench
-replay arm and tests/test_capture_replay.py do). ``--selftest`` builds a
+``ReplayHarness`` programmatically against your server, as
+tests/test_capture_replay.py does). ``--selftest`` builds a
 tiny in-process model server, captures a fresh mixed window against it,
 replays that bundle on an identical server, and exits non-zero unless
 the digest identity rate is 1.0 — the end-to-end proof of the loop.

@@ -324,7 +324,7 @@ class SLOController:
     @classmethod
     def from_env(cls, scheduler: TokenBudgetScheduler) -> "SLOController":
         """Targets from ``GOFR_ML_TTFT_TARGET_MS`` / ``GOFR_ML_TPOT_TARGET_MS``
-        (defaults 200 / 50 ms — the bench's own SLO line)."""
+        (defaults 200 / 50 ms)."""
         ttft_ms = float(os.environ.get("GOFR_ML_TTFT_TARGET_MS", "200"))
         tpot_ms = float(os.environ.get("GOFR_ML_TPOT_TARGET_MS", "50"))
         return cls(scheduler, ttft_target_s=ttft_ms / 1e3,

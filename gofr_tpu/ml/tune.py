@@ -1,9 +1,9 @@
 """Replay-driven config search: the self-tuning flywheel's offline half.
 
-PRs 16–17 proved the fast serving paths (fused decode windows, pipelined
-dispatch) win 1.07–1.53× with digest identity 1.0 — but every one of
-them is an opt-in env knob the default boot never arms, so the headline
-bench never moves. This module closes that loop: replay a captured
+PRs 16–17 added fast serving paths (fused decode windows, pipelined
+dispatch) that keep digest identity 1.0 — but every one of them is an
+opt-in env knob the default boot never arms, and none has a chip line
+yet (ROADMAP S8). This module closes that loop: replay a captured
 traffic bundle (ml/capture.py + ml/replay.py) across a **config grid**,
 prune every arm whose greedy digest identity is not exactly 1.0 (the
 hard correctness gate — a fast wrong answer is not a candidate), rank
@@ -11,14 +11,13 @@ the survivors by goodput-weighted steady decode tok/s with a TTFT/TPOT
 SLO penalty, and emit a **tuned profile**: a fingerprint-stamped JSON
 knob map plus the full per-arm scoreboard that justifies it.
 
-The profile is consumed in three places:
+The profile is consumed in two places:
 
 - ``GOFR_ML_PROFILE=<path>`` / ``register_llm(profile=)`` applies the
   knob map at boot (loud validation, fingerprint-drift warnings; unset
   constructs nothing — the default path stays byte-identical),
 - ``GOFR_ML_CANARY=<path>`` boots the candidate on a shadow replica and
-  lets live traffic judge it before promotion (ml/replica.py), and
-- the bench tune arm (config4 phase P) reports default-vs-tuned deltas.
+  lets live traffic judge it before promotion (ml/replica.py).
 
 CLI::
 
@@ -30,9 +29,9 @@ CLI::
 crash bundle. Without ``--tiny`` the CLI inspects: bundle summary plus
 the grid it *would* search (a replay needs a model, which a bundle
 deliberately does not carry — drive ``Tuner`` programmatically against
-your own builder, as the bench arm does). ``--tiny`` rebuilds the tiny
-paged float32 reference model the committed ``bench/`` bundle was
-captured from and runs the real search. ``--selftest`` captures a fresh
+your own builder). ``--tiny`` runs the real search against the tiny
+paged float32 reference model (``_tiny_builder``), which suits a bundle
+captured from that model (``--selftest`` captures one). ``--selftest`` captures a fresh
 window in-process, searches a 7-arm grid with a deliberately **poisoned
 arm** (same config, different weights — guaranteed identity violation),
 and exits non-zero unless the poisoned arm was pruned AND the winner
@@ -381,8 +380,8 @@ class Tuner:
 # -- reference builder + selftest ---------------------------------------------
 
 def _tiny_builder(poison: bool = False):
-    """The tiny paged float32 reference server the committed bench
-    bundle was captured from (float32 because cross-PROGRAM identity is
+    """The tiny paged float32 reference server that ``--tiny`` and the
+    selftest search against (float32 because cross-PROGRAM identity is
     the claim and bf16 rounding can flip a near-tie argmax between
     program shapes). ``poison=True`` swaps in weights from a different
     seed — same config, different model — the canonical identity
@@ -479,8 +478,7 @@ def main(argv: list[str] | None = None) -> int:
                              "bundle")
     parser.add_argument("--tiny", action="store_true",
                         help="search against the tiny paged float32 "
-                             "reference model (the committed bench "
-                             "bundle's source)")
+                             "reference model")
     parser.add_argument("--out", default=None,
                         help="write the tuned profile JSON here")
     parser.add_argument("--speed", type=float, default=1000.0,

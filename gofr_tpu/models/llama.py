@@ -32,7 +32,11 @@ from ..ops import (
     apply_rope,
     attention,
     cached_decode_attention,
+    dequantize_kv,
+    dequantize_kv4,
     flash_attention,
+    quantize_kv,
+    quantize_kv4,
     repeat_kv,
     rms_norm,
     rope_table,
@@ -128,7 +132,7 @@ def params_from_config(cfg: "LlamaConfig", seed: int = 0,
                        checkpoint_dir: str | None = None) -> dict:
     """Init or restore params honoring the config's serving knobs — the
     one place that consumes ``cfg.w8`` and ``LLAMA_CKPT``, so every boot
-    path (examples, bench, multi-host workers) serves the same way.
+    path (examples, multi-host workers) serves the same way.
 
     ``LLAMA_CKPT=<dir>`` (or ``checkpoint_dir``) restores real weights
     instead of random init. Two layouts are auto-detected:
@@ -217,7 +221,7 @@ def config_from_env(tiny_vocab_size: int | None = None) -> LlamaConfig:
     # bit-identity dtype — bf16 rounding can flip a near-tie argmax
     # between two program SHAPES computing the same math (e.g. a spec
     # verify window vs a plain decode step), which is numeric noise, not
-    # a serving bug; benches assert cross-arm token identity under f32
+    # a serving bug; tests assert cross-arm token identity under f32
     raw_dtype = os.environ.get("LLAMA_DTYPE", "").strip().lower()
     dtype_kw: dict = {}
     if raw_dtype:
@@ -262,8 +266,8 @@ def draft_from_env(target_cfg: "LlamaConfig", target_params=None) -> tuple:
     builds a random-weight draft of that shape (demo/testing — a random
     draft keeps outputs lossless, it just accepts ~nothing);
     ``LLM_DRAFT_PRESET=self`` reuses the target weights as the draft —
-    the acceptance upper bound for the draft-model machinery (config8's
-    draft arm; a real small checkpoint slots in via LLM_DRAFT_CKPT).
+    the acceptance upper bound for the draft-model machinery (a real
+    small checkpoint slots in via LLM_DRAFT_CKPT).
     """
     import os
 
@@ -437,23 +441,66 @@ def _swiglu(x, lp):
     return _mm(g * _mm(x, lp["w_up"]), lp["w_down"])
 
 
-def _layer(cfg: LlamaConfig, x, lp, cos, sin, *, kv_len=None, full_seq=True,
-           mesh=None):
-    """One full-sequence decoder block (training / prefill).
-    Returns (x, k_proj, v_proj)."""
+# Where the block's activations are pinned when a mesh is in context
+# (``constrain`` is a no-op without one): heads on tp, batch on dp,
+# sequence on sp. Programs that never ran on a mesh pass neither.
+_QK_SPEC = P("dp", None, "tp", None)
+_ACT_SPEC = P("dp", "sp", None)
+
+
+def _block(cfg: LlamaConfig, x, lp, cos, sin, attend, *, qk_spec=None,
+           out_spec=None):
+    """THE decoder block, for any ``x`` [b, s, D]: norm → q/k/v → rope →
+    ``attend`` → ``wo`` → residual → SwiGLU.
+
+    ``attend(q, k, v) -> (o, kept)`` is the only thing a program supplies:
+    it writes the roped K and V where its layout keeps them (nowhere, a
+    dense cache row, a segment, pages, a shard's pages) and returns the
+    attention output [b, s, H, hd] (or flat) plus whatever the layer scan
+    must carry on — the updated cache arrays, or this layer's (k, v).
+    Returns (x, kept)."""
     b, s, _ = x.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    pin = lambda a, spec: a if spec is None else constrain(a, spec)
 
     with jax.named_scope("attention"):
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
         q = _mm(h, lp["wq"]).reshape(b, s, H, hd)
         k = _mm(h, lp["wk"]).reshape(b, s, KV, hd)
         v = _mm(h, lp["wv"]).reshape(b, s, KV, hd)
-        q = constrain(q, P("dp", None, "tp", None))
-        k = constrain(k, P("dp", None, "tp", None))
+        q = pin(q, qk_spec)
+        k = pin(k, qk_spec)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
+        o, kept = attend(q, k, v)
+        x = x + pin(_mm(o.reshape(b, s, H * hd), lp["wo"]), out_spec)
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+        x = x + pin(_swiglu(h, lp), out_spec)
+    return x, kept
 
+
+def _head(params: dict, cfg: LlamaConfig, x, pick):
+    """Final norm, then the ``lm_head`` projection of the rows ``pick``
+    selects from [b, s, D] (project only what is sampled) -> f32 logits."""
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope("lm_head"):
+        return _mm(pick(x), params["lm_head"]).astype(jnp.float32)
+
+
+def _full_sequence(params: dict, tokens, cfg: LlamaConfig, seq_lens, mesh,
+                   pick, *, keep_kv: bool, remat: bool = False):
+    """Embed ``tokens`` [B, S], run every block over the whole sequence
+    and project ``pick``'s rows: (logits, stacked per-layer (k, v) or
+    None). ``forward`` and ``prefill`` are this with and without the K/V
+    kept; nothing but ``x`` rides the scan's carry."""
+    x = params["embed"][tokens].astype(cfg.dtype)
+    x = constrain(x, _ACT_SPEC)
+    positions = jnp.arange(tokens.shape[1])[None, :]
+    cos, sin = rope_table(positions, cfg.head_dim, cfg.rope_theta,
+                          scaling=cfg.rope_scaling)
+
+    def attend(q, k, v):
         kf, vf = repeat_kv(k, cfg.n_rep), repeat_kv(v, cfg.n_rep)
         if cfg.sequence_parallel and mesh is not None:
             # long-context: exact sequence-parallel attention over sp — K/V
@@ -463,101 +510,23 @@ def _layer(cfg: LlamaConfig, x, lp, cos, sin, *, kv_len=None, full_seq=True,
 
             sp_attn = (ring_attention if cfg.attn_impl == "ring"
                        else ulysses_attention)
-            o = sp_attn(q, kf, vf, mesh, kv_len=kv_len, causal=True)
+            o = sp_attn(q, kf, vf, mesh, kv_len=seq_lens, causal=True)
         elif cfg.use_flash:
-            o = flash_attention(q, kf, vf, causal=True, kv_len=kv_len)
+            o = flash_attention(q, kf, vf, causal=True, kv_len=seq_lens)
         else:
-            o = attention(q, kf, vf, causal=True, kv_len=kv_len)
+            o = attention(q, kf, vf, causal=True, kv_len=seq_lens)
+        return o, ((k, v) if keep_kv else None)
 
-        o = o.reshape(b, s, H * hd)
-        x = x + constrain(_mm(o, lp["wo"]), P("dp", "sp", None))
+    def body(x, lp):
+        return _block(cfg, x, lp, cos, sin, attend, qk_spec=_QK_SPEC,
+                      out_spec=_ACT_SPEC)
 
-    with jax.named_scope("mlp"):
-        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + constrain(_swiglu(h, lp), P("dp", "sp", None))
-    return x, k, v
-
-
-def _decode_layer(cfg: LlamaConfig, x, lp, cos, sin, arrays, layer,
-                  pos, rows, mesh=None):
-    """One decode block writing directly into the FULL stacked cache.
-
-    ``arrays`` is the cache dict minus "len" ("k"/"v", plus
-    "k_scale"/"v_scale" when int8-quantized). The caches ride the layer
-    scan's CARRY so XLA aliases them in place: a first version returned
-    per-layer caches through scan ys, which restacked (= copied) the
-    entire multi-GB cache every token — that copy, not attention, was the
-    first decode bottleneck. Here the only cache write is the [B, KV, D]
-    scatter of the new token at ``[layer, rows, pos]``.
-    """
-    b = x.shape[0]
-    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    # a row that has sat at capacity (decode_step caps its ``len`` at
-    # S_max) must not ask the kernels for S_max + 1 keys: the Pallas decode
-    # kernel would fetch a block past the end of the cache
-    kv_len = jnp.minimum(pos + 1, arrays["k"].shape[2])
-
-    with jax.named_scope("attention"):
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q = _mm(h, lp["wq"]).reshape(b, 1, H, hd)
-        k = _mm(h, lp["wk"]).reshape(b, 1, KV, hd)
-        v = _mm(h, lp["wv"]).reshape(b, 1, KV, hd)
-        q = constrain(q, P("dp", None, "tp", None))
-        k = constrain(k, P("dp", None, "tp", None))
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-
-        if cfg.kv_quant:
-            from ..ops import quantize_kv
-
-            kq, k_sc = quantize_kv(k[:, 0])
-            vq, v_sc = quantize_kv(v[:, 0])
-            # int8 values scatter flat ([B, KV*D] rows); scales are
-            # [L, B, KV, S]: scatter the [B, KV] token scales at each row's
-            # position via full advanced indexing
-            kv_idx = jnp.arange(KV)[None, :]
-            arrays = {
-                "k": arrays["k"].at[layer, rows, pos].set(kq.reshape(b, KV * hd)),
-                "v": arrays["v"].at[layer, rows, pos].set(vq.reshape(b, KV * hd)),
-                "k_scale": arrays["k_scale"].at[
-                    layer, rows[:, None], kv_idx, pos[:, None]].set(k_sc),
-                "v_scale": arrays["v_scale"].at[
-                    layer, rows[:, None], kv_idx, pos[:, None]].set(v_sc),
-            }
-            if cfg.sequence_parallel and mesh is not None:
-                from ..parallel.ring import sp_decode_attention
-
-                o = sp_decode_attention(
-                    q, arrays["k"], arrays["v"], kv_len, mesh, layer=layer,
-                    k_scale=arrays["k_scale"], v_scale=arrays["v_scale"])
-            else:
-                o = cached_decode_attention(
-                    q, arrays["k"], arrays["v"], kv_len, layer=layer,
-                    use_kernel=cfg.use_flash,
-                    k_scale=arrays["k_scale"], v_scale=arrays["v_scale"])
-        else:
-            arrays = {
-                "k": arrays["k"].at[layer, rows, pos].set(k[:, 0]),
-                "v": arrays["v"].at[layer, rows, pos].set(v[:, 0]),
-            }
-            if cfg.sequence_parallel and mesh is not None:
-                # S-sharded cache: grouped online-softmax per shard + one
-                # pmax/psum combine (parallel/ring.py) — no cache all-gather
-                from ..parallel.ring import sp_decode_attention
-
-                o = sp_decode_attention(q, arrays["k"], arrays["v"], kv_len,
-                                        mesh, layer=layer)
-            else:
-                o = cached_decode_attention(q, arrays["k"], arrays["v"], kv_len,
-                                            layer=layer,
-                                            use_kernel=cfg.use_flash)
-
-        x = x + constrain(_mm(o.reshape(b, 1, H * hd), lp["wo"]),
-                          P("dp", "sp", None))
-    with jax.named_scope("mlp"):
-        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + constrain(_swiglu(h, lp), P("dp", "sp", None))
-    return x, arrays
+    if remat:
+        # recompute layer activations in the backward pass: HBM footprint
+        # stays O(1) in depth for long-sequence training
+        body = jax.checkpoint(body)
+    x, kv = jax.lax.scan(body, x, params["layers"])
+    return _head(params, cfg, x, pick), kv
 
 
 def forward(params: dict, tokens: jnp.ndarray, cfg: LlamaConfig,
@@ -567,26 +536,9 @@ def forward(params: dict, tokens: jnp.ndarray, cfg: LlamaConfig,
     Used for training and for prefill-without-cache; ``seq_lens`` masks
     padded tail positions out of attention.
     """
-    x = params["embed"][tokens].astype(cfg.dtype)
-    x = constrain(x, P("dp", "sp", None))
-    positions = jnp.arange(tokens.shape[1])[None, :]
-    cos, sin = rope_table(positions, cfg.head_dim, cfg.rope_theta,
-                          scaling=cfg.rope_scaling)
-
-    def body(x, lp):
-        x, _, _ = _layer(cfg, x, lp, cos, sin, kv_len=seq_lens, full_seq=True,
-                         mesh=mesh)
-        return x, None
-
-    if cfg.remat:
-        # recompute layer activations in the backward pass: HBM footprint
-        # stays O(1) in depth for long-sequence training
-        body = jax.checkpoint(body)
-    x, _ = jax.lax.scan(body, x, params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    with jax.named_scope("lm_head"):
-        logits = _mm(x, params["lm_head"]).astype(jnp.float32)
-    return constrain(logits, P("dp", "sp", None))
+    logits, _ = _full_sequence(params, tokens, cfg, seq_lens, mesh,
+                               lambda x: x, keep_kv=False, remat=cfg.remat)
+    return constrain(logits, _ACT_SPEC)
 
 
 # -- KV-cache serving path ----------------------------------------------------
@@ -608,8 +560,6 @@ def kv_store_width(cfg: LlamaConfig) -> int:
 def kv_encode(cfg: LlamaConfig, x: jnp.ndarray):
     """Quantize [..., KV, hd] at the config's precision. Returns
     (values [..., KV, kv_store_width], {plane: [..., KV]})."""
-    from ..ops import quantize_kv, quantize_kv4
-
     if cfg.kv_bits == 4:
         q, sc, zp = quantize_kv4(x)
         return q, {"scale": sc, "zero": zp}
@@ -621,8 +571,6 @@ def kv_decode(cfg: LlamaConfig, q: jnp.ndarray, planes: dict,
               dtype=None) -> jnp.ndarray:
     """Dequantize values [..., KV, kv_store_width] with their planes back
     to [..., KV, hd] — the inverse of ``kv_encode``."""
-    from ..ops import dequantize_kv, dequantize_kv4
-
     dtype = dtype or cfg.dtype
     if cfg.kv_bits == 4:
         return dequantize_kv4(q, planes["scale"], planes["zero"], dtype)
@@ -661,6 +609,93 @@ def init_cache(cfg: LlamaConfig, batch: int, max_seq: int | None = None) -> dict
     }
 
 
+def _planes(cache: dict) -> dict:
+    """The cache's arrays without its ``len``: what rides the layer scan."""
+    return {key: cache[key] for key in cache if key != "len"}
+
+
+def _scan_blocks(params: dict, cfg: LlamaConfig, x, cos, sin, arrays: dict,
+                 attend_at, pick, **pins):
+    """The frame every cached program shares: blocks over
+    ``params["layers"]``, final norm, ``lm_head`` of ``pick``'s rows.
+    Returns (logits, arrays).
+
+    Weights stream through the scan's xs; the FULL cache ``arrays`` ride
+    its CARRY beside a layer counter, so XLA aliases them in place. A
+    first version returned per-layer caches through scan ys, which
+    restacked (= copied) the entire multi-GB cache every token — that
+    copy, not attention, was the first decode bottleneck.
+    ``attend_at(arrays, layer)`` gives the block's ``attend`` for one
+    layer; the ``kept`` it returns is the updated ``arrays``."""
+    def body(carry, lp):
+        x, arrays, layer = carry
+        x, arrays = _block(cfg, x, lp, cos, sin, attend_at(arrays, layer),
+                           **pins)
+        return (x, arrays, layer + 1), None
+
+    (x, arrays, _), _ = jax.lax.scan(
+        body, (x, arrays, jnp.int32(0)), params["layers"])
+    return _head(params, cfg, x, pick), arrays
+
+
+def _store_kv(cfg: LlamaConfig, arrays: dict, layer, at, k, v, *,
+              drop: int | None = None, mode=None) -> dict:
+    """Write the block's ``k``/``v`` [b, s, KV, hd] into layer ``layer``
+    of a cache at ``at = (i, j)``: (slot row, position) of the dense
+    cache, (page, offset) of the pool — the two layouts keep the same
+    planes (init_cache, init_paged_cache). ``i`` and ``j`` are [b, s], or
+    lack the axis ``drop`` of size one (decode's s, one slot's b).
+    Quantised values scatter FLAT ([..., KV*W] rows); their scale/zero
+    planes are sequence-minor, so each vector's [KV] entries scatter at
+    (i, :, j) through full advanced indexing."""
+    i, j = at
+    cells = lambda a: a if drop is None else a[(slice(None),) * drop + (0,)]
+    if not cfg.kv_quant:
+        dt = arrays["k"].dtype
+        return {
+            "k": arrays["k"].at[layer, i, j].set(cells(k).astype(dt),
+                                                 mode=mode),
+            "v": arrays["v"].at[layer, i, j].set(cells(v).astype(dt),
+                                                 mode=mode)}
+    kq, k_pl = kv_encode(cfg, cells(k))
+    vq, v_pl = kv_encode(cfg, cells(v))
+    kv_i = jnp.arange(cfg.n_kv_heads)[(None,) * j.ndim]
+    flat = lambda q: q.reshape(*q.shape[:-2], -1)
+    arrays = dict(arrays)
+    arrays["k"] = arrays["k"].at[layer, i, j].set(flat(kq), mode=mode)
+    arrays["v"] = arrays["v"].at[layer, i, j].set(flat(vq), mode=mode)
+    for name, planes in (("k", k_pl), ("v", v_pl)):
+        for pl, val in planes.items():
+            key = f"{name}_{pl}"
+            arrays[key] = arrays[key].at[
+                layer, i[..., None], kv_i, j[..., None]].set(val, mode=mode)
+    return arrays
+
+
+def _gather_pages(cfg: LlamaConfig, arrays: dict, layer, table):
+    """(k, v) [B, P*page_s, KV, hd] of the virtual sequences that
+    ``table`` ([B, P], or one row [P] -> B = 1) maps onto layer ``layer``
+    of the pool, dequantised to ``cfg.dtype`` where the pages are int8 or
+    int4. What every paged reader without a kernel attends over."""
+    n = 1 if table.ndim == 1 else table.shape[0]
+    KV = cfg.n_kv_heads
+    lyr = {key: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+           for key, a in arrays.items()}
+
+    def virt(name):
+        vals = jnp.take(lyr[name], table, axis=0)       # [B, P, ps, ...]
+        if not cfg.kv_quant:
+            return vals.reshape(n, -1, KV, cfg.head_dim)
+        planes = {
+            pl: jnp.swapaxes(jnp.take(lyr[f"{name}_{pl}"], table, axis=0),
+                             -1, -2).reshape(n, -1, KV)  # [B, P, KV, ps] ->
+            for pl in kv_plane_names(cfg)}
+        return kv_decode(cfg, vals.reshape(n, -1, KV, kv_store_width(cfg)),
+                         planes, cfg.dtype)
+
+    return virt("k"), virt("v")
+
+
 def prefill(params: dict, tokens: jnp.ndarray, seq_lens: jnp.ndarray,
             cfg: LlamaConfig, cache: dict, mesh=None
             ) -> tuple[jnp.ndarray, dict]:
@@ -670,24 +705,10 @@ def prefill(params: dict, tokens: jnp.ndarray, seq_lens: jnp.ndarray,
     ``seq_lens`` gives each row's true prompt length.
     """
     b, s = tokens.shape
-    x = params["embed"][tokens].astype(cfg.dtype)
-    x = constrain(x, P("dp", "sp", None))
-    positions = jnp.arange(s)[None, :]
-    cos, sin = rope_table(positions, cfg.head_dim, cfg.rope_theta,
-                          scaling=cfg.rope_scaling)
-
-    def body(x, lp):
-        x, k, v = _layer(cfg, x, lp, cos, sin, kv_len=seq_lens, full_seq=True,
-                         mesh=mesh)
-        return x, (k, v)
-
-    x, (ks, vs) = jax.lax.scan(body, x, params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     # gather each row's last valid position, then project only that row
-    rows = jnp.arange(b)
-    last = x[rows, seq_lens - 1]  # [B, D]
-    with jax.named_scope("lm_head"):
-        logits = _mm(last, params["lm_head"]).astype(jnp.float32)
+    logits, (ks, vs) = _full_sequence(
+        params, tokens, cfg, seq_lens, mesh,
+        lambda x: x[jnp.arange(b), seq_lens - 1], keep_kv=True)
 
     S_max = cache["k"].shape[2]
     pad = S_max - s
@@ -755,77 +776,62 @@ def prefill_segment_into(params: dict, tokens: jnp.ndarray,
     row's garbage writes out of bounds (dropped) instead of corrupting
     prefilled positions — and the true prompt length on the final
     segment. Composes with the int8 cache (kv_quant)."""
-    from ..ops import (apply_rope, attention, dequantize_kv, quantize_kv,
-                       repeat_kv, rms_norm, rope_table)
-
     if cfg.kv_bits == 4:
         raise ValueError("int4 KV is a paged-cache precision — use "
                          "page_size > 0 (paged_suffix_prefill)")
     _, c = tokens.shape
-    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    KV, hd = cfg.n_kv_heads, cfg.head_dim
     positions = start + jnp.arange(c)[None, :]            # [1, C]
     x = params["embed"][tokens].astype(cfg.dtype)         # [1, C, D]
     cos, sin = rope_table(positions, cfg.head_dim, cfg.rope_theta,
                           scaling=cfg.rope_scaling)
     valid_to = start + seg_len[0]                         # rows < this attend
 
-    def body(carry, lp):
-        x, arrays, layer = carry
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q = _mm(h, lp["wq"]).reshape(1, c, H, hd)
-        k = _mm(h, lp["wk"]).reshape(1, c, KV, hd)
-        v = _mm(h, lp["wv"]).reshape(1, c, KV, hd)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        if cfg.kv_quant:
-            kq, k_sc = quantize_kv(k[0])     # [C, KV, hd] -> sc [C, KV]
-            vq, v_sc = quantize_kv(v[0])
-            upd_q = lambda a, w: jax.lax.dynamic_update_slice(
-                a, w.reshape(1, 1, c, KV * hd), (layer, slot, start, 0))
-            upd_s = lambda a, s_: jax.lax.dynamic_update_slice(
-                a, s_.T[None, None], (layer, slot, jnp.int32(0), start))
-            arrays = {"k": upd_q(arrays["k"], kq),
-                      "v": upd_q(arrays["v"], vq),
-                      "k_scale": upd_s(arrays["k_scale"], k_sc),
-                      "v_scale": upd_s(arrays["v_scale"], v_sc)}
-            s_max = arrays["k"].shape[2]
-            row = lambda a: jax.lax.dynamic_slice(
-                a, (layer, slot, 0, 0), (1, 1, s_max, KV * hd)
-            )[0, 0].reshape(s_max, KV, hd)
-            row_s = lambda a: jax.lax.dynamic_slice(
-                a, (layer, slot, 0, 0), (1, 1, KV, s_max))[0, 0]
-            k_row = dequantize_kv(row(arrays["k"]),
-                                  row_s(arrays["k_scale"]).T,
-                                  cfg.dtype)[None]
-            v_row = dequantize_kv(row(arrays["v"]),
-                                  row_s(arrays["v_scale"]).T,
-                                  cfg.dtype)[None]
-        else:
-            dt = arrays["k"].dtype
-            upd = lambda a, w: jax.lax.dynamic_update_slice(
-                a, w.astype(dt)[:, None], (layer, slot, start, 0, 0))
-            arrays = {"k": upd(arrays["k"], k), "v": upd(arrays["v"], v)}
-            s_max = arrays["k"].shape[2]
-            row5 = lambda a: jax.lax.dynamic_slice(
-                a, (layer, slot, 0, 0, 0), (1, 1, s_max, KV, hd))[0]
-            k_row, v_row = row5(arrays["k"]), row5(arrays["v"])
-        o = attention(q, repeat_kv(k_row, cfg.n_rep),
-                      repeat_kv(v_row, cfg.n_rep), causal=True,
-                      q_offset=start, kv_len=valid_to[None])
-        x = x + _mm(o.reshape(1, c, H * hd), lp["wo"])
-        h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + _swiglu(h2, lp)
-        return (x, arrays, layer + 1), None
+    def attend_at(arrays, layer):
+        # the segment is contiguous in one cache row: slab updates and one
+        # row's slice, where decode scatters and reads every row
+        s_max = arrays["k"].shape[2]
 
-    arrays0 = {key: cache[key] for key in cache if key != "len"}
-    (x, arrays, _), _ = jax.lax.scan(
-        body, (x, arrays0, jnp.int32(0)), params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    last = x[0, seg_len[0] - 1]                           # [D]
-    with jax.named_scope("lm_head"):
-        logits = _mm(last[None], params["lm_head"]).astype(jnp.float32)
-    return logits, {**arrays,
-                    "len": cache["len"].at[slot].set(new_len)}
+        def attend(q, k, v):
+            if cfg.kv_quant:
+                kq, k_sc = quantize_kv(k[0])  # [C, KV, hd] -> sc [C, KV]
+                vq, v_sc = quantize_kv(v[0])
+                upd_q = lambda a, w: jax.lax.dynamic_update_slice(
+                    a, w.reshape(1, 1, c, KV * hd), (layer, slot, start, 0))
+                upd_s = lambda a, s_: jax.lax.dynamic_update_slice(
+                    a, s_.T[None, None], (layer, slot, jnp.int32(0), start))
+                new = {"k": upd_q(arrays["k"], kq),
+                       "v": upd_q(arrays["v"], vq),
+                       "k_scale": upd_s(arrays["k_scale"], k_sc),
+                       "v_scale": upd_s(arrays["v_scale"], v_sc)}
+                row = lambda a: jax.lax.dynamic_slice(
+                    a, (layer, slot, 0, 0), (1, 1, s_max, KV * hd)
+                )[0, 0].reshape(s_max, KV, hd)
+                row_s = lambda a: jax.lax.dynamic_slice(
+                    a, (layer, slot, 0, 0), (1, 1, KV, s_max))[0, 0]
+                deq = lambda name: dequantize_kv(
+                    row(new[name]), row_s(new[f"{name}_scale"]).T,
+                    cfg.dtype)[None]
+                k_row, v_row = deq("k"), deq("v")
+            else:
+                dt = arrays["k"].dtype
+                upd = lambda a, w: jax.lax.dynamic_update_slice(
+                    a, w.astype(dt)[:, None], (layer, slot, start, 0, 0))
+                new = {"k": upd(arrays["k"], k), "v": upd(arrays["v"], v)}
+                row5 = lambda a: jax.lax.dynamic_slice(
+                    a, (layer, slot, 0, 0, 0), (1, 1, s_max, KV, hd))[0]
+                k_row, v_row = row5(new["k"]), row5(new["v"])
+            o = attention(q, repeat_kv(k_row, cfg.n_rep),
+                          repeat_kv(v_row, cfg.n_rep), causal=True,
+                          q_offset=start, kv_len=valid_to[None])
+            return o, new
+
+        return attend
+
+    logits, arrays = _scan_blocks(
+        params, cfg, x, cos, sin, _planes(cache), attend_at,
+        lambda x: x[0, seg_len[0] - 1][None])
+    return logits, {**arrays, "len": cache["len"].at[slot].set(new_len)}
 
 
 def decode_step(params: dict, tokens: jnp.ndarray, cache: dict,
@@ -833,7 +839,9 @@ def decode_step(params: dict, tokens: jnp.ndarray, cache: dict,
     """One token per row: tokens [B] -> (logits [B, V], updated cache).
 
     Rows may sit at different positions (continuous batching); each row
-    writes its cache slot at its own ``len`` and attends to len+1 keys.
+    writes its cache slot at its own ``len`` and attends to len+1 keys —
+    the only cache write is the [B, KV, D] scatter of the new token at
+    ``[layer, rows, pos]``.
     """
     if cfg.kv_bits == 4:
         raise ValueError("int4 KV is a paged-cache precision — use "
@@ -845,21 +853,36 @@ def decode_step(params: dict, tokens: jnp.ndarray, cache: dict,
                           scaling=cfg.rope_scaling)
     rows = jnp.arange(b)
 
-    # weights stream through scan xs; the FULL caches ride the carry with a
-    # carried layer counter, so cache updates alias in place (see
-    # _decode_layer docstring for why ys-restacking was the r1 bottleneck)
-    def body(carry, lp):
-        x, arrays, layer = carry
-        x, arrays = _decode_layer(
-            cfg, x, lp, cos, sin, arrays, layer, pos, rows, mesh=mesh)
-        return (x, arrays, layer + 1), None
+    def attend_at(arrays, layer):
+        # a row that has sat at capacity (its ``len`` is capped at S_max
+        # below) must not ask the kernels for S_max + 1 keys: the Pallas
+        # decode kernel would fetch a block past the end of the cache
+        kv_len = jnp.minimum(pos + 1, arrays["k"].shape[2])
 
-    arrays0 = {key: cache[key] for key in cache if key != "len"}
-    (x, arrays, _), _ = jax.lax.scan(
-        body, (x, arrays0, jnp.int32(0)), params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    with jax.named_scope("lm_head"):
-        logits = _mm(x[:, 0], params["lm_head"]).astype(jnp.float32)
+        def attend(q, k, v):
+            new = _store_kv(cfg, arrays, layer, (rows, pos), k, v, drop=1)
+            scales = ({"k_scale": new["k_scale"], "v_scale": new["v_scale"]}
+                      if cfg.kv_quant else {})
+            if cfg.sequence_parallel and mesh is not None:
+                # S-sharded cache: grouped online-softmax per shard + one
+                # pmax/psum combine (parallel/ring.py) — no cache
+                # all-gather; each shard dequantises its own int8 slice
+                from ..parallel.ring import sp_decode_attention
+
+                o = sp_decode_attention(q, new["k"], new["v"], kv_len, mesh,
+                                        layer=layer, **scales)
+            else:
+                o = cached_decode_attention(q, new["k"], new["v"], kv_len,
+                                            layer=layer,
+                                            use_kernel=cfg.use_flash,
+                                            **scales)
+            return o, new
+
+        return attend
+
+    logits, arrays = _scan_blocks(
+        params, cfg, x, cos, sin, _planes(cache), attend_at,
+        lambda x: x[:, 0], qk_spec=_QK_SPEC, out_spec=_ACT_SPEC)
     # cap len at capacity: rows past the end keep decoding garbage (their
     # cache writes are dropped as out-of-bounds) but never index OOB.
     S_max = cache["k"].shape[2]
@@ -877,7 +900,7 @@ def init_paged_cache(cfg: LlamaConfig, batch: int, n_pages: int,
     virtual positions onto pool pages through a host-owned page table
     ([B, pages_per_slot] int32, passed into each program), so concurrent
     slot count is bounded by ACTUAL tokens, not worst case — the capacity
-    lever for long-context serving (config7). Page 0 is reserved as
+    lever for long-context serving. Page 0 is reserved as
     scratch: unallocated table entries point at it, over-capacity writes
     land there harmlessly, and kv_len masking keeps reads out.
 
@@ -943,6 +966,23 @@ def paged_prefill_into(params: dict, tokens: jnp.ndarray,
     return logits, {**arrays, "len": new_len}
 
 
+def _page_of(table, positions, page_s: int):
+    """(page, offset) of virtual ``positions`` ([S] through one slot's
+    row [P]; [B] or [B, W] through ``table`` [B, P]). Positions past
+    virtual capacity land in scratch page 0 — the paged analogue of the
+    dense path's dropped out-of-bounds scatters — never in a wrapped
+    real page."""
+    p_max = table.shape[-1]
+
+    def entry():
+        rows = () if table.ndim == 1 else (jnp.arange(table.shape[0]).reshape(
+            -1, *[1] * (positions.ndim - 1)),)
+        return table[(*rows, jnp.minimum(positions // page_s, p_max - 1))]
+
+    page = jnp.where(positions < p_max * page_s, entry(), 0)
+    return page, positions % page_s
+
+
 def paged_suffix_prefill(params: dict, tokens: jnp.ndarray,
                          seq_lens: jnp.ndarray, cfg: LlamaConfig,
                          cache: dict, table_row: jnp.ndarray,
@@ -958,93 +998,31 @@ def paged_suffix_prefill(params: dict, tokens: jnp.ndarray,
     prefill_into argument). Returns last-valid-token logits [1, V].
     Composes with int8 pages (cfg.kv_quant).
     """
-    from ..ops import (apply_rope, attention, dequantize_kv, quantize_kv,
-                       repeat_kv, rms_norm, rope_table)
-
     b, s = tokens.shape
-    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     start = jnp.asarray(start, jnp.int32)
     positions = start + jnp.arange(s)[None, :]            # [1, S_pad]
-    vpos = positions[0]                                   # [S_pad]
-    p_max = table_row.shape[0]
-    # positions past virtual capacity write into scratch page 0 (same
-    # guard as paged_decode_step) — never into a wrapped real page
-    page = jnp.where(vpos < p_max * page_s,
-                     table_row[jnp.minimum(vpos // page_s, p_max - 1)], 0)
-    off = vpos % page_s
+    at = _page_of(table_row, positions[0], page_s)
     x = params["embed"][tokens].astype(cfg.dtype)
     cos, sin = rope_table(positions, cfg.head_dim, cfg.rope_theta,
                           scaling=cfg.rope_scaling)
 
-    def body(carry, lp):
-        x, arrays, layer = carry
-        with jax.named_scope("attention"):
-            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-            q = _mm(h, lp["wq"]).reshape(b, s, H, hd)
-            k = _mm(h, lp["wk"]).reshape(b, s, KV, hd)
-            v = _mm(h, lp["wv"]).reshape(b, s, KV, hd)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-            if cfg.kv_quant:
-                kq, k_pl = kv_encode(cfg, k[0])  # [S, KV, W] + planes [S, KV]
-                vq, v_pl = kv_encode(cfg, v[0])
-                w_kv = kq.shape[-1]
-                kv_i = jnp.arange(KV)[None, :]
-                arrays = dict(arrays)
-                arrays["k"] = arrays["k"].at[layer, page, off].set(
-                    kq.reshape(s, KV * w_kv))
-                arrays["v"] = arrays["v"].at[layer, page, off].set(
-                    vq.reshape(s, KV * w_kv))
-                for base, planes in (("k", k_pl), ("v", v_pl)):
-                    for pl, val in planes.items():
-                        key = f"{base}_{pl}"
-                        arrays[key] = arrays[key].at[
-                            layer, page[:, None], kv_i, off[:, None]].set(val)
-
-                def virt(name):
-                    q8 = jnp.take(jax.lax.dynamic_index_in_dim(
-                        arrays[name], layer, 0, keepdims=False),
-                        table_row, axis=0).reshape(1, -1, KV, w_kv)
-                    planes = {}
-                    for pl in kv_plane_names(cfg):
-                        p = jnp.take(jax.lax.dynamic_index_in_dim(
-                            arrays[f"{name}_{pl}"], layer, 0, keepdims=False),
-                            table_row, axis=0)          # [P, KV, ps]
-                        planes[pl] = jnp.swapaxes(p, -1, -2).reshape(1, -1, KV)
-                    return kv_decode(cfg, q8, planes, cfg.dtype)
-
-                k_virt, v_virt = virt("k"), virt("v")
-            else:
-                dt = arrays["k"].dtype
-                arrays = {
-                    "k": arrays["k"].at[layer, page, off].set(k[0].astype(dt)),
-                    "v": arrays["v"].at[layer, page, off].set(v[0].astype(dt)),
-                }
-                k_l = jax.lax.dynamic_index_in_dim(arrays["k"], layer, 0,
-                                                   keepdims=False)
-                v_l = jax.lax.dynamic_index_in_dim(arrays["v"], layer, 0,
-                                                   keepdims=False)
-                # virtual sequence for this ONE slot: [1, P_max*page_s, KV, hd]
-                k_virt = jnp.take(k_l, table_row, axis=0).reshape(1, -1, KV, hd)
-                v_virt = jnp.take(v_l, table_row, axis=0).reshape(1, -1, KV, hd)
+    def attend_at(arrays, layer):
+        def attend(q, k, v):
+            new = _store_kv(cfg, arrays, layer, at, k, v, drop=0)
+            # this ONE slot's virtual sequence [1, P_max*page_s, KV, hd]
+            k_virt, v_virt = _gather_pages(cfg, new, layer, table_row)
             # causal from the segment's absolute offset: suffix token t
             # attends every prefix position plus the window up to itself
             o = attention(q, repeat_kv(k_virt, cfg.n_rep),
                           repeat_kv(v_virt, cfg.n_rep),
                           causal=True, q_offset=start)
-            x = x + _mm(o.reshape(b, s, H * hd), lp["wo"])
-        with jax.named_scope("mlp"):
-            h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-            x = x + _swiglu(h2, lp)
-        return (x, arrays, layer + 1), None
+            return o, new
 
-    arrays0 = {key: cache[key] for key in cache if key != "len"}
-    (x, arrays, _), _ = jax.lax.scan(
-        body, (x, arrays0, jnp.int32(0)), params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    last = x[jnp.arange(b), seq_lens - 1]                 # [1, D]
-    with jax.named_scope("lm_head"):
-        logits = _mm(last, params["lm_head"]).astype(jnp.float32)
+        return attend
+
+    logits, arrays = _scan_blocks(
+        params, cfg, x, cos, sin, _planes(cache), attend_at,
+        lambda x: x[jnp.arange(b), seq_lens - 1])
     return logits, {**arrays, "len": cache["len"]}
 
 
@@ -1059,24 +1037,16 @@ def paged_decode_step(params: dict, tokens: jnp.ndarray, cache: dict,
     kernel that walks the row's live pages), over int8/int4 pages a
     dequantising gather of the row's virtual [P_max * page_s] sequence.
     """
-    from ..ops import (apply_rope, attention, paged_decode_attention,
-                       record_branch, repeat_kv, rms_norm, rope_table)
+    from ..ops import paged_decode_attention, record_branch
 
     b = tokens.shape[0]
     page_s = cache["k"].shape[2]
-    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     pos = cache["len"]                           # [B]
     p_max = table.shape[1]
-    # over-capacity rows (pos pinned at S_virt) write into scratch page 0
-    # — the paged analogue of the dense path's dropped OOB scatters
-    page = jnp.where(
-        pos < p_max * page_s,
-        table[jnp.arange(b), jnp.minimum(pos // page_s, p_max - 1)], 0)
-    off = pos % page_s
+    at = _page_of(table, pos, page_s)
     x = params["embed"][tokens][:, None, :].astype(cfg.dtype)
     cos, sin = rope_table(pos[:, None], cfg.head_dim, cfg.rope_theta,
                           scaling=cfg.rope_scaling)
-    kv_idx = jnp.arange(KV)[None, :]
     # an over-capacity row attends its whole table and no further: one
     # past it would send the kernel's page walk off the table's end
     new_len = jnp.minimum(pos + 1, p_max * page_s)
@@ -1085,69 +1055,25 @@ def paged_decode_step(params: dict, tokens: jnp.ndarray, cache: dict,
     # attends one position of that page, not 2,048 of them
     kv_len = jnp.where(table[:, 0] == 0, 1, new_len)
 
-    def body(carry, lp):
-        x, arrays, layer = carry
-        with jax.named_scope("attention"):
-            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-            q = _mm(h, lp["wq"]).reshape(b, 1, H, hd)
-            k = _mm(h, lp["wk"]).reshape(b, 1, KV, hd)
-            v = _mm(h, lp["wv"]).reshape(b, 1, KV, hd)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
+    def attend_at(arrays, layer):
+        def attend(q, k, v):
+            new = _store_kv(cfg, arrays, layer, at, k, v, drop=1)
             if cfg.kv_quant:
-                kq, k_pl = kv_encode(cfg, k[:, 0])  # [B, KV, W] + [B, KV]
-                vq, v_pl = kv_encode(cfg, v[:, 0])
-                w_kv = kq.shape[-1]
-                arrays = dict(arrays)
-                arrays["k"] = arrays["k"].at[layer, page, off].set(
-                    kq.reshape(b, KV * w_kv))
-                arrays["v"] = arrays["v"].at[layer, page, off].set(
-                    vq.reshape(b, KV * w_kv))
-                for base, planes in (("k", k_pl), ("v", v_pl)):
-                    for pl, val in planes.items():
-                        key = f"{base}_{pl}"
-                        arrays[key] = arrays[key].at[
-                            layer, page[:, None], kv_idx, off[:, None]].set(val)
-
-                def virt(name):
-                    q8 = jnp.take(jax.lax.dynamic_index_in_dim(
-                        arrays[name], layer, 0, keepdims=False), table, axis=0)
-                    q8 = q8.reshape(b, -1, KV, w_kv)    # [B, P*ps, KV, W]
-                    planes = {}
-                    for pl in kv_plane_names(cfg):
-                        p = jnp.take(jax.lax.dynamic_index_in_dim(
-                            arrays[f"{name}_{pl}"], layer, 0, keepdims=False),
-                            table, axis=0)              # [B, P, KV, ps]
-                        planes[pl] = jnp.swapaxes(p, -1, -2).reshape(b, -1, KV)
-                    return kv_decode(cfg, q8, planes, cfg.dtype)
-
                 # quantised pages have no kernel yet
-                record_branch("paged_decode_attention", False, q, arrays["k"])
-                o = attention(q, repeat_kv(virt("k"), cfg.n_rep),
-                              repeat_kv(virt("v"), cfg.n_rep),
+                record_branch("paged_decode_attention", False, q, new["k"])
+                k_virt, v_virt = _gather_pages(cfg, new, layer, table)
+                o = attention(q, repeat_kv(k_virt, cfg.n_rep),
+                              repeat_kv(v_virt, cfg.n_rep),
                               causal=False, kv_len=kv_len)
             else:
-                dt = arrays["k"].dtype
-                arrays = {
-                    "k": arrays["k"].at[layer, page, off].set(
-                        k[:, 0].astype(dt)),
-                    "v": arrays["v"].at[layer, page, off].set(
-                        v[:, 0].astype(dt)),
-                }
-                o = paged_decode_attention(q, arrays["k"], arrays["v"],
-                                           table, kv_len, layer=layer)
-            x = x + _mm(o.reshape(b, 1, H * hd), lp["wo"])
-        with jax.named_scope("mlp"):
-            h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-            x = x + _swiglu(h2, lp)
-        return (x, arrays, layer + 1), None
+                o = paged_decode_attention(q, new["k"], new["v"], table,
+                                           kv_len, layer=layer)
+            return o, new
 
-    arrays0 = {key: cache[key] for key in cache if key != "len"}
-    (x, arrays, _), _ = jax.lax.scan(
-        body, (x, arrays0, jnp.int32(0)), params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    with jax.named_scope("lm_head"):
-        logits = _mm(x[:, 0], params["lm_head"]).astype(jnp.float32)
+        return attend
+
+    logits, arrays = _scan_blocks(params, cfg, x, cos, sin, _planes(cache),
+                                  attend_at, lambda x: x[:, 0])
     return logits, {**arrays, "len": new_len}
 
 
@@ -1172,38 +1098,24 @@ def sp_paged_decode_step(params: dict, tokens: jnp.ndarray, cache: dict,
     (``cfg.kv_quant``): each shard dequantizes only its own pages.
     ``table`` holds GLOBAL page ids, unchanged from the single-device
     layout — striping is purely the pool's device placement."""
-    from ..parallel import P as _P
     from ..parallel import shard_map
 
     b = tokens.shape[0]
     page_s = cache["k"].shape[2]
     p_max = table.shape[1]
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    n_rep = cfg.n_rep
-    arrays0 = {key: cache[key] for key in cache if key != "len"}
-    pos0 = cache["len"]
-    if cfg.kv_quant:
-        pool_specs = {
-            "k": _P(None, "sp", None, None), "v": _P(None, "sp", None, None)}
-        for pl in kv_plane_names(cfg):
-            pool_specs[f"k_{pl}"] = _P(None, "sp", None, None)
-            pool_specs[f"v_{pl}"] = _P(None, "sp", None, None)
-    else:
-        pool_specs = {"k": _P(None, "sp", None, None, None),
-                      "v": _P(None, "sp", None, None, None)}
+    arrays0 = _planes(cache)
+    # values are [L, N, ps, KV, hd] at full precision, flat and with
+    # their planes [L, N, KV, ps] when quantised: pages on sp either way
+    pool_specs = {key: P(None, "sp", *[None] * (a.ndim - 2))
+                  for key, a in arrays0.items()}
 
     def local(params, tokens, arrays, table, pos):
-        from ..ops import apply_rope, rms_norm, rope_table
-
         shard = jax.lax.axis_index("sp")
         p_loc = arrays["k"].shape[1]      # pages THIS device owns
         base = shard * p_loc
-        rows = jnp.arange(b)
         # the write target (global), exactly as paged_decode_step
-        page_g = jnp.where(
-            pos < p_max * page_s,
-            table[rows, jnp.minimum(pos // page_s, p_max - 1)], 0)
-        off = pos % page_s
+        page_g, off = _page_of(table, pos, page_s)
         # non-owned writes route out of bounds and drop
         wpage = jnp.where((page_g >= base) & (page_g < base + p_loc),
                           page_g - base, p_loc)
@@ -1218,97 +1130,46 @@ def sp_paged_decode_step(params: dict, tokens: jnp.ndarray, cache: dict,
         x = params["embed"][tokens][:, None, :].astype(cfg.dtype)
         cos, sin = rope_table(pos[:, None], cfg.head_dim, cfg.rope_theta,
                               scaling=cfg.rope_scaling)
-        kv_idx = jnp.arange(KV)[None, :]
         scale = hd ** -0.5
 
-        def body(carry, lp):
-            x, arrays, layer = carry
-            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-            q = _mm(h, lp["wq"]).reshape(b, 1, H, hd)
-            k = _mm(h, lp["wk"]).reshape(b, 1, KV, hd)
-            v = _mm(h, lp["wv"]).reshape(b, 1, KV, hd)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-            if cfg.kv_quant:
-                kq, k_pl = kv_encode(cfg, k[:, 0])
-                vq, v_pl = kv_encode(cfg, v[:, 0])
-                w_kv = kq.shape[-1]
-                arrays = dict(arrays)
-                arrays["k"] = arrays["k"].at[layer, wpage, off].set(
-                    kq.reshape(b, KV * w_kv), mode="drop")
-                arrays["v"] = arrays["v"].at[layer, wpage, off].set(
-                    vq.reshape(b, KV * w_kv), mode="drop")
-                for bs, planes in (("k", k_pl), ("v", v_pl)):
-                    for pl, val in planes.items():
-                        key = f"{bs}_{pl}"
-                        arrays[key] = arrays[key].at[
-                            layer, wpage[:, None], kv_idx,
-                            off[:, None]].set(val, mode="drop")
+        def attend_at(arrays, layer):
+            def attend(q, k, v):
+                new = _store_kv(cfg, arrays, layer, (wpage, off), k, v,
+                                drop=1, mode="drop")
+                k_virt, v_virt = _gather_pages(cfg, new, layer, ltab)
+                # grouped online-softmax over LOCAL keys, exact
+                # cross-shard combine: one pmax (global row max) + two
+                # psums (rescaled numerator / denominator) —
+                # _sp_decode_local's math over a page-gathered virtual
+                # sequence
+                qg = (q[:, 0].reshape(b, KV, cfg.n_rep, hd)
+                      .astype(jnp.float32) * scale)
+                att = jnp.einsum("bgrd,bsgd->bgrs", qg,
+                                 k_virt.astype(jnp.float32))
+                att = jnp.where(valid[:, None, None, :], att, -1e30)
+                m = jnp.max(att, axis=-1, keepdims=True)
+                m_glob = jax.lax.pmax(m, "sp")
+                p = jnp.exp(att - m_glob)
+                l_loc = jnp.sum(p, axis=-1, keepdims=True)
+                acc_loc = jnp.einsum("bgrs,bsgd->bgrd", p,
+                                     v_virt.astype(jnp.float32))
+                l_glob = jax.lax.psum(l_loc, "sp")
+                acc_glob = jax.lax.psum(acc_loc, "sp")
+                o = (acc_glob / jnp.maximum(l_glob, 1e-30)).astype(cfg.dtype)
+                return o, new
 
-                def virt(name):
-                    q8 = jnp.take(jax.lax.dynamic_index_in_dim(
-                        arrays[name], layer, 0, keepdims=False),
-                        ltab, axis=0).reshape(b, -1, KV, w_kv)
-                    planes = {}
-                    for pl in kv_plane_names(cfg):
-                        p = jnp.take(jax.lax.dynamic_index_in_dim(
-                            arrays[f"{name}_{pl}"], layer, 0,
-                            keepdims=False), ltab, axis=0)  # [B,P,KV,ps]
-                        planes[pl] = jnp.swapaxes(
-                            p, -1, -2).reshape(b, -1, KV)
-                    return kv_decode(cfg, q8, planes, cfg.dtype)
+            return attend
 
-                k_virt, v_virt = virt("k"), virt("v")
-            else:
-                dt = arrays["k"].dtype
-                arrays = {
-                    "k": arrays["k"].at[layer, wpage, off].set(
-                        k[:, 0].astype(dt), mode="drop"),
-                    "v": arrays["v"].at[layer, wpage, off].set(
-                        v[:, 0].astype(dt), mode="drop"),
-                }
-                k_l = jax.lax.dynamic_index_in_dim(arrays["k"], layer, 0,
-                                                   keepdims=False)
-                v_l = jax.lax.dynamic_index_in_dim(arrays["v"], layer, 0,
-                                                   keepdims=False)
-                k_virt = jnp.take(k_l, ltab, axis=0).reshape(b, -1, KV, hd)
-                v_virt = jnp.take(v_l, ltab, axis=0).reshape(b, -1, KV, hd)
-            # grouped online-softmax over LOCAL keys, exact cross-shard
-            # combine: one pmax (global row max) + two psums (rescaled
-            # numerator / denominator) — _sp_decode_local's math over a
-            # page-gathered virtual sequence
-            qg = (q[:, 0].reshape(b, KV, n_rep, hd).astype(jnp.float32)
-                  * scale)
-            att = jnp.einsum("bgrd,bsgd->bgrs", qg,
-                             k_virt.astype(jnp.float32))
-            att = jnp.where(valid[:, None, None, :], att, -1e30)
-            m = jnp.max(att, axis=-1, keepdims=True)
-            m_glob = jax.lax.pmax(m, "sp")
-            p = jnp.exp(att - m_glob)
-            l_loc = jnp.sum(p, axis=-1, keepdims=True)
-            acc_loc = jnp.einsum("bgrs,bsgd->bgrd", p,
-                                 v_virt.astype(jnp.float32))
-            l_glob = jax.lax.psum(l_loc, "sp")
-            acc_glob = jax.lax.psum(acc_loc, "sp")
-            o = (acc_glob / jnp.maximum(l_glob, 1e-30)).astype(x.dtype)
-            o = o.reshape(b, 1, H * hd)
-            x = x + _mm(o, lp["wo"])
-            h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-            x = x + _swiglu(h2, lp)
-            return (x, arrays, layer + 1), None
-
-        (x, arrays, _), _ = jax.lax.scan(
-            body, (x, arrays, jnp.int32(0)), params["layers"])
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = _mm(x[:, 0], params["lm_head"]).astype(jnp.float32)
+        logits, arrays = _scan_blocks(params, cfg, x, cos, sin, arrays,
+                                      attend_at, lambda x: x[:, 0])
         new_len = jnp.minimum(pos + 1, p_max * page_s)
         return logits, arrays, new_len
 
     logits, arrays, new_len = shard_map(
         local, mesh=mesh,
-        in_specs=(_P(), _P(), pool_specs, _P(), _P()),
-        out_specs=(_P(), pool_specs, _P()), check_vma=False,
-    )(params, tokens, arrays0, table, pos0)
+        in_specs=(P(), P(), pool_specs, P(), P()),
+        out_specs=(P(), pool_specs, P()), check_vma=False,
+    )(params, tokens, arrays0, table, cache["len"])
     return logits, {**arrays, "len": new_len}
 
 
@@ -1323,95 +1184,27 @@ def paged_decode_window(params: dict, toks: jnp.ndarray, cache: dict,
     mask can reach them (the decode_window argument, page-routed).
     Composes with int8 pages (cfg.kv_quant): window rows quantize on
     write, attention dequantizes the gathered virtual sequence."""
-    from ..ops import (apply_rope, attention, dequantize_kv, quantize_kv,
-                       repeat_kv, rms_norm, rope_table)
-
-    b, w = toks.shape
-    page_s = cache["k"].shape[2]
-    p_max = table.shape[1]
-    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    w = toks.shape[1]
     pos0 = cache["len"]                                    # [B]
     positions = pos0[:, None] + jnp.arange(w)[None, :]     # [B, W]
-    rows = jnp.arange(b)
-    # over-capacity window cells write into scratch page 0
-    page = jnp.where(
-        positions < p_max * page_s,
-        table[rows[:, None], jnp.minimum(positions // page_s, p_max - 1)],
-        0)                                                 # [B, W]
-    off = positions % page_s
+    at = _page_of(table, positions, cache["k"].shape[2])
     x = params["embed"][toks].astype(cfg.dtype)            # [B, W, D]
     cos, sin = rope_table(positions, cfg.head_dim, cfg.rope_theta,
                           scaling=cfg.rope_scaling)
 
-    kv_idx3 = jnp.arange(KV)[None, None, :]
-
-    def body(carry, lp):
-        x, arrays, layer = carry
-        with jax.named_scope("attention"):
-            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-            q = _mm(h, lp["wq"]).reshape(b, w, H, hd)
-            k = _mm(h, lp["wk"]).reshape(b, w, KV, hd)
-            v = _mm(h, lp["wv"]).reshape(b, w, KV, hd)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-            if cfg.kv_quant:
-                # quantized page layouts (init_paged_cache): values flat
-                # [L, N, ps, KV*W], scale/zero planes [L, N, KV, ps]
-                kq, k_pl = kv_encode(cfg, k)  # [B, W, KV, Wd] + [B, W, KV]
-                vq, v_pl = kv_encode(cfg, v)
-                w_kv = kq.shape[-1]
-                arrays = dict(arrays)
-                arrays["k"] = arrays["k"].at[layer, page, off].set(
-                    kq.reshape(b, w, KV * w_kv))
-                arrays["v"] = arrays["v"].at[layer, page, off].set(
-                    vq.reshape(b, w, KV * w_kv))
-                for base, planes in (("k", k_pl), ("v", v_pl)):
-                    for pl, val in planes.items():
-                        key = f"{base}_{pl}"
-                        arrays[key] = arrays[key].at[
-                            layer, page[:, :, None], kv_idx3,
-                            off[:, :, None]].set(val)
-
-                def virt(name):
-                    q8 = jnp.take(jax.lax.dynamic_index_in_dim(
-                        arrays[name], layer, 0, keepdims=False), table, axis=0)
-                    q8 = q8.reshape(b, -1, KV, w_kv)    # [B, P*ps, KV, W]
-                    planes = {}
-                    for pl in kv_plane_names(cfg):
-                        p = jnp.take(jax.lax.dynamic_index_in_dim(
-                            arrays[f"{name}_{pl}"], layer, 0, keepdims=False),
-                            table, axis=0)              # [B, P, KV, ps]
-                        planes[pl] = jnp.swapaxes(p, -1, -2).reshape(b, -1, KV)
-                    return kv_decode(cfg, q8, planes, cfg.dtype)
-
-                k_virt, v_virt = virt("k"), virt("v")
-            else:
-                dt = arrays["k"].dtype
-                arrays = {
-                    "k": arrays["k"].at[layer, page, off].set(k.astype(dt)),
-                    "v": arrays["v"].at[layer, page, off].set(v.astype(dt)),
-                }
-                k_l = jax.lax.dynamic_index_in_dim(arrays["k"], layer, 0,
-                                                   keepdims=False)
-                v_l = jax.lax.dynamic_index_in_dim(arrays["v"], layer, 0,
-                                                   keepdims=False)
-                k_virt = jnp.take(k_l, table, axis=0).reshape(b, -1, KV, hd)
-                v_virt = jnp.take(v_l, table, axis=0).reshape(b, -1, KV, hd)
+    def attend_at(arrays, layer):
+        def attend(q, k, v):
+            new = _store_kv(cfg, arrays, layer, at, k, v)
+            k_virt, v_virt = _gather_pages(cfg, new, layer, table)
             o = attention(q, repeat_kv(k_virt, cfg.n_rep),
                           repeat_kv(v_virt, cfg.n_rep),
                           causal=True, q_offset=pos0)  # per-row offsets
-            x = x + _mm(o.reshape(b, w, H * hd), lp["wo"])
-        with jax.named_scope("mlp"):
-            h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-            x = x + _swiglu(h2, lp)
-        return (x, arrays, layer + 1), None
+            return o, new
 
-    arrays0 = {key: cache[key] for key in cache if key != "len"}
-    (x, arrays, _), _ = jax.lax.scan(
-        body, (x, arrays0, jnp.int32(0)), params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    with jax.named_scope("lm_head"):
-        logits = _mm(x, params["lm_head"]).astype(jnp.float32)  # [B, W, V]
+        return attend
+
+    logits, arrays = _scan_blocks(params, cfg, x, cos, sin, _planes(cache),
+                                  attend_at, lambda x: x)  # [B, W, V]
     return logits, {**arrays, "len": cache["len"]}
 
 
@@ -1432,15 +1225,11 @@ def decode_window(params: dict, toks: jnp.ndarray, cache: dict,
     dequantized for the window attention — the HBM sweep (the decode
     roofline) still reads int8.
     """
-    from ..ops import (apply_rope, attention, dequantize_kv, quantize_kv,
-                       repeat_kv, rms_norm, rope_table)
-    from ..parallel import constrain
-
     if cfg.kv_bits == 4:
         raise ValueError("int4 KV is a paged-cache precision — use "
                          "page_size > 0 (paged_decode_window)")
     b, w = toks.shape
-    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    KV, hd = cfg.n_kv_heads, cfg.head_dim
     pos0 = cache["len"]                                   # [B]
     positions = pos0[:, None] + jnp.arange(w)[None, :]    # [B, W]
     x = params["embed"][toks].astype(cfg.dtype)           # [B, W, D]
@@ -1448,73 +1237,33 @@ def decode_window(params: dict, toks: jnp.ndarray, cache: dict,
                           scaling=cfg.rope_scaling)
     rows = jnp.arange(b)
 
-    def body(carry, lp):
-        x, arrays, layer = carry
-        with jax.named_scope("attention"):
-            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-            q = _mm(h, lp["wq"]).reshape(b, w, H, hd)
-            k = _mm(h, lp["wk"]).reshape(b, w, KV, hd)
-            v = _mm(h, lp["wv"]).reshape(b, w, KV, hd)
-            q = constrain(q, P("dp", None, "tp", None))
-            k = constrain(k, P("dp", None, "tp", None))
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
+    def attend_at(arrays, layer):
+        def attend(q, k, v):
+            new = _store_kv(cfg, arrays, layer, (rows[:, None], positions),
+                            k, v, mode="drop")
+            lyr = lambda key: jax.lax.dynamic_index_in_dim(
+                new[key], layer, 0, keepdims=False)
             if cfg.kv_quant:
-                # same layouts as _decode_layer: int8 values FLAT [L,B,S,KV*D],
-                # scales [L,B,KV,S] — W rows scatter at their own positions
-                kq, k_sc = quantize_kv(k)      # [B,W,KV,hd] -> sc [B,W,KV]
-                vq, v_sc = quantize_kv(v)
-                r_i = rows[:, None, None]
-                kv_i = jnp.arange(KV)[None, None, :]
-                p_i = positions[:, :, None]
-                arrays = {
-                    "k": arrays["k"].at[layer, rows[:, None], positions].set(
-                        kq.reshape(b, w, KV * hd), mode="drop"),
-                    "v": arrays["v"].at[layer, rows[:, None], positions].set(
-                        vq.reshape(b, w, KV * hd), mode="drop"),
-                    "k_scale": arrays["k_scale"].at[layer, r_i, kv_i, p_i].set(
-                        k_sc, mode="drop"),
-                    "v_scale": arrays["v_scale"].at[layer, r_i, kv_i, p_i].set(
-                        v_sc, mode="drop"),
-                }
-                idx = lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0,
-                                                             keepdims=False)
-                s_max = arrays["k"].shape[2]
-                deq = lambda qv, sc: dequantize_kv(
-                    idx(qv).reshape(b, s_max, KV, hd),
-                    idx(sc).transpose(0, 2, 1), cfg.dtype)
-                k_row = deq(arrays["k"], arrays["k_scale"])
-                v_row = deq(arrays["v"], arrays["v_scale"])
+                s_max = new["k"].shape[2]
+                deq = lambda name: dequantize_kv(
+                    lyr(name).reshape(b, s_max, KV, hd),
+                    lyr(f"{name}_scale").transpose(0, 2, 1), cfg.dtype)
+                k_row, v_row = deq("k"), deq("v")
             else:
-                dt = arrays["k"].dtype
-                arrays = {
-                    "k": arrays["k"].at[layer, rows[:, None], positions].set(
-                        k.astype(dt), mode="drop"),
-                    "v": arrays["v"].at[layer, rows[:, None], positions].set(
-                        v.astype(dt), mode="drop"),
-                }
-                k_row = jax.lax.dynamic_index_in_dim(arrays["k"], layer, 0,
-                                                     keepdims=False)
-                v_row = jax.lax.dynamic_index_in_dim(arrays["v"], layer, 0,
-                                                     keepdims=False)
+                k_row, v_row = lyr("k"), lyr("v")
             # per-row causal offset: query t of row i attends positions
-            # <= pos0[i]+t — its prefix plus the window so far; stale cells
-            # past the window are unreachable
+            # <= pos0[i]+t — its prefix plus the window so far; stale
+            # cells past the window are unreachable
             o = attention(q, repeat_kv(k_row, cfg.n_rep),
                           repeat_kv(v_row, cfg.n_rep),
                           causal=True, q_offset=pos0)
-            x = x + _mm(o.reshape(b, w, H * hd), lp["wo"])
-        with jax.named_scope("mlp"):
-            h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-            x = x + _swiglu(h2, lp)
-        return (x, arrays, layer + 1), None
+            return o, new
 
-    arrays0 = {key: cache[key] for key in cache if key != "len"}
-    (x, arrays, _), _ = jax.lax.scan(
-        body, (x, arrays0, jnp.int32(0)), params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    with jax.named_scope("lm_head"):
-        logits = _mm(x, params["lm_head"]).astype(jnp.float32)  # [B, W, V]
+        return attend
+
+    logits, arrays = _scan_blocks(
+        params, cfg, x, cos, sin, _planes(cache), attend_at,
+        lambda x: x, qk_spec=_QK_SPEC)                    # [B, W, V]
     return logits, {**arrays, "len": cache["len"]}
 
 

@@ -12,8 +12,9 @@ Kernel shape notes (pallas_guide.md):
 - causal skip: K blocks entirely above the diagonal are not even read
   (grid dimension is masked with ``when``), halving FLOPs and DMA traffic.
 
-Tested in interpret mode on CPU (tests/test_ops.py) and compiled for real
-on TPU by bench.py.
+Tested in interpret mode on CPU (tests/test_ops.py), compiled for a
+described v5e in tests/test_tpu_compile.py, and run on the chip by every
+prefill of the benchmark's cells (benchmark/run.py).
 """
 
 from __future__ import annotations

@@ -2,8 +2,8 @@
 
 ``GOFR_ML_FAULT`` arms probabilistic faults at named points of the device
 dispatch path so the resilience layer (watchdog, crash recovery, typed
-errors) can actually be exercised — by tests/test_resilience.py and the
-bench's fault arm (config4 phase G). Spec grammar, comma-separated::
+errors) can actually be exercised — by tests/test_resilience.py. Spec
+grammar, comma-separated::
 
     point:rate[:ExcName]
 
@@ -55,7 +55,7 @@ reproducible run-to-run.
 
 With a replica pool, ``GOFR_ML_FAULT_REPLICA=<idx>`` narrows the blast
 radius to exactly one replica: only that replica's serving core gets an
-injector (``from_env_for_replica``), so a failover test or bench arm can
+injector (``from_env_for_replica``), so a failover test can
 kill replica N deterministically while its peers stay clean. The front's
 own ``route`` point is replica-independent and stays armed.
 """

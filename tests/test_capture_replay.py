@@ -170,8 +170,8 @@ def test_capture_ring_bounds_and_offset_normalization(monkeypatch):
 
 
 def test_rearming_with_new_ring_size_starts_fresh(monkeypatch):
-    """Re-pinning GOFR_ML_CAPTURE with a DIFFERENT size (the bench's
-    between-boots pattern) must honor the new bound and must NOT leak
+    """Re-pinning GOFR_ML_CAPTURE with a DIFFERENT size (between
+    in-process app boots) must honor the new bound and must NOT leak
     the previous window's records into the next bundle."""
     cap = _arm(monkeypatch, ring=24)
     assert cap.stats()["capacity"] == 24

@@ -117,30 +117,39 @@ def test_assemble_around_an_admission_wave_excludes_its_inner_drain(model):
     own ``gen.drain()`` stamps device_wait/emit INSIDE assemble, and the
     record's assemble seconds leave them out (what the deleted
     ``pending_total`` subtraction did by hand)."""
-    async def scenario():
-        server = LLMServer(_gen(model), name="sp-wave")
+    async def scenario(name):
+        server = LLMServer(_gen(model), name=name)
         try:
             # as long an answer as max_seq 64 holds: on a loaded machine a
             # short one ended before the second request was admitted
             first = asyncio.ensure_future(server.generate([3, 1, 4], 56))
             for _ in range(400):  # until the first request is decoding
-                if _of("sp-wave"):
+                if _of(name):
                     break
                 await asyncio.sleep(0.005)
             await asyncio.gather(first, server.generate([2, 7, 1], 6))
         finally:
             server.close()
 
-    asyncio.run(scenario())
+    # whether the second request lands while the first still decodes is
+    # a race with the machine's other work (six test workers): a life in
+    # which it came too late shows no wave and is run again
     nested = []
-    for r in _of("sp-wave"):
-        for name, a, b in r["spans"]:
-            if name != "assemble":
-                continue
-            inside = [(n, c, d) for n, c, d in r["spans"]
-                      if n in ("device_wait", "emit") and a <= c and d <= b]
-            if inside:
-                nested.append((r, b - a, sum(d - c for _, c, d in inside)))
+    for attempt in range(5):
+        name = f"sp-wave-{attempt}"
+        asyncio.run(scenario(name))
+        for r in _of(name):
+            for span, a, b in r["spans"]:
+                if span != "assemble":
+                    continue
+                inside = [(n, c, d) for n, c, d in r["spans"]
+                          if n in ("device_wait", "emit")
+                          and a <= c and d <= b]
+                if inside:
+                    nested.append(
+                        (r, b - a, sum(d - c for _, c, d in inside)))
+        if nested:
+            break
     assert nested, "no admission wave drained a chunk in flight"
     for r, interval, inner in nested:
         assert r["phases"]["assemble"] <= interval - inner + 1e-6

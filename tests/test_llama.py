@@ -47,22 +47,131 @@ def test_prefill_matches_forward(setup):
     np.testing.assert_array_equal(np.asarray(cache["len"]), [5, 8])
 
 
-def test_decode_matches_forward(setup):
-    """Teacher-forced decode over the cache == full forward, per position."""
-    cfg, params = setup
-    seq = np.array([[3, 1, 4, 1, 5, 9, 2, 6]], np.int32)
-    full = llama.forward(params, jnp.asarray(seq), cfg)
+# One sequence, every cached program: each must give ``forward``'s logits
+# at the positions it computes. Row 1 of two slots carries the sequence;
+# row 0 stays empty (a freed slot: its table row is scratch page 0).
+_SEQ = np.array([[3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8]], np.int32)
+_PAGE = 4
+# largest logit difference over the reference's largest logit: bf16
+# rounding alone at 16 bits, plus the K/V rounding of symmetric int8 and
+# asymmetric int4 per vector (largest readings at the tiny config on the
+# CPU: under 0.0001, 0.017, 0.143)
+_KV_TOL = {16: 0.02, 8: 0.05, 4: 0.25}
 
-    cache = llama.init_cache(cfg, 1, 32)
-    logits, cache = llama.prefill(
-        params, jnp.asarray(seq[:, :4]), jnp.array([4], jnp.int32), cfg, cache
-    )
-    np.testing.assert_allclose(np.asarray(full[0, 3]), np.asarray(logits[0]),
-                               atol=3e-2, rtol=3e-2)
-    for t in range(4, 8):
-        logits, cache = llama.decode_step(params, jnp.asarray(seq[:, t]), cache, cfg)
-        np.testing.assert_allclose(np.asarray(full[0, t]), np.asarray(logits[0]),
-                                   atol=3e-2, rtol=3e-2)
+
+def _dense_prefilled(cfg, params):
+    cache = llama.init_cache(cfg, 2, 32)
+    return llama.prefill_into(
+        params, jnp.asarray(_SEQ[:, :4]), jnp.array([4], jnp.int32), cfg,
+        cache, jnp.int32(1))
+
+
+def _paged_prefilled(cfg, params):
+    cache = llama.init_paged_cache(cfg, 2, 9, _PAGE)
+    table = jnp.asarray([[0] * 8, list(range(1, 9))], jnp.int32)
+    logits, cache = llama.paged_prefill_into(
+        params, jnp.asarray(_SEQ[:, :4]), jnp.array([4], jnp.int32), cfg,
+        cache, table[1, :1], jnp.int32(1), _PAGE)
+    return logits, cache, table
+
+
+def _run_decode_step(cfg, params):
+    logits, cache = _dense_prefilled(cfg, params)
+    out = {3: logits[0]}
+    for t in range(4, 12):
+        logits, cache = llama.decode_step(
+            params, jnp.asarray([0, _SEQ[0, t]], jnp.int32), cache, cfg)
+        out[t] = logits[1]
+    return out
+
+
+def _run_prefill_segment_into(cfg, params):
+    cache = llama.init_cache(cfg, 2, 32)
+    out = {}
+    for start, n in ((0, 4), (4, 4), (8, 3)):  # the last segment is ragged
+        seg = np.zeros((1, 4), np.int32)
+        seg[0, :n] = _SEQ[0, start:start + n]
+        logits, cache = llama.prefill_segment_into(
+            params, jnp.asarray(seg), jnp.array([n], jnp.int32), cfg, cache,
+            jnp.int32(1), jnp.int32(start), jnp.int32(start + n))
+        out[start + n - 1] = logits[0]
+    return out
+
+
+def _run_paged_decode_step(cfg, params):
+    logits, cache, table = _paged_prefilled(cfg, params)
+    out = {3: logits[0]}
+    for t in range(4, 12):
+        logits, cache = llama.paged_decode_step(
+            params, jnp.asarray([0, _SEQ[0, t]], jnp.int32), cache, table, cfg)
+        out[t] = logits[1]
+    return out
+
+
+def _run_paged_suffix_prefill(cfg, params):
+    cache = llama.init_paged_cache(cfg, 2, 9, _PAGE)
+    row = jnp.arange(1, 9, dtype=jnp.int32)
+    first, cache = llama.paged_suffix_prefill(
+        params, jnp.asarray(_SEQ[:, :4]), jnp.array([4], jnp.int32), cfg,
+        cache, row, 0, _PAGE)
+    sfx = np.zeros((1, 8), np.int32)
+    sfx[0, :7] = _SEQ[0, 4:11]
+    second, cache = llama.paged_suffix_prefill(
+        params, jnp.asarray(sfx), jnp.array([7], jnp.int32), cfg, cache, row,
+        4, _PAGE)
+    return {3: first[0], 10: second[0]}
+
+
+def _windows(step, cache):
+    """Two verify windows of four tokens; the caller advances ``len``."""
+    out = {}
+    for start in (4, 8):
+        toks = np.zeros((2, 4), np.int32)
+        toks[1] = _SEQ[0, start:start + 4]
+        logits, cache = step(jnp.asarray(toks), cache)
+        out.update({start + i: logits[1, i] for i in range(4)})
+        cache = {**cache, "len": cache["len"].at[1].add(4)}
+    return out
+
+
+def _run_decode_window(cfg, params):
+    logits, cache = _dense_prefilled(cfg, params)
+    return {3: logits[0], **_windows(
+        lambda toks, c: llama.decode_window(params, toks, c, cfg), cache)}
+
+
+def _run_paged_decode_window(cfg, params):
+    logits, cache, table = _paged_prefilled(cfg, params)
+    return {3: logits[0], **_windows(
+        lambda toks, c: llama.paged_decode_window(params, toks, c, table, cfg),
+        cache)}
+
+
+_CACHED_PROGRAMS = {
+    "decode_step": (_run_decode_step, (16, 8)),
+    "prefill_segment_into": (_run_prefill_segment_into, (16, 8)),
+    "paged_decode_step": (_run_paged_decode_step, (16, 8, 4)),
+    "paged_suffix_prefill": (_run_paged_suffix_prefill, (16, 8, 4)),
+    "decode_window": (_run_decode_window, (16, 8)),
+    "paged_decode_window": (_run_paged_decode_window, (16, 8, 4)),
+}
+
+
+@pytest.mark.parametrize(
+    "program,kv_bits",
+    [(name, bits) for name, (_, precisions) in _CACHED_PROGRAMS.items()
+     for bits in precisions])
+def test_cached_program_matches_forward(setup, program, kv_bits):
+    """Every cached program is the one block with another ``attend``: its
+    logits are ``forward``'s at the same positions, to the KV precision."""
+    _, params = setup
+    cfg = llama.tiny_llama(use_flash=False, kv_bits=kv_bits)
+    full = np.asarray(llama.forward(params, jnp.asarray(_SEQ), cfg)[0])
+    got = _CACHED_PROGRAMS[program][0](cfg, params)
+    assert got, program
+    for pos, logits in got.items():
+        err = np.max(np.abs(np.asarray(logits) - full[pos]))
+        assert err / np.max(np.abs(full[pos])) < _KV_TOL[kv_bits], (pos, err)
 
 
 def test_ragged_decode_rows_at_different_positions(setup):

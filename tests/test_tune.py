@@ -12,13 +12,11 @@ whose tokens bill to the ``canary`` waste reason (the ledger stays
 balanced — mirrored answers never reach a client), promotes into the
 fleet on a good verdict, rolls back on degraded SLO medians, and a
 canary-core crash is a rollback signal that never touches client
-traffic; and the committed ``bench/`` bundle replays identity-1.0 on
-the reference model — the regression gate the bench tune arm rides.
+traffic.
 """
 
 import asyncio
 import json
-import pathlib
 import time
 
 import jax
@@ -32,7 +30,6 @@ from gofr_tpu.ml.capture import runtime_fingerprint, traffic_capture
 from gofr_tpu.ml.generate import Generator
 from gofr_tpu.ml.goodput import goodput_ledger
 from gofr_tpu.ml.llm import LLMServer
-from gofr_tpu.ml.replay import ReplayHarness, load_bundle
 from gofr_tpu.ml.replica import ReplicaPool
 from gofr_tpu.ml import tune as tune_mod
 from gofr_tpu.ml.tune import (PROFILE_FORMAT, TUNABLE_KNOBS, Tuner,
@@ -40,10 +37,6 @@ from gofr_tpu.ml.tune import (PROFILE_FORMAT, TUNABLE_KNOBS, Tuner,
                               profile_boot_warnings, profile_from_env,
                               profile_overlay)
 from gofr_tpu.models import llama
-
-BENCH_BUNDLE = (pathlib.Path(__file__).resolve().parent.parent
-                / "bench" / "tune_window.bundle")
-
 
 @pytest.fixture(scope="module")
 def model():
@@ -566,26 +559,3 @@ def test_canary_boot_validation(model, monkeypatch):
         assert pool.routing_snapshot()["canary"] is None
     finally:
         pool.close()
-
-
-# ------------------------------------------------- committed bundle gate
-def test_committed_bench_bundle_replays_identical(run):
-    """The regression gate the bench tune arm rides: the bundle
-    committed under bench/ replays on the tiny reference model with
-    digest identity 1.0 and a healthy goodput — a serving change that
-    breaks either fails tier-1 here, before any bench run."""
-    assert BENCH_BUNDLE.exists(), "bench/tune_window.bundle is committed"
-    bundle = load_bundle(str(BENCH_BUNDLE))
-    assert len(bundle["requests"]) >= 6
-    server = tune_mod._tiny_builder()({"name": "default", "knobs": {}})
-    try:
-        verdict = run(ReplayHarness(server, bundle, speed=1000.0).run())
-    finally:
-        server.close()
-    assert verdict["identity"]["compared"] == len(bundle["requests"])
-    assert verdict["identity"]["rate"] == 1.0
-    assert verdict["replay_failed"] == 0 and verdict["skipped"] == 0
-    gp = verdict["goodput"]
-    assert gp["balanced"] and gp["goodput"] >= 0.95
-    assert verdict["throughput"]["steady_tok_s"] > 0
-    assert verdict["throughput"]["out_tokens"] == gp["delivered"]
