@@ -304,28 +304,39 @@ def gqa_decode_attention(
 def cached_decode_attention(q, k_cache, v_cache, kv_len, *, layer=None,
                             use_kernel: bool = True,
                             k_scale=None, v_scale=None):
-    """Decode-attention dispatcher: the Pallas length-skipping kernel on TPU
-    when shapes allow (S_max a multiple of its block), the XLA grouped
-    einsum everywhere else.
+    """Decode-attention dispatcher: a Pallas length-skipping kernel on TPU
+    when shapes allow, the XLA grouped einsum everywhere else.
 
     Caches may be per-layer [B, S, KV, D] or the FULL stacked
     [L, B, S, KV, D] with ``layer`` a traced index — the kernel reads the
     layer's slab straight from HBM, and the XLA path relies on the
-    dynamic-index fusing into the einsum. With int8 caches pass
-    ``k_scale``/``v_scale`` ([L, B, KV, S] or [B, KV, S]; seq minor for
-    DMA alignment): the kernel dequantizes in VMEM after the (halved)
-    HBM read.
+    dynamic-index fusing into the einsum. A full-precision cache whose
+    widths have a tiling (``decode_attention.row_tiling``: KV heads and
+    chunks of whole sublane tiles, a head size of whole lanes) is read as
+    the paged layout reads pages: every head in one product in the cache's
+    dtype, the row's tail fetched to its live length. Any other cache takes
+    the kernel that works a KV head at a time where S is a multiple of its
+    block of 256: fewer KV heads than a tile holds, and int8 caches, for
+    which pass ``k_scale``/``v_scale`` ([L, B, KV, S] or [B, KV, S]; seq
+    minor for DMA alignment; dequantized in VMEM after the halved HBM
+    read).
     """
     quantized = k_scale is not None
     # quantized caches are FLAT [L?, B, S, KV*D] (int8 tiling, see
     # models/llama.init_cache); fp caches are [L?, B, S, KV, D]
     stacked = k_cache.ndim == (4 if quantized else 5)
     s_max = k_cache.shape[2] if stacked else k_cache.shape[1]
-    kernel = use_kernel and _on_tpu() and q.shape[1] == 1 and s_max % 256 == 0
+    kernel = use_kernel and _on_tpu() and q.shape[1] == 1
+    if kernel:
+        # (imported here, on a TPU only: the submodule takes the package's
+        # name ``decode_attention`` from the function above)
+        from .decode_attention import gqa_decode_attention_tpu, row_tiling
+
+        kernel = s_max % 256 == 0 or (
+            not quantized and row_tiling(
+                s_max, *k_cache.shape[-2:], k_cache.dtype.itemsize) is not None)
     record_branch("decode_attention", kernel, q, k_cache)
     if kernel:
-        from .decode_attention import gqa_decode_attention_tpu
-
         return gqa_decode_attention_tpu(q, k_cache, v_cache, kv_len,
                                         layer=layer, k_scale=k_scale,
                                         v_scale=v_scale)

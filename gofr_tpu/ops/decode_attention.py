@@ -2,27 +2,50 @@
 
 The decode step's cost is one sweep of the KV cache per layer; with a
 padded [B, S_max, KV, D] cache, XLA reads and masks all S_max positions
-even when a row holds a 100-token conversation in a 2048-slot cache. This
-kernel makes the sweep proportional to the VALID length instead:
+even when a row holds a 100-token conversation in a 2048-slot cache. The
+kernels here make the sweep proportional to the VALID length instead:
 
 - grid = (B,): ONE cell per batch row (a first version gridded over
   (B, S-blocks) and lost everything to per-cell overhead — 256 tiny
   sequential cells per layer; this shape has 32).
 - the caches stay in HBM (``memory_space=ANY``); the kernel issues its own
-  double-buffered ``make_async_copy`` per [block_s, KV, D] chunk inside a
-  ``fori_loop`` whose trip count is ``cdiv(kv_len[b], block_s)`` — the
-  padded tail is neither DMA'd nor computed, so cost tracks the live
-  prefix, not S_max (guide: "DMA Pipeline Pattern").
-- query heads stay grouped: per KV head ``g`` the kernel contracts the
-  ``n_rep`` query rows against the un-expanded chunk, preserving the
-  no-``repeat_kv`` property of ``ops.gqa_decode_attention`` inside VMEM.
+  double-buffered ``make_async_copy`` inside a ``fori_loop`` whose trip
+  count follows ``kv_len[b]`` (scalar prefetch) — the padded tail is
+  neither DMA'd nor computed, so cost tracks the live prefix, not S_max
+  (guide: "DMA Pipeline Pattern").
 - online softmax (running max / sum / accumulator carried in f32 through
   the loop, as in flash_attention.py).
 
-``kv_len`` rides scalar prefetch so trip counts are available before the
-body runs. Reference has no counterpart (pure-Go, no ML — SURVEY §2.10);
-this is the serving-path analogue of the prefill flash kernel, needed to
-hold the BASELINE.md config-#4 token rate at large slot counts and caches.
+Two bodies, chosen by how the cache lies in HBM:
+
+- **One matrix a block** (``_dense_kernel``): a full-precision cache whose
+  KV heads are whole sublane tiles (``KV % 8 == 0``: Mistral's 8,
+  DeepSeek's 32) is the page pool's easy case. ``[S_max, KV, D]`` and
+  ``[S_max * KV, D]`` are then the same bytes (row ``t * KV + g`` is token
+  ``t``, KV head ``g``), and the row is read by
+  ``paged_attention.walk_pages``, the body the paged layout's kernel runs,
+  with arithmetic where that one looks a page up in its table: the row's
+  ``j``-th "page" is its ``j``-th chunk of ``row_tiling``'s tokens. So
+  every head meets a block of 2,048 rows in ONE product on the MXU in the
+  cache's dtype with float32 accumulation, an additive own-head mask
+  standing in for per-head slices (MHA and any group size are the same
+  code), and the block that holds the row's last token is fetched chunk
+  by chunk up to the live length, not whole.
+- **A product a KV head** (``_decode_kernel``): the int8 cache (flat
+  ``[S_max, KV * D]`` values with seq-minor scales, dequantised in VMEM
+  so that HBM only ever moves int8), and a full-precision cache with
+  fewer KV heads than a sublane tile holds (Qwen3-Next's 2 of 256): there
+  the heads of one token lie packed inside one 32-bit word of a (2, 128)
+  tile, the flat view would be a copy of the cache (2 GB a layer call at
+  that cell's size), and putting the block's matrix together in VMEM from
+  a strided slice a head read eight times slower on the chip than this
+  body (``PERF.md`` §6, PR 33). It fetches whole blocks of ``block_s``
+  positions and contracts each KV head's ``n_rep`` query rows against the
+  un-expanded chunk in float32 — no ``repeat_kv`` inside VMEM either.
+
+Reference has no counterpart (pure-Go, no ML — SURVEY §2.10); this is the
+serving-path analogue of the prefill flash kernel, needed to hold the
+BASELINE.md config-#4 token rate at large slot counts and caches.
 """
 
 from __future__ import annotations
@@ -34,9 +57,48 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from .paged_attention import NEG_INF, block_pages, walk_pages
 
-__all__ = ["gqa_decode_attention_tpu"]
+# rows (token x KV head) a chunk, the unit a row's tail is fetched in: the
+# tail over-reads half a chunk a row in the mean (8 tokens at 8 KV heads).
+# On the chip chunks of 128, 256 and 512 rows read alike and a whole block
+# 5 % slower at short rows (PERF.md §6, PR 33)
+_CHUNK_ROWS = 128
+
+__all__ = ["gqa_decode_attention_tpu", "row_tiling"]
+
+
+def row_tiling(s_max: int, kv_heads: int, head_dim: int, itemsize: int
+               ) -> tuple[int, int] | None:
+    """(tokens a chunk, rows a block) for a full-precision cache that
+    ``_dense_kernel`` takes, or None where it does not: the KV heads must
+    be whole sublane tiles (else the flat view is a copy), a row whole
+    chunks, and a chunk must do as a page of ``paged_attention``'s walk,
+    whose block of pages is then the block."""
+    chunk_s = max(1, _CHUNK_ROWS // kv_heads)
+    chunks = block_pages(chunk_s, kv_heads, head_dim, itemsize)
+    if kv_heads % 8 or s_max % chunk_s or chunks is None:
+        return None
+    return chunk_s, chunks * chunk_s * kv_heads
+
+
+def _dense_kernel(kvlen_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
+                  v_buf, own_ref, k_sem, v_sem, *, page_s: int, kv_heads: int,
+                  n_rep: int):
+    """A row's pages lie one behind the other in its own row of the cache;
+    k_hbm/v_hbm: [L, B, S_max * KV, D] in HBM."""
+    b = pl.program_id(0)
+    layer = layer_ref[0]
+    page_rows = page_s * kv_heads
+
+    def page_at(first, j):
+        return layer, b, pl.ds(
+            pl.multiple_of((first + j) * page_rows, page_rows), page_rows)
+
+    walk_pages(kvlen_ref[b], page_at, q_ref, k_hbm, v_hbm, o_ref, k_buf,
+               v_buf, own_ref, k_sem, v_sem, page_s=page_s, kv_heads=kv_heads,
+               n_rep=n_rep)
+
 
 
 def _decode_kernel(kvlen_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
@@ -165,9 +227,13 @@ def gqa_decode_attention_tpu(q, k_cache, v_cache, kv_len, *, layer=None,
     kv_len: [B] int32. Optional ``k_scale``/``v_scale`` ([..., KV, S_max]
     bf16, seq minor) mark an int8 cache: dequantization happens in VMEM.
 
-    Returns [B, 1, H, D] in q.dtype. S_max must divide by ``block_s``
-    (serving caches are power-of-two sized; callers fall back to the XLA
-    path otherwise).
+    Returns [B, 1, H, D] in q.dtype. A full-precision cache that has a
+    tiling (``row_tiling``) is read as one matrix a block, whose size
+    follows from the cache's widths, with ``kv_len`` clamped to
+    ``[1, S_max]`` so that the walk never leaves the row. Every other cache
+    is read a product a KV head in blocks of ``block_s`` positions, which
+    S_max must divide by (serving caches are power-of-two sized; callers
+    fall back to the XLA path otherwise) and ``kv_len`` must not pass.
     """
     b, tq, h, d = q.shape
     quantized = k_scale is not None
@@ -179,47 +245,65 @@ def gqa_decode_attention_tpu(q, k_cache, v_cache, kv_len, *, layer=None,
         layer = 0
     if layer is None:
         raise ValueError("stacked caches require a layer index")
-    s_max = k_cache.shape[2]
+    n_layers, _, s_max = k_cache.shape[:3]
     kv = k_scale.shape[2] if quantized else k_cache.shape[3]
     if tq != 1:
         raise ValueError(f"decode kernel takes one query token, got Tq={tq}")
-    block_s = min(block_s, s_max)
-    if s_max % block_s:
-        raise ValueError(f"S_max {s_max} must divide block_s {block_s}")
     n_rep = h // kv
     kv_len = jnp.asarray(kv_len, jnp.int32)
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    tiling = None if quantized else row_tiling(
+        s_max, kv, d, k_cache.dtype.itemsize)
 
+    row_spec = pl.BlockSpec((None, h, d), lambda bi, kvlen, lyr: (bi, 0, 0))
     in_specs = [
-        pl.BlockSpec((None, h, d), lambda bi, kvlen, lyr: (bi, 0, 0)),
+        row_spec,
         pl.BlockSpec(memory_space=pl.ANY),  # k cache stays in HBM
         pl.BlockSpec(memory_space=pl.ANY),  # v cache stays in HBM
     ]
-    buf_shape = (2, block_s, kv * d) if quantized else (2, block_s, kv, d)
-    scratch = [
-        pltpu.VMEM(buf_shape, k_cache.dtype),
-        pltpu.VMEM(buf_shape, v_cache.dtype),
-    ]
     sems = [pltpu.SemaphoreType.DMA((2,)), pltpu.SemaphoreType.DMA((2,))]
-    args = [kv_len, layer, q[:, 0], k_cache, v_cache]
-    if quantized:
+    if tiling is not None:
+        chunk_s, block_rows = tiling
         kernel = functools.partial(
-            _decode_kernel_quant, block_s=block_s, kv_heads=kv, n_rep=n_rep)
-        in_specs += [pl.BlockSpec(memory_space=pl.ANY),
-                     pl.BlockSpec(memory_space=pl.ANY)]
-        scratch += [pltpu.VMEM((2, kv, block_s), k_scale.dtype),
-                    pltpu.VMEM((2, kv, block_s), v_scale.dtype)]
-        sems += [pltpu.SemaphoreType.DMA((2,)), pltpu.SemaphoreType.DMA((2,))]
-        args += [k_scale, v_scale]
+            _dense_kernel, page_s=chunk_s, kv_heads=kv, n_rep=n_rep)
+        scratch = [pltpu.VMEM((2, block_rows, d), k_cache.dtype),
+                   pltpu.VMEM((2, block_rows, d), v_cache.dtype),
+                   pltpu.VMEM((h, block_rows), jnp.float32)]
+        # [S_max, KV, D] -> [S_max * KV, D]: the same bytes, so that a
+        # chunk lands in the buffer as rows of one matrix
+        args = [jnp.clip(kv_len, 1, s_max), layer, q[:, 0],
+                k_cache.reshape(n_layers, b, s_max * kv, d),
+                v_cache.reshape(n_layers, b, s_max * kv, d)]
     else:
-        kernel = functools.partial(
-            _decode_kernel, block_s=block_s, kv_heads=kv, n_rep=n_rep)
+        block_s = min(block_s, s_max)
+        if s_max % block_s:
+            raise ValueError(f"S_max {s_max} must divide block_s {block_s}")
+        buf_shape = (2, block_s, kv * d) if quantized else (2, block_s, kv, d)
+        scratch = [
+            pltpu.VMEM(buf_shape, k_cache.dtype),
+            pltpu.VMEM(buf_shape, v_cache.dtype),
+        ]
+        args = [kv_len, layer, q[:, 0], k_cache, v_cache]
+        if quantized:
+            kernel = functools.partial(
+                _decode_kernel_quant, block_s=block_s, kv_heads=kv,
+                n_rep=n_rep)
+            in_specs += [pl.BlockSpec(memory_space=pl.ANY),
+                         pl.BlockSpec(memory_space=pl.ANY)]
+            scratch += [pltpu.VMEM((2, kv, block_s), k_scale.dtype),
+                        pltpu.VMEM((2, kv, block_s), v_scale.dtype)]
+            sems += [pltpu.SemaphoreType.DMA((2,)),
+                     pltpu.SemaphoreType.DMA((2,))]
+            args += [k_scale, v_scale]
+        else:
+            kernel = functools.partial(
+                _decode_kernel, block_s=block_s, kv_heads=kv, n_rep=n_rep)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, h, d), lambda bi, kvlen, lyr: (bi, 0, 0)),
+        out_specs=row_spec,
         scratch_shapes=scratch + sems,
     )
     out = pl.pallas_call(

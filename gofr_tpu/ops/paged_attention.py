@@ -49,7 +49,7 @@ NEG_INF = -1e30
 # the vector register file, and the chip read no other size faster
 _BLOCK_ROWS = 2048
 
-__all__ = ["paged_decode_attention_tpu", "block_pages"]
+__all__ = ["paged_decode_attention_tpu", "block_pages", "walk_pages"]
 
 
 def block_pages(page_s: int, kv_heads: int, head_dim: int, itemsize: int
@@ -64,27 +64,28 @@ def block_pages(page_s: int, kv_heads: int, head_dim: int, itemsize: int
     return pages if (pages * page_rows) % 128 == 0 else None
 
 
-def _paged_kernel(kvlen_ref, layer_ref, table_ref, q_ref, k_hbm, v_hbm,
-                  o_ref, k_buf, v_buf, own_ref, k_sem, v_sem, *,
-                  page_s: int, kv_heads: int, n_rep: int, p_max: int,
-                  n_block_pages: int):
-    """One batch row: pipelined sweep of its live pages.
+def walk_pages(kvlen, page_at, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
+               own_ref, k_sem, v_sem, *, page_s: int, kv_heads: int,
+               n_rep: int):
+    """One batch row: pipelined sweep of its ``kvlen`` live positions, a
+    page at a time. ``page_at(first, j)`` indexes either plane down to the
+    ``[page_s * KV, D]`` slice that holds the row's page ``first + j``: a
+    look-up in the page table for the pool, arithmetic for a dense row
+    (``decode_attention.py``).
 
-    q_ref/o_ref: [H, D] VMEM; k_hbm/v_hbm: [L, N, page_s * KV, D] in HBM;
-    k_buf/v_buf: [2, block_rows, D] double buffers; own_ref: [H, block_rows]
-    float32, 0 where a lane's KV head is the row's own and -inf elsewhere.
+    q_ref/o_ref: [H, D] VMEM; k_buf/v_buf: [2, block_rows, D] double
+    buffers; own_ref: [H, block_rows] float32, 0 where a lane's KV head is
+    the row's own and -inf elsewhere.
     """
-    b = pl.program_id(0)
-    kvlen = kvlen_ref[b]
-    layer = layer_ref[0]
     h, d = q_ref.shape
     page_rows = page_s * kv_heads
-    block_rows = n_block_pages * page_rows
+    block_rows = k_buf.shape[1]
+    n_block_pages = block_rows // page_rows
     n_pages = pl.cdiv(kvlen, page_s)
     n_blocks = pl.cdiv(n_pages, n_block_pages)
     live_rows = kvlen * kv_heads
 
-    @pl.when(b == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         # a page that is not fetched leaves its rows of the buffer as they
         # were: masked out of the softmax, but 0 x NaN is NaN in the second
@@ -101,11 +102,11 @@ def _paged_kernel(kvlen_ref, layer_ref, table_ref, q_ref, k_hbm, v_hbm,
         live = jnp.minimum(n_block_pages, n_pages - first)
 
         def one(j, carry):
-            page = table_ref[b * p_max + first + j]
+            page = page_at(first, j)
             dst = pl.ds(pl.multiple_of(j * page_rows, page_rows), page_rows)
-            fn(pltpu.make_async_copy(k_hbm.at[layer, page],
+            fn(pltpu.make_async_copy(k_hbm.at[page],
                                      k_buf.at[slot, dst], k_sem.at[slot]))
-            fn(pltpu.make_async_copy(v_hbm.at[layer, page],
+            fn(pltpu.make_async_copy(v_hbm.at[page],
                                      v_buf.at[slot, dst], v_sem.at[slot]))
             return carry
 
@@ -150,6 +151,22 @@ def _paged_kernel(kvlen_ref, layer_ref, table_ref, q_ref, k_hbm, v_hbm,
     o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
+def _paged_kernel(kvlen_ref, layer_ref, table_ref, q_ref, k_hbm, v_hbm,
+                  o_ref, k_buf, v_buf, own_ref, k_sem, v_sem, *,
+                  p_max: int, **widths):
+    """A row's pages are where its row of the table says; k_hbm/v_hbm:
+    [L, N, page_s * KV, D] in HBM."""
+    b = pl.program_id(0)
+    kvlen = kvlen_ref[b]
+    layer = layer_ref[0]
+
+    def page_at(first, j):
+        return layer, table_ref[b * p_max + first + j]
+
+    walk_pages(kvlen, page_at, q_ref, k_hbm, v_hbm, o_ref, k_buf,
+               v_buf, own_ref, k_sem, v_sem, **widths)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention_tpu(q, k_pool, v_pool, table, kv_len, *, layer,
                                interpret: bool = False):
@@ -181,8 +198,7 @@ def paged_decode_attention_tpu(q, k_pool, v_pool, table, kv_len, *, layer,
     v_pool = v_pool.reshape(n_layers, n_pool, page_rows, d)
 
     kernel = functools.partial(
-        _paged_kernel, page_s=page_s, kv_heads=kv, n_rep=h // kv,
-        p_max=p_max, n_block_pages=n_block_pages)
+        _paged_kernel, p_max=p_max, page_s=page_s, kv_heads=kv, n_rep=h // kv)
     row_spec = pl.BlockSpec((None, h, d), lambda bi, *_: (bi, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,

@@ -185,6 +185,100 @@ def test_rope_scaling_linear_and_unsupported():
         scale_rope_freqs(freqs, {"rope_type": "yarn", "factor": 2.0})
 
 
+# ------------------------------------------------- dense decode attention
+DENSE_S = 768   # whole blocks of the per-head kernel, whole chunks of the other
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 3e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize(
+    "lens", ["short", "block_edges", "chunk_edges", "ragged", "capacity"])
+@pytest.mark.parametrize("heads,kv_heads,head_dim",
+                         [(32, 8, 128), (32, 32, 128), (16, 2, 256)],
+                         ids=["gqa32x8", "mha32", "gqa16x2-d256"])
+def test_dense_kernel_matches_einsum(heads, kv_heads, head_dim, lens, dtype,
+                                     tol):
+    """The dense layout's full-precision kernel (interpret mode) against
+    the XLA grouped einsum, the dispatcher's branch off the TPU, on a
+    stacked cache read at layer 1: Mistral's and DeepSeek's widths take the
+    one-matrix body (a row walked like pages), Qwen3-Next's 2 KV heads of
+    256 the per-head body. One token, and an idle row whose stale length
+    still counts; exactly a block, one past it and one short of it; the
+    same around a chunk, the unit the tail is fetched in; ragged rows; a
+    row at capacity, which the one-matrix body is handed one more than
+    (``pos + 1`` with ``pos`` pinned) and must clamp, never reading past
+    the row."""
+    from gofr_tpu.ops import gqa_decode_attention
+    from gofr_tpu.ops.decode_attention import (
+        gqa_decode_attention_tpu,
+        row_tiling,
+    )
+
+    tiling = row_tiling(DENSE_S, kv_heads, head_dim, jnp.dtype(dtype).itemsize)
+    assert (tiling is not None) == (kv_heads % 8 == 0)
+    if tiling is None:   # the per-head body: whole blocks of 256, no chunks,
+        block, chunk, past = 256, 64, 0    # and its callers do the clamping
+    else:
+        chunk, block, past = tiling[0], tiling[1] // kv_heads, 1
+    assert block + 1 <= DENSE_S
+    handed = {
+        "short": [1, 17, 500],
+        "block_edges": [block, block + 1, block - 1],
+        "chunk_edges": [chunk + 1, chunk - 1, 3 * chunk],
+        "ragged": [5, 300, 77, DENSE_S - 3],
+        "capacity": [DENSE_S + past, DENSE_S, 3],
+    }[lens]
+    b = len(handed)
+    keys = jax.random.split(jax.random.PRNGKey(heads + kv_heads), 3)
+    q = jax.random.normal(keys[0], (b, 1, heads, head_dim), dtype)
+    k_cache, v_cache = (
+        jax.random.normal(kk, (2, b, DENSE_S, kv_heads, head_dim), dtype)
+        for kk in keys[1:])
+    attended = jnp.asarray(np.minimum(handed, DENSE_S), jnp.int32)
+    want = gqa_decode_attention(q, k_cache[1], v_cache[1], kv_len=attended)
+    got = gqa_decode_attention_tpu(
+        q, k_cache, v_cache, jnp.asarray(handed, jnp.int32), layer=1,
+        interpret=True)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("s_max,kv_heads,head_dim,dtype,chunk", [
+    (2048, 8, 128, jnp.bfloat16, 16),     # mistral7b-batch
+    (2048, 32, 128, jnp.bfloat16, 4),     # DeepSeek on the dense layout
+    (4096, 2, 256, jnp.bfloat16, None),   # qwen3next: heads packed in a tile
+    (2048, 8, 64, jnp.bfloat16, None),    # toy head size: lanes not whole
+    (2056, 8, 128, jnp.bfloat16, None),   # a row that is not whole chunks
+    (768, 8, 128, jnp.float32, 16),
+])
+def test_dense_row_tiling(s_max, kv_heads, head_dim, dtype, chunk):
+    """Which full-precision caches the one-matrix body takes, and that a
+    block of what it takes is whole chunks, whole lane tiles and small
+    beside the scoped VMEM (two planes, double-buffered)."""
+    from gofr_tpu.ops.decode_attention import row_tiling
+
+    itemsize = jnp.dtype(dtype).itemsize
+    tiling = row_tiling(s_max, kv_heads, head_dim, itemsize)
+    assert (tiling and tiling[0]) == chunk
+    if tiling is not None:
+        block_rows = tiling[1]
+        assert block_rows % (chunk * kv_heads) == 0 and block_rows % 128 == 0
+        assert 4 * block_rows * head_dim * itemsize <= 4 * 2**20
+
+
+def test_dense_dispatcher_records_einsum_off_tpu():
+    from gofr_tpu import ops
+
+    q = jnp.ones((2, 1, 32, 128), jnp.float32)
+    cache = jnp.ones((1, 2, 256, 8, 128), jnp.float32)
+    ops.cached_decode_attention(q, cache, cache, jnp.asarray([40, 3]),
+                                layer=0)
+    key = ops.branch_key("decode_attention", q, cache)
+    assert ops.kernel_branches()[key] == "xla"
+
+
 # ------------------------------------------------- paged decode attention
 PAGE_S, P_MAX, HEAD_D = 16, 40, 128   # a row's table holds 640 positions
 

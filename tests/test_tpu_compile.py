@@ -80,6 +80,42 @@ def test_decode_kernel_compiles(chip, layout):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("rows,s_max,heads,kv_heads,head_dim", [
+    (32, 2048, 32, 8, 128),    # mistral7b-batch
+    (128, 4096, 16, 2, 256),   # qwen3next-longanswer's attention layers
+    (8, 2048, 32, 32, 128),    # DeepSeek's widths on the dense layout
+], ids=["mistral-32x2048", "qwen3next-128x4096", "deepseek-8x2048"])
+def test_dense_kernel_compiles_at_cell_widths(chip, rows, s_max, heads,
+                                              kv_heads, head_dim):
+    """The full-precision dense kernel at the cells' own widths, the cache
+    stacked and the layer traced: it compiles, its block fits VMEM (the
+    compiler refuses one that does not), and NO copy of the cache is made
+    on the way in. The one-matrix body sees ``[S, KV, D]`` as
+    ``[S * KV, D]``, which is the same bytes only where the KV heads are
+    whole sublane tiles: at 2 KV heads XLA answered that reshape with a
+    relayout of both planes (2 GB of temporaries at the hybrid cell's
+    size), which is why such a cache keeps the per-head body."""
+    from gofr_tpu.ops.decode_attention import (
+        gqa_decode_attention_tpu,
+        row_tiling,
+    )
+
+    tiling = row_tiling(s_max, kv_heads, head_dim, 2)
+    assert (tiling is not None) == (kv_heads % 8 == 0)
+    if tiling is not None:
+        assert 4 * tiling[1] * head_dim * 2 <= 4 * 2**20
+    kv = _shape(chip, jnp.bfloat16, 2, rows, s_max, kv_heads, head_dim)
+    compiled = jax.jit(
+        lambda q, k, v, kv_len, layer: gqa_decode_attention_tpu(
+            q, k, v, kv_len, layer=layer)
+    ).lower(_shape(chip, jnp.bfloat16, rows, 1, heads, head_dim), kv, kv,
+            _shape(chip, jnp.int32, rows), _shape(chip, jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "gqa_decode_attention_tpu" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
 def test_flash_kernel_compiles(chip):
     from gofr_tpu.ops.flash_attention import flash_attention_tpu
 
