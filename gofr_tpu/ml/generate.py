@@ -297,10 +297,12 @@ def _model_of(cfg):
     place the serving loop chooses between families. The dense slot
     layout takes ``init_cache``, ``prefill_into`` and ``decode_step`` from
     it; every other layout is the llama block's."""
-    from ..models import llama, qwen3_next
+    from ..models import jamba, llama, qwen3_next
 
     if isinstance(cfg, qwen3_next.Qwen3NextConfig):
         return qwen3_next
+    if isinstance(cfg, jamba.JambaConfig):
+        return jamba
     return llama
 
 
@@ -652,6 +654,10 @@ class Generator:
         # decide/launch/device_wait/emit phases (``with phase(rec, …)``:
         # the shared no-op context while it is None)
         self.recorder = None
+        # called once a processed dispatch, after the last of its
+        # callbacks (``_fire_bursts``): the serving layer hands every
+        # stream's burst to its consumers' loop there, in one wakeup
+        self.after_bursts = None
         # goodput ledger handle (ml/goodput.py): the serving layer installs
         # a model-bound ModelGoodput here so the spec verify path and the
         # restore-fallback path can classify device tokens; same
@@ -672,6 +678,11 @@ class Generator:
         # whole-prompt prefill programs: their ratio is the pad share
         self.prefill_tokens_real = 0
         self.prefill_tokens_padded = 0
+        # a family with per-slot state that has no length axis sweeps every
+        # slot's row of it each decode step, whoever holds the slot: rows
+        # read and written, and those of them that decoded a request
+        self.state_rows_swept = 0
+        self.state_rows_live = 0
 
         sampler_cfg = self.sampler
         host_visible = self._host_visible
@@ -1483,15 +1494,19 @@ class Generator:
                 kv_restore_fallbacks=self.kv_restore_fallbacks,
                 prefix_prefills=self.prefix_prefills,
             )
-        if "moe_counts" in self.cache:
-            # a family with recurrent state and routed experts: how the
-            # slots' memory divides, and what routing did so far
+        if "state" in self.cache:
+            # a family with per-slot state that has no length axis
+            # (models/slot_state.py): how the slots' memory divides, and
+            # what the state's sweep was spent on
             cache = dict(self.cache)
             out.update(
                 recurrent_state_bytes=int(cache["state"].nbytes
                                           + cache["conv"].nbytes),
                 kv_cache_bytes=int(cache["k"].nbytes + cache["v"].nbytes),
-                **self._expert_counts())
+                state_rows_swept=self.state_rows_swept,
+                state_rows_live=self.state_rows_live)
+        if "moe_counts" in self.cache:
+            out.update(self._expert_counts())  # what routing did so far
         return out
 
     def _keep_counts(self) -> None:
@@ -2971,8 +2986,12 @@ class Generator:
                     table = self._table_device()
             # the record and the launch annotation say what runs: the
             # program's kind, its decode steps, the rows producing tokens
+            rows = self._n_decodable()
+            if "state" in self.cache:
+                self.state_rows_swept += n_steps * self.batch_slots
+                self.state_rows_live += n_steps * rows
             with phase(rec, "launch", kind="mini" if mini else kind,
-                       steps=n_steps, rows=self._n_decodable()):
+                       steps=n_steps, rows=rows):
                 if kind == "specwin":
                     (row0, emits, counts, realized, self._tok_dev,
                      self.cache, self._tokens_dev, self._draft_cache) = fn(
@@ -3415,13 +3434,17 @@ class Generator:
         """Deliver each slot's token burst to its callback — the emit
         phase of the dispatch breakdown (in the serving stack every call
         is a ``call_soon_threadsafe`` wakeup of the consumer's loop)."""
-        if not bursts:
+        if not bursts and self.after_bursts is None:
             return
         with phase(self.recorder, "emit"):
             for i, burst in bursts.items():
                 cb = self.slots[i].callback
                 if cb is not None:
                     cb(i, burst)
+            if self.after_bursts is not None:
+                # also where ``bursts`` is empty: a first token's callback
+                # (``_resolve_first``) may have run before
+                self.after_bursts()
 
     def release(self, i: int) -> None:
         """Return a finished slot to the free pool (its tokens are consumed)."""

@@ -9,9 +9,10 @@ from one thread.
 
 Flow per request: handler awaits ``stream()``/``generate()`` → request goes
 on a thread-safe queue → the serving thread admits it into a free slot
-(prefill) or parks it until one frees → each sampled token is pushed back
-to the handler's asyncio queue via ``call_soon_threadsafe`` → slot release
-on completion. Metrics: queue wait, TTFT, tokens out.
+(prefill) or parks it until one frees → each burst of sampled tokens is
+pushed back to the handler's asyncio queue via ``call_soon_threadsafe``,
+one wakeup a dispatch for all streams (``_post``) → slot release on
+completion. Metrics: queue wait, TTFT, tokens out.
 
 Paged generators additionally get the framework shared-prefix cache
 (prefix_cache.py): admission longest-matches each prompt against a radix
@@ -94,6 +95,13 @@ def drain_s_from_env() -> float:
         raise ValueError(
             f"GOFR_ML_DRAIN_S must be finite and >= 0, got {raw!r}")
     return drain_s
+
+
+def _deliver(items: list) -> None:
+    """On a consumer's loop: what one pass of the serving thread had for
+    its streams, each into its own queue, in the order it was posted."""
+    for out_q, item in items:
+        out_q.put_nowait(item)
 
 
 class _Finish:
@@ -274,6 +282,10 @@ class LLMServer:
         self.recorder = (DispatchRecorder(model=name, metrics=metrics)
                          if recorder_enabled() else None)
         generator.recorder = self.recorder
+        # what the serving thread has for its consumers, by loop, until
+        # ``_flush_posts`` hands it over: see ``_post``
+        self._outbox: dict = {}
+        generator.after_bursts = self._flush_posts
         # anomaly-triggered auto-profiler (flight_recorder.py): observes
         # every committed dispatch record through recorder.observer and
         # captures a bounded jax.profiler trace when step time or a phase
@@ -757,11 +769,9 @@ class LLMServer:
                 # reroute this request to a survivor, and the journey
                 # must keep recording — the reject is just one mark
                 req.journey.mark("reject", reason=reason or "error")
-        try:
-            req.loop.call_soon_threadsafe(req.out_q.put_nowait, exc)
-            req.loop.call_soon_threadsafe(req.out_q.put_nowait, _DONE)
-        except Exception:
-            pass  # consumer loop itself already gone
+        self._post(req, exc)
+        self._post(req, _DONE)
+        self._flush_posts()
 
     # -- watchdog / crash recovery --------------------------------------------
     def _recover_or_die(self, exc: BaseException) -> bool:
@@ -1346,7 +1356,29 @@ class LLMServer:
                     "app_llm_tokens_total", len(tokens), model=self.name)
             except Exception:
                 pass
-        req.loop.call_soon_threadsafe(req.out_q.put_nowait, list(tokens))
+        self._post(req, list(tokens))
+
+    def _post(self, req: _Request, item) -> None:
+        """Queue ``item`` (a burst of tokens, a finish marker, an error)
+        for ``req``'s consumer. ``_flush_posts`` wakes each consumer loop
+        ONCE for all that was posted since: after a dispatch's last burst,
+        after the finished streams' markers, after a rejection. At 128
+        streams a wakeup a stream was 128 writes to the loop's wake-up
+        socket a dispatch, each of which gives up the interpreter's lock
+        to the loop's thread; after a wave's one-step program the device
+        stands idle until they are through and the next program is
+        launched."""
+        self._outbox.setdefault(req.loop, []).append((req.out_q, item))
+
+    def _flush_posts(self) -> None:
+        if not self._outbox:
+            return
+        outbox, self._outbox = self._outbox, {}
+        for loop, items in outbox.items():
+            try:
+                loop.call_soon_threadsafe(_deliver, items)
+            except RuntimeError:
+                pass  # consumer loop itself already gone
 
     def _expire(self, req: _Request, where: str) -> None:
         """One request past its deadline: typed 504 to the consumer plus
@@ -1557,8 +1589,8 @@ class LLMServer:
                 self.gen.release(slot)
                 del self._active[slot]
                 self.served += 1
-                req.loop.call_soon_threadsafe(req.out_q.put_nowait,
-                                              _Finish(reason))
+                self._post(req, _Finish(reason))
+        self._flush_posts()
 
     def check_admissible(self, prompt_ids, max_new_tokens: int = 1,
                          prefix: int | None = None) -> None:

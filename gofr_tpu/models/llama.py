@@ -451,7 +451,8 @@ _ACT_SPEC = P("dp", "sp", None)
 def _block(cfg: LlamaConfig, x, lp, cos, sin, attend, *, qk_spec=None,
            out_spec=None):
     """THE decoder block, for any ``x`` [b, s, D]: norm → q/k/v → rope →
-    ``attend`` → ``wo`` → residual → SwiGLU.
+    ``attend`` → ``wo`` → residual → SwiGLU. ``cos`` None leaves the rope
+    out (a family whose attention carries no positional term).
 
     ``attend(q, k, v) -> (o, kept)`` is the only thing a program supplies:
     it writes the roped K and V where its layout keeps them (nowhere, a
@@ -470,8 +471,9 @@ def _block(cfg: LlamaConfig, x, lp, cos, sin, attend, *, qk_spec=None,
         v = _mm(h, lp["wv"]).reshape(b, s, KV, hd)
         q = pin(q, qk_spec)
         k = pin(k, qk_spec)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        if cos is not None:
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
         o, kept = attend(q, k, v)
         x = x + pin(_mm(o.reshape(b, s, H * hd), lp["wo"]), out_spec)
     with jax.named_scope("mlp"):

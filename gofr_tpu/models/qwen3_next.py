@@ -56,6 +56,7 @@ from ..ops import (
     rope_table,
 )
 from . import moe
+from .slot_state import UNSUPPORTED  # what ``Generator`` refuses
 
 __all__ = ["Qwen3NextConfig", "init_params", "init_cache", "prefill_into",
            "decode_step", "gated_delta_chunked", "gated_delta_step",
@@ -65,24 +66,6 @@ CHUNK = 64  # DeltaNet's prefill chunk, as published
 
 # rows of the cache's ``moe_counts``
 MOE_COUNTERS = ("expert_pairs_routed", "expert_pairs_held", "experts_touched")
-
-# what ``Generator`` refuses for this family, each with what it would take
-UNSUPPORTED = {
-    "page_size": "the recurrent state has no pages: the paged layout, the "
-                 "prefix cache, kv_offload and kv_transport need snapshots "
-                 "of it at page boundaries",
-    "sp": "sequence-parallel prefill would have to hand the recurrent "
-          "state from shard to shard",
-    "spec_k": "a rejected draft token has already changed the recurrent "
-              "state; speculation needs a checkpoint of it per window",
-    "kv_bits": "the attention layers' cache is served in the model's dtype "
-               "only (no int8/int4 planes)",
-    "prefill_chunk": "a prompt's segments would have to carry the "
-                     "recurrent state from one to the next",
-    "mesh": "the family has no sharding rules (shard_cache and "
-            "tensor-parallel replicas need them, and the expert exchange)",
-}
-
 
 class Qwen3NextConfig:
     """Sizes under their published (HF ``config.json``) names. ``held`` is

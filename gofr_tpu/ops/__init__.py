@@ -303,7 +303,8 @@ def gqa_decode_attention(
 
 def cached_decode_attention(q, k_cache, v_cache, kv_len, *, layer=None,
                             use_kernel: bool = True,
-                            k_scale=None, v_scale=None):
+                            k_scale=None, v_scale=None,
+                            flat_kv_heads: int | None = None):
     """Decode-attention dispatcher: a Pallas length-skipping kernel on TPU
     when shapes allow, the XLA grouped einsum everywhere else.
 
@@ -320,7 +321,17 @@ def cached_decode_attention(q, k_cache, v_cache, kv_len, *, layer=None,
     which pass ``k_scale``/``v_scale`` ([L, B, KV, S] or [B, KV, S]; seq
     minor for DMA alignment; dequantized in VMEM after the halved HBM
     read).
+
+    ``flat_kv_heads``: a full-precision stacked cache stored as
+    ``[L, B, S * KV, D]`` (row ``t * KV + g`` is token ``t``, KV head
+    ``g``), the form in which fewer KV heads than a sublane tile holds
+    cost their own bytes on the chip (``[S, 1, 128]`` bfloat16 pads its
+    last two axes to a whole tile, 16 times the bytes). It is read as one
+    matrix a block whatever the number of KV heads.
     """
+    if flat_kv_heads is not None:
+        return _flat_decode_attention(q, k_cache, v_cache, kv_len, layer,
+                                      use_kernel, flat_kv_heads)
     quantized = k_scale is not None
     # quantized caches are FLAT [L?, B, S, KV*D] (int8 tiling, see
     # models/llama.init_cache); fp caches are [L?, B, S, KV, D]
@@ -357,6 +368,25 @@ def cached_decode_attention(q, k_cache, v_cache, kv_len, *, layer=None,
         v_cache = dequantize_kv(unflat(v_cache),
                                 v_scale.transpose(0, 2, 1), q.dtype)
     return gqa_decode_attention(q, k_cache, v_cache, kv_len=kv_len)
+
+
+def _flat_decode_attention(q, k_cache, v_cache, kv_len, layer, use_kernel,
+                           kv: int):
+    """``cached_decode_attention`` for a cache stored flat (see there)."""
+    n_layers, b, rows, d = k_cache.shape
+    kernel = use_kernel and _on_tpu() and q.shape[1] == 1
+    if kernel:
+        from .decode_attention import gqa_decode_attention_tpu, row_tiling
+
+        kernel = row_tiling(rows // kv, kv, d, k_cache.dtype.itemsize,
+                            flat=True) is not None
+    record_branch("decode_attention", kernel, q, k_cache)
+    if kernel:
+        return gqa_decode_attention_tpu(q, k_cache, v_cache, kv_len,
+                                        layer=layer, flat_kv_heads=kv)
+    idx = lambda a: jax.lax.dynamic_index_in_dim(
+        a, layer, 0, keepdims=False).reshape(b, rows // kv, kv, d)
+    return gqa_decode_attention(q, idx(k_cache), idx(v_cache), kv_len=kv_len)
 
 
 def paged_decode_attention(q, k_pool, v_pool, table, kv_len, *, layer):

@@ -68,16 +68,17 @@ _CHUNK_ROWS = 128
 __all__ = ["gqa_decode_attention_tpu", "row_tiling"]
 
 
-def row_tiling(s_max: int, kv_heads: int, head_dim: int, itemsize: int
-               ) -> tuple[int, int] | None:
+def row_tiling(s_max: int, kv_heads: int, head_dim: int, itemsize: int,
+               flat: bool = False) -> tuple[int, int] | None:
     """(tokens a chunk, rows a block) for a full-precision cache that
     ``_dense_kernel`` takes, or None where it does not: the KV heads must
-    be whole sublane tiles (else the flat view is a copy), a row whole
-    chunks, and a chunk must do as a page of ``paged_attention``'s walk,
-    whose block of pages is then the block."""
+    be whole sublane tiles (else the flat view is a copy) unless the cache
+    is stored ``flat`` already, a row whole chunks, and a chunk must do as
+    a page of ``paged_attention``'s walk, whose block of pages is then the
+    block."""
     chunk_s = max(1, _CHUNK_ROWS // kv_heads)
     chunks = block_pages(chunk_s, kv_heads, head_dim, itemsize)
-    if kv_heads % 8 or s_max % chunk_s or chunks is None:
+    if ((kv_heads % 8 and not flat) or s_max % chunk_s or chunks is None):
         return None
     return chunk_s, chunks * chunk_s * kv_heads
 
@@ -218,9 +219,11 @@ def _decode_kernel_quant(kvlen_ref, layer_ref, q_ref, k_hbm, v_hbm, ks_hbm,
                    vs_buf=vs_buf, ks_sem=ks_sem, vs_sem=vs_sem)
 
 
-@functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("block_s", "interpret", "flat_kv_heads"))
 def gqa_decode_attention_tpu(q, k_cache, v_cache, kv_len, *, layer=None,
                              k_scale=None, v_scale=None,
+                             flat_kv_heads: int | None = None,
                              block_s: int = 256, interpret: bool = False):
     """q: [B, 1, H, D]; caches: [B, S_max, KV, D] per-layer, or the full
     stacked [L, B, S_max, KV, D] with ``layer`` the (traced) index to read;
@@ -234,10 +237,17 @@ def gqa_decode_attention_tpu(q, k_cache, v_cache, kv_len, *, layer=None,
     is read a product a KV head in blocks of ``block_s`` positions, which
     S_max must divide by (serving caches are power-of-two sized; callers
     fall back to the XLA path otherwise) and ``kv_len`` must not pass.
+
+    ``flat_kv_heads``: the full-precision cache is stored as the matrix the
+    first body reads, ``[L, B, S_max * KV, D]`` (row ``t * KV + g`` is
+    token ``t``, KV head ``g``), so fewer KV heads than a sublane tile
+    holds cost their own bytes in HBM and take that body too (its query
+    heads padded to a multiple of 8 where they are none).
     """
     b, tq, h, d = q.shape
     quantized = k_scale is not None
-    per_layer_ndim = 3 if quantized else 4  # quantized caches are FLAT
+    flat = flat_kv_heads is not None
+    per_layer_ndim = 3 if quantized or flat else 4  # both kinds lie FLAT
     if k_cache.ndim == per_layer_ndim:
         k_cache, v_cache = k_cache[None], v_cache[None]
         if quantized:
@@ -245,17 +255,32 @@ def gqa_decode_attention_tpu(q, k_cache, v_cache, kv_len, *, layer=None,
         layer = 0
     if layer is None:
         raise ValueError("stacked caches require a layer index")
-    n_layers, _, s_max = k_cache.shape[:3]
-    kv = k_scale.shape[2] if quantized else k_cache.shape[3]
+    n_layers = k_cache.shape[0]
+    if flat:
+        kv = flat_kv_heads
+        s_max = k_cache.shape[2] // kv
+    else:
+        s_max = k_cache.shape[2]
+        kv = k_scale.shape[2] if quantized else k_cache.shape[3]
     if tq != 1:
         raise ValueError(f"decode kernel takes one query token, got Tq={tq}")
     n_rep = h // kv
     kv_len = jnp.asarray(kv_len, jnp.int32)
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
     tiling = None if quantized else row_tiling(
-        s_max, kv, d, k_cache.dtype.itemsize)
+        s_max, kv, d, k_cache.dtype.itemsize, flat=flat)
+    if flat and tiling is None:
+        raise ValueError(f"no tiling for a flat cache of S_max={s_max}, "
+                         f"KV={kv}, D={d}, {k_cache.dtype}")
 
-    row_spec = pl.BlockSpec((None, h, d), lambda bi, kvlen, lyr: (bi, 0, 0))
+    # the one-matrix body wants the query heads in whole sublane tiles (20
+    # heads on one KV head are not): a padded row has no KV head of its
+    # own, so the own-head mask hides every lane from it, and it is cut off
+    hp = h + (-h % 8 if tiling is not None else 0)
+    q_rows = q[:, 0]
+    if hp != h:
+        q_rows = jnp.pad(q_rows, ((0, 0), (0, hp - h), (0, 0)))
+    row_spec = pl.BlockSpec((None, hp, d), lambda bi, kvlen, lyr: (bi, 0, 0))
     in_specs = [
         row_spec,
         pl.BlockSpec(memory_space=pl.ANY),  # k cache stays in HBM
@@ -268,10 +293,11 @@ def gqa_decode_attention_tpu(q, k_cache, v_cache, kv_len, *, layer=None,
             _dense_kernel, page_s=chunk_s, kv_heads=kv, n_rep=n_rep)
         scratch = [pltpu.VMEM((2, block_rows, d), k_cache.dtype),
                    pltpu.VMEM((2, block_rows, d), v_cache.dtype),
-                   pltpu.VMEM((h, block_rows), jnp.float32)]
-        # [S_max, KV, D] -> [S_max * KV, D]: the same bytes, so that a
-        # chunk lands in the buffer as rows of one matrix
-        args = [jnp.clip(kv_len, 1, s_max), layer, q[:, 0],
+                   pltpu.VMEM((hp, block_rows), jnp.float32)]
+        # [S_max, KV, D] -> [S_max * KV, D]: the same bytes (a cache stored
+        # flat is that already), so that a chunk lands in the buffer as
+        # rows of one matrix
+        args = [jnp.clip(kv_len, 1, s_max), layer, q_rows,
                 k_cache.reshape(n_layers, b, s_max * kv, d),
                 v_cache.reshape(n_layers, b, s_max * kv, d)]
     else:
@@ -283,7 +309,7 @@ def gqa_decode_attention_tpu(q, k_cache, v_cache, kv_len, *, layer=None,
             pltpu.VMEM(buf_shape, k_cache.dtype),
             pltpu.VMEM(buf_shape, v_cache.dtype),
         ]
-        args = [kv_len, layer, q[:, 0], k_cache, v_cache]
+        args = [kv_len, layer, q_rows, k_cache, v_cache]
         if quantized:
             kernel = functools.partial(
                 _decode_kernel_quant, block_s=block_s, kv_heads=kv,
@@ -309,7 +335,7 @@ def gqa_decode_attention_tpu(q, k_cache, v_cache, kv_len, *, layer=None,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hp, d), q.dtype),
         interpret=interpret,
     )(*args)
-    return out[:, None]
+    return out[:, None, :h] if hp != h else out[:, None]

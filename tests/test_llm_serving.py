@@ -172,6 +172,47 @@ def test_answer_to_a_stream_end_is_seen_at_the_next_settle(model, run):
     assert seen["admitted_at"] > seen["sent_at"]
 
 
+def test_a_dispatch_wakes_the_consumers_loop_once(model, run):
+    """What one processed dispatch has for its streams (every stream's
+    burst) reaches the consumers' loop in ONE wakeup, and so do the finish
+    markers of the streams that ended: a wakeup a stream was a write to
+    the loop's wake-up socket a stream, each giving up the interpreter's
+    lock with the device waiting for the next launch. Every stream still
+    gets its own tokens, in order, and its marker last."""
+    cfg, params = model
+    prompts = [[i + 1, i + 2] for i in range(4)]
+    expects = [_expected(params, cfg, p, 9) for p in prompts]
+
+    async def scenario():
+        gen = Generator(params, cfg, batch_slots=4, max_seq=64,
+                        prefill_buckets=(8,), chunk=4)
+        server = LLMServer(gen, idle_wait_s=1.0, admit_window_s=0.25)
+        loop = asyncio.get_running_loop()
+        wakeups, wake = [], loop.call_soon_threadsafe
+
+        def counted(fn, *args):
+            if fn.__name__ == "_deliver":
+                wakeups.append(len(args[0]))
+            return wake(fn, *args)
+
+        loop.call_soon_threadsafe = counted
+        try:
+            got = await asyncio.gather(*(server.generate(p, 9)
+                                         for p in prompts))
+            return got, wakeups, gen.settled
+        finally:
+            loop.call_soon_threadsafe = wake
+            server.close()
+
+    got, wakeups, settled = run(scenario())
+    assert got == expects
+    # 4 streams x (first token + 8 more in chunks of 1, 4 and 4) + 4 markers
+    assert sum(wakeups) == 4 * 4 + 4
+    # a wakeup a settled dispatch and one for the markers, not one a stream
+    assert len(wakeups) <= settled + 1
+    assert max(wakeups) >= 4
+
+
 def test_idle_burst_is_collected_until_it_is_over(model, run):
     """Requests that reach an idle server one after another, each within
     the admit window of the last but over a longer span than one window,
