@@ -21,7 +21,9 @@ full width, top-k and the renormalisation run over every expert, and only
 the held experts' terms are computed and added (one chip's share of an
 expert-parallel layer, without the exchange). Tokens are sorted by expert
 and each projection is one grouped product over the held experts
-(``jax.lax.ragged_dot``, a native grouped matmul on the TPU).
+(``ops.grouped_matmul``: on a TPU a Pallas kernel that streams each touched
+expert's weights once, ``ops/grouped_matmul.py``; ``jax.lax.ragged_dot``
+everywhere else).
 """
 
 from __future__ import annotations
@@ -31,6 +33,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from ..ops import grouped_matmul
 from ..parallel import P, constrain
 
 __all__ = ["MoEConfig", "init_moe_params", "moe_layer", "MOE_SHARDING_RULES",
@@ -155,9 +158,11 @@ def dropless_experts(x: jnp.ndarray, weights: jnp.ndarray, idx: jnp.ndarray,
     [held, F, D]; ``held`` = (first, count) of the router's experts whose
     weights these are; ``valid`` [N] leaves padding out. With ``layer`` (a
     traced index) the weights are a whole stack's, [layers * held, ...]:
-    the grouped product takes the stack as it lies and finds every other
-    layer's groups empty, where a slice of one layer would first be copied
-    out (the product is a kernel; a slice does not fuse into it). Returns
+    the grouped product takes the stack as it lies and names the layer's
+    experts by their rows in it (``first = layer * held``), where a slice
+    of one layer would first be copied out: the product is a custom call,
+    on the chip the Pallas kernel, and a slice does not fuse into one (a
+    layer's 0.8 GB a call at the benchmark's size). Returns
     ``y`` [N, D] and int32 counts (pairs routed, pairs that fell on held
     experts, held experts with at least one token)."""
     n, k = idx.shape
@@ -171,15 +176,12 @@ def dropless_experts(x: jnp.ndarray, weights: jnp.ndarray, idx: jnp.ndarray,
     order = jnp.argsort(key, stable=True)
     sizes = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0,
                     dtype=jnp.int32)
-    groups = sizes
-    if layer is not None:
-        groups = jax.lax.dynamic_update_slice(
-            jnp.zeros((w_gate_up.shape[0],), jnp.int32), sizes,
-            (layer * count,))
+    first = None if layer is None else layer * count
     xs = x[order // k]
-    h = jax.lax.ragged_dot(xs, w_gate_up, groups)
+    h = grouped_matmul(xs, w_gate_up, sizes, first=first)
     f = w_down.shape[1]
-    y = jax.lax.ragged_dot(jax.nn.silu(h[:, :f]) * h[:, f:], w_down, groups)
+    y = grouped_matmul(jax.nn.silu(h[:, :f]) * h[:, f:], w_down, sizes,
+                       first=first)
     # rows behind the last group belong to no expert: whatever the grouped
     # product left there is not a term of the sum
     n_mine = jnp.sum(sizes)

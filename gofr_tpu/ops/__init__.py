@@ -20,6 +20,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
+# (loaded before the function of the same name is defined: a submodule
+# loaded later would take the package's name ``grouped_matmul`` from it)
+from . import grouped_matmul as _grouped
+
 __all__ = [
     "rms_norm",
     "layer_norm",
@@ -32,6 +36,7 @@ __all__ = [
     "gqa_decode_attention",
     "cached_decode_attention",
     "paged_decode_attention",
+    "grouped_matmul",
     "quantize_kv",
     "dequantize_kv",
     "quantize_kv4",
@@ -418,6 +423,32 @@ def paged_decode_attention(q, k_pool, v_pool, table, kv_len, *, layer):
     v_virt = jnp.take(v_l, table, axis=0).reshape(b, -1, kv, d)
     return attention(q, repeat_kv(k_virt, n_rep), repeat_kv(v_virt, n_rep),
                      causal=False, kv_len=kv_len)
+
+
+def grouped_matmul(xs, w, sizes, *, first=None):
+    """The grouped product of a dropless expert layer: ``xs`` [M, K] with
+    its rows sorted by group, group ``g`` (``sizes[g]`` rows, [G] int32)
+    against ``w[first + g]`` of the stack ``w`` [E, K, N]; ``first`` is a
+    traced index (a layer's offset into every layer's experts) or None
+    for 0. Operands in their dtype, float32 accumulation, the result
+    [M, N] in ``xs.dtype``; rows behind the last group hold nothing a
+    caller may use.
+
+    On a TPU, where the shapes have a tiling, the Pallas kernel streams
+    each touched group's weights once (``ops/grouped_matmul.py``; its row
+    tile follows from M); everywhere else ``jax.lax.ragged_dot``
+    over the whole stack, which finds the other groups empty.
+    """
+    kernel = (_on_tpu() and xs.dtype == w.dtype
+              and _grouped.row_tile(xs.shape[0]) is not None
+              and _grouped.col_tile(*w.shape[1:], w.dtype.itemsize) is not None)
+    record_branch("grouped_matmul", kernel, xs, w)
+    if kernel:
+        return _grouped.grouped_matmul_tpu(xs, w, sizes, first)
+    if first is not None:
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((w.shape[0],), jnp.int32), sizes, (first,))
+    return jax.lax.ragged_dot(xs, w, sizes)
 
 
 def swiglu(x: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray,

@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from benchmark import reference_qwen3_next as reference
+from gofr_tpu import ops
 from gofr_tpu.ml import MLDatasource
 from gofr_tpu.ml.generate import Generator, _prefill_ladder
 from gofr_tpu.models import llama, moe
@@ -361,3 +362,25 @@ def test_decode_hands_the_kernels_a_length_inside_the_cache(family, model,
     for handed in seen:
         np.testing.assert_array_equal(handed, [S_max, S_max, 5])
     np.testing.assert_array_equal(np.asarray(new["len"]), [S_max, S_max, 5])
+
+
+def test_a_prompt_and_a_step_record_the_grouped_products_branch(model,
+                                                                programs):
+    """Both expert products of a prefill and of a decode step go through
+    ``ops.grouped_matmul`` over the whole stack of every layer's held
+    experts, and the dispatch record says which branch they took: off the
+    chip ``jax.lax.ragged_dot``."""
+    cfg, params = model
+    ids = np.random.default_rng(3).integers(1, 128, 12).tolist()
+    _serve(programs, params, qn.init_cache(cfg, 3, MAX_SEQ), ids, 10,
+           slot=0, steps=1)
+    k, (_, held) = cfg.num_experts_per_tok, cfg.held
+    stack, d, f = 8 * held, cfg.hidden_size, cfg.moe_intermediate_size
+    took = ops.kernel_branches()
+    for rows in (3 * k, 128 * k):  # a decode step's pairs, a prompt's
+        for xs, w in (((rows, d), (stack, d, 2 * f)),
+                      ((rows, f), (stack, f, d))):
+            key = ops.branch_key(
+                "grouped_matmul", jax.ShapeDtypeStruct(xs, jnp.float32),
+                jax.ShapeDtypeStruct(w, jnp.float32))
+            assert took[key] == "xla", (key, sorted(took))

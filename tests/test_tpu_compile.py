@@ -16,7 +16,7 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
 from gofr_tpu import ops
-from gofr_tpu.models import llama
+from gofr_tpu.models import llama, qwen3_next
 
 # The kernel modules are imported inside the tests: importing the submodule
 # ``ops.decode_attention`` rebinds the package attribute of that name from
@@ -146,6 +146,18 @@ def test_decode_step_compiles_with_kernel(chip, monkeypatch):
             < 16 * 2**30)
 
 
+def _hybrid(chip):
+    """Qwen3-Next at the published widths, two periods, 16 held experts a
+    layer: the configuration, and its parameters' and cache's shapes."""
+    cfg = qwen3_next.Qwen3NextConfig(vocab_size=4096, num_hidden_layers=8,
+                                     held=(0, 16))
+    params = _on_chip(chip, jax.eval_shape(
+        lambda: qwen3_next.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = _on_chip(chip, jax.eval_shape(
+        lambda: qwen3_next.init_cache(cfg, B, S)))
+    return cfg, params, cache
+
+
 def test_hybrid_decode_step_compiles_with_kernel_and_one_loop(chip,
                                                               monkeypatch):
     """``qwen3_next.decode_step`` at the published widths (two periods, 16
@@ -158,23 +170,36 @@ def test_hybrid_decode_step_compiles_with_kernel_and_one_loop(chip,
     benchmark's size, until the tree was laid out against it)."""
     import re
 
-    from gofr_tpu.models import qwen3_next
-
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
-    cfg = qwen3_next.Qwen3NextConfig(vocab_size=4096, num_hidden_layers=8,
-                                     held=(0, 16))
-
-    params = _on_chip(chip, jax.eval_shape(
-        lambda: qwen3_next.init_params(cfg, jax.random.PRNGKey(0))))
-    cache = _on_chip(chip, jax.eval_shape(lambda: qwen3_next.init_cache(cfg, B, S)))
+    cfg, params, cache = _hybrid(chip)
     compiled = jax.jit(
         lambda p, t, c: qwen3_next.decode_step(p, t, c, cfg),
         donate_argnums=(2,),
     ).lower(params, _shape(chip, jnp.int32, B), cache).compile()
     text = compiled.as_text()
     assert "gqa_decode_attention_tpu" in text
+    assert "grouped_matmul_tpu" in text and "ragged-dot" not in text
     assert len(re.findall(r" while\(", text)) == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
+def test_hybrid_prefill_compiles_with_the_grouped_kernel(chip, monkeypatch):
+    """``qwen3_next.prefill_into`` for a 512-token prompt at the published
+    widths (two periods, 16 held experts a layer): the expert products are
+    the Pallas grouped matmul, walking the stack of every layer's experts
+    where it lies (no ``ragged-dot``, and the temporaries, 74 MiB of
+    activations, are smaller than one layer's slice of that stack)."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    cfg, params, cache = _hybrid(chip)
+    compiled = jax.jit(
+        lambda p, t, n, c, s: qwen3_next.prefill_into(p, t, n, cfg, c, s),
+        donate_argnums=(3,),
+    ).lower(params, _shape(chip, jnp.int32, 1, 512),
+            _shape(chip, jnp.int32, 1), cache, _shape(chip, jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "grouped_matmul_tpu" in text and "ragged-dot" not in text
+    layer_slice = 16 * 3 * cfg.hidden_size * cfg.moe_intermediate_size * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_slice
 
 
 @pytest.mark.parametrize("rows,kv_heads,vocab,ffn", [
