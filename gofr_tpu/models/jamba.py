@@ -66,7 +66,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import attention, cached_decode_attention, repeat_kv, rms_norm
+from ..ops import (attention, cached_decode_attention, repeat_kv, rms_norm,
+                   ssm_update)
+from ..ops.selective_state import selective_scan_step
 from . import llama
 from .slot_state import UNSUPPORTED  # what ``Generator`` refuses
 
@@ -235,16 +237,6 @@ def init_cache(cfg: JambaConfig, batch: int,
 
 
 # ------------------------------------------------------------ selective scan
-def selective_scan_step(S, dt, x, B, C, A):
-    """One token of the selective state-space recurrence, any leading
-    axes. ``S`` [..., N, Di] float32; ``dt``, ``x`` [..., Di]; ``B``, ``C``
-    [..., N]; ``A`` [N, Di] (negative). ``S <- exp(dt A) S + (dt x) B``;
-    ``y = S C``. Elementwise float32, so no operand is rounded."""
-    S = (jnp.exp(dt[..., None, :] * A) * S
-         + (dt * x)[..., None, :] * B[..., :, None])
-    return S, jnp.sum(S * C[..., :, None], axis=-2)
-
-
 def selective_scan_chunked(dt, x, B, C, A, chunk: int = CHUNK, S0=None):
     """The same recurrence over a whole sequence from ``S0`` (zero if not
     given), a chunk at a time. ``dt``, ``x`` [T, Di]; ``B``, ``C`` [T, N];
@@ -321,9 +313,10 @@ def _mamba_prefill(cfg, lp, h, n):
     return _ssm_out(lp, y, c, z, h.dtype), S, window
 
 
-def _mamba_decode(cfg, lp, h, S, window):
-    """One token a row: ``h`` [B, D] (normed), ``S`` [B, N, Di],
-    ``window`` [K - 1, B, Di]."""
+def _mamba_decode(cfg, lp, h, state, i, window):
+    """One token a row: ``h`` [B, D] (normed), ``state`` the whole stack
+    [Mamba layers, B, N, Di] of which layer ``i`` moves, ``window``
+    [K - 1, B, Di]."""
     Di = cfg.d_inner
     uz = h @ lp["w_in"]
     u, z = uz[:, :Di], uz[:, Di:]
@@ -336,9 +329,9 @@ def _mamba_decode(cfg, lp, h, S, window):
         window = jnp.concatenate([window[1:], u[None]], axis=0)
     dt, B, C = _ssm_inputs(cfg, lp, c)
     with jax.named_scope("ssm_scan"):
-        S, y = selective_scan_step(
-            S, dt, c, B, C, -jnp.exp(lp["A_log"].astype(jnp.float32)))
-    return _ssm_out(lp, y, c, z, h.dtype), S, window
+        state, y = ssm_update(
+            state, i, dt, c, B, C, -jnp.exp(lp["A_log"].astype(jnp.float32)))
+    return _ssm_out(lp, y, c, z, h.dtype), state, window
 
 
 # --------------------------------------------------------------- the stack
@@ -483,9 +476,9 @@ def decode_step(params: dict, tokens: jnp.ndarray, cache: dict,
     at = pos[:, None] * KV + jnp.arange(KV)[None, :]   # [B, KV] cache rows
 
     def mamba_mixer(lp, h, st, i):
-        y, S, window = _mamba_decode(cfg, lp, h[:, 0], _at(st["state"], i),
-                                     _at(st["conv"], i))
-        return y[:, None], {**st, "state": _put(st["state"], i, S),
+        y, state, window = _mamba_decode(cfg, lp, h[:, 0], st["state"], i,
+                                         _at(st["conv"], i))
+        return y[:, None], {**st, "state": state,
                             "conv": _put(st["conv"], i, window)}
 
     def attend_at(st, i):

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 # (loaded before the function of the same name is defined: a submodule
 # loaded later would take the package's name ``grouped_matmul`` from it)
 from . import grouped_matmul as _grouped
+from . import selective_state as _state
 
 __all__ = [
     "rms_norm",
@@ -37,6 +38,7 @@ __all__ = [
     "cached_decode_attention",
     "paged_decode_attention",
     "grouped_matmul",
+    "ssm_update",
     "quantize_kv",
     "dequantize_kv",
     "quantize_kv4",
@@ -449,6 +451,30 @@ def grouped_matmul(xs, w, sizes, *, first=None):
         sizes = jax.lax.dynamic_update_slice(
             jnp.zeros((w.shape[0],), jnp.int32), sizes, (first,))
     return jax.lax.ragged_dot(xs, w, sizes)
+
+
+def ssm_update(state, layer, dt, x, B, C, A):
+    """One decode step of a Mamba-1 layer's selective state:
+    ``S' = exp(dt A) S + (dt x) B`` and ``y = sum_n S'[n] C[n]``, all
+    float32, on layer ``layer`` (a traced index) of the whole stack
+    ``state`` [L, rows, N, Di]; ``dt``, ``x`` [rows, Di]; ``B``, ``C``
+    [rows, N]; ``A`` [N, Di]. Returns the stack with that layer alone
+    changed, and ``y`` [rows, Di].
+
+    On a TPU, where the widths tile, the Pallas kernel reads the layer's
+    state once and writes it once, in place (``ops/selective_state.py``);
+    everywhere else ``selective_scan_step`` on the layer's slice, put back
+    with a dynamic update.
+    """
+    rows, n, di = state.shape[1:]
+    kernel = _on_tpu() and _state.block_rows(rows, n, di) is not None
+    record_branch("ssm_update", kernel, state, x)
+    if kernel:
+        return _state.ssm_update_tpu(state, layer, dt, x, B, C, A)
+    S, y = _state.selective_scan_step(
+        jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False),
+        dt, x, B, C, A)
+    return jax.lax.dynamic_update_index_in_dim(state, S, layer, 0), y
 
 
 def swiglu(x: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray,
