@@ -51,6 +51,7 @@ from ..ops import (
     attention,
     cached_decode_attention,
     flash_attention,
+    gated_delta_update,
     repeat_kv,
     rms_norm,
     rope_table,
@@ -59,8 +60,8 @@ from . import moe
 from .slot_state import UNSUPPORTED  # what ``Generator`` refuses
 
 __all__ = ["Qwen3NextConfig", "init_params", "init_cache", "prefill_into",
-           "decode_step", "gated_delta_chunked", "gated_delta_step",
-           "MOE_COUNTERS", "UNSUPPORTED"]
+           "decode_step", "gated_delta_chunked", "MOE_COUNTERS",
+           "UNSUPPORTED"]
 
 CHUNK = 64  # DeltaNet's prefill chunk, as published
 
@@ -269,23 +270,6 @@ def _count(counts, adds):
 
 
 # ------------------------------------------------------------ Gated DeltaNet
-def gated_delta_step(S, q, k, v, g, beta):
-    """One token of the gated delta rule, any leading axes. ``S``
-    [..., dk, dv] float32 (key x value); ``q``, ``k`` [..., dk]; ``v``
-    [..., dv]; ``g``, ``beta`` [...]. ``S <- exp(g) S``; ``delta = beta
-    (v - S^T k)``; ``S <- S + k delta^T``; ``o = S^T q``. The old state is
-    swept once for both products (``S_new^T q = exp(g) S^T q + delta
-    (k.q)``) and once for the update; products are elementwise float32,
-    not matmuls, so no operand is rounded to bfloat16."""
-    decay = jnp.exp(g)[..., None]
-    Sk = jnp.sum(S * k[..., :, None], axis=-2) * decay
-    Sq = jnp.sum(S * q[..., :, None], axis=-2) * decay
-    delta = beta[..., None] * (v - Sk)
-    S = S * decay[..., None] + k[..., :, None] * delta[..., None, :]
-    o = Sq + delta * jnp.sum(k * q, axis=-1, keepdims=True)
-    return S, o
-
-
 def gated_delta_chunked(q, k, v, g, beta, chunk: int = CHUNK):
     """The same rule over a whole sequence from a zero state, in its
     chunk-parallel form. ``q``, ``k`` [T, H, dk]; ``v`` [T, H, dv]; ``g``,
@@ -402,8 +386,9 @@ def _delta_prefill(cfg, lp, x, n):
     return _delta_out(cfg, lp, o[:T], z, x.dtype), S, window
 
 
-def _delta_decode(cfg, lp, x, S, window):
-    """One token a row: ``x`` [B, D], ``S`` [B, Hv, dk, dv], ``window``
+def _delta_decode(cfg, lp, x, state, i, window):
+    """One token a row: ``x`` [B, D], ``state`` the whole stack
+    [DeltaNet layers, B, Hv, dk, dv] of which layer ``i`` moves, ``window``
     [B, kernel - 1, channels]."""
     mixed, z, beta, g = _delta_inputs(cfg, lp, x)
     taps = lp["conv"].astype(jnp.float32)
@@ -411,8 +396,8 @@ def _delta_decode(cfg, lp, x, S, window):
               + mixed.astype(jnp.float32) * taps[-1])
     window = jnp.concatenate([window[:, 1:], mixed[:, None]], axis=1)
     q, k, v = _delta_heads(cfg, conved)
-    S, o = gated_delta_step(S, q, k, v, g, beta)
-    return _delta_out(cfg, lp, o, z, x.dtype), S, window
+    state, o = gated_delta_update(state, i, q, k, v, g, beta)
+    return _delta_out(cfg, lp, o, z, x.dtype), state, window
 
 
 # ----------------------------------------------------------- gated attention
@@ -566,11 +551,10 @@ def decode_step(params: dict, tokens: jnp.ndarray, cache: dict,
             lp = pp["lin"][j]
             with jax.named_scope("linear_attention"):
                 h = _norm(x, lp["norm"], cfg.rms_norm_eps)
-                y, S, window = _delta_decode(cfg, lp, h, at(st["state"], li),
-                                             at(st["conv"], li))
+                y, state, window = _delta_decode(cfg, lp, h, st["state"], li,
+                                                 at(st["conv"], li))
                 x = x + y
-            st = {**st, "state": put(st["state"], li, S),
-                  "conv": put(st["conv"], li, window)}
+            st = {**st, "state": state, "conv": put(st["conv"], li, window)}
             x, counts = _experts(cfg, pp["moe"][j], stack, p * I + j,
                                  x, valid, st["moe_counts"])
             st = {**st, "moe_counts": counts}

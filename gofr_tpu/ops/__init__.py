@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 # (loaded before the function of the same name is defined: a submodule
 # loaded later would take the package's name ``grouped_matmul`` from it)
+from . import gated_delta as _delta
 from . import grouped_matmul as _grouped
 from . import selective_state as _state
 
@@ -39,6 +40,7 @@ __all__ = [
     "paged_decode_attention",
     "grouped_matmul",
     "ssm_update",
+    "gated_delta_update",
     "quantize_kv",
     "dequantize_kv",
     "quantize_kv4",
@@ -475,6 +477,29 @@ def ssm_update(state, layer, dt, x, B, C, A):
         jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False),
         dt, x, B, C, A)
     return jax.lax.dynamic_update_index_in_dim(state, S, layer, 0), y
+
+
+def gated_delta_update(state, layer, q, k, v, g, beta):
+    """One decode step of a Gated DeltaNet layer's state: the gated delta
+    rule (``gated_delta.gated_delta_step``) on layer ``layer`` (a traced
+    index) of the whole stack ``state`` [L, rows, Hv, dk, dv] float32;
+    ``q``, ``k`` [rows, Hv, dk]; ``v`` [rows, Hv, dv]; ``g``, ``beta``
+    [rows, Hv]. Returns the stack with that layer alone changed, and ``o``
+    [rows, Hv, dv] float32.
+
+    On a TPU, where the widths tile, the Pallas kernel reads the layer's
+    state once and writes it once, in place (``ops/gated_delta.py``);
+    everywhere else ``gated_delta_step`` on the layer's slice, put back
+    with a dynamic update.
+    """
+    kernel = _on_tpu() and _delta.tiles(*state.shape[2:])
+    record_branch("gated_delta_update", kernel, state, q)
+    if kernel:
+        return _delta.gated_delta_update_tpu(state, layer, q, k, v, g, beta)
+    S, o = _delta.gated_delta_step(
+        jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False),
+        q, k, v, g, beta)
+    return jax.lax.dynamic_update_index_in_dim(state, S, layer, 0), o
 
 
 def swiglu(x: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray,

@@ -17,6 +17,7 @@ from gofr_tpu.ml import MLDatasource
 from gofr_tpu.ml.generate import Generator, _prefill_ladder
 from gofr_tpu.models import llama, moe
 from gofr_tpu.models import qwen3_next as qn
+from gofr_tpu.ops.gated_delta import gated_delta_step
 
 # a configuration file's keys at toy widths: ``num_experts`` held of
 # ``router_width``; two periods of three DeltaNet layers and one attention
@@ -170,7 +171,7 @@ def test_chunked_delta_rule_equals_recurrence(tokens, real):
     np.testing.assert_allclose(np.asarray(S), np.asarray(S_ref), atol=2e-5,
                                rtol=0)
     # and one recurrent step of the program's own from that state
-    S1, o1 = qn.gated_delta_step(S_ref, q[0], k[0], v[0], g[0], beta[0])
+    S1, o1 = gated_delta_step(S_ref, q[0], k[0], v[0], g[0], beta[0])
     S = S_ref * jnp.exp(g[0])[:, None, None]
     d = beta[0][:, None] * (v[0] - jnp.einsum("hkv,hk->hv", S, k[0]))
     S = S + k[0][:, :, None] * d[:, None, :]

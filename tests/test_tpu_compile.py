@@ -146,7 +146,7 @@ def test_decode_step_compiles_with_kernel(chip, monkeypatch):
             < 16 * 2**30)
 
 
-def _hybrid(chip):
+def _hybrid(chip, rows=B, s_max=S):
     """Qwen3-Next at the published widths, two periods, 16 held experts a
     layer: the configuration, and its parameters' and cache's shapes."""
     cfg = qwen3_next.Qwen3NextConfig(vocab_size=4096, num_hidden_layers=8,
@@ -154,33 +154,41 @@ def _hybrid(chip):
     params = _on_chip(chip, jax.eval_shape(
         lambda: qwen3_next.init_params(cfg, jax.random.PRNGKey(0))))
     cache = _on_chip(chip, jax.eval_shape(
-        lambda: qwen3_next.init_cache(cfg, B, S)))
+        lambda: qwen3_next.init_cache(cfg, rows, s_max)))
     return cfg, params, cache
 
 
-def test_hybrid_decode_step_compiles_with_kernel_and_one_loop(chip,
-                                                              monkeypatch):
+@pytest.mark.parametrize("rows,s_max", [(B, S), (128, 4096)],
+                         ids=["32x1024", "cell-128x4096"])
+def test_hybrid_decode_step_compiles_with_kernel_and_one_loop(
+        chip, monkeypatch, rows, s_max):
     """``qwen3_next.decode_step`` at the published widths (two periods, 16
-    held experts a layer): the attention layers take the Pallas kernel at
-    head size 256 with a group of 8; the step is ONE loop (what the
-    benchmark counts decode steps by); and no layer's expert weights are
-    copied out of their stacks (a dynamic slice does not fuse into the
-    grouped product's kernel, nor a static slice of a period's slab into a
-    matmul: the temporaries were a period's weights wide, 3.8 GB at the
-    benchmark's size, until the tree was laid out against it)."""
+    held experts a layer), at 32 rows and at the registered cell's 128 of
+    4,096: the attention layers take the Pallas kernel at head size 256
+    with a group of 8, and the DeltaNet layers the gated-delta kernel on
+    the whole state stack; the step is ONE loop (what the benchmark counts
+    decode steps by); no layer's expert weights are copied out of their
+    stacks (a dynamic slice does not fuse into the grouped product's
+    kernel, nor a static slice of a period's slab into a matmul: the
+    temporaries were a period's weights wide, 3.8 GB at the benchmark's
+    size, until the tree was laid out against it); and the state stack is
+    not copied (a layer's slice handed to the kernel would be, in and
+    out: 268 MB a layer at the cell's size)."""
     import re
 
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
-    cfg, params, cache = _hybrid(chip)
+    cfg, params, cache = _hybrid(chip, rows, s_max)
     compiled = jax.jit(
         lambda p, t, c: qwen3_next.decode_step(p, t, c, cfg),
         donate_argnums=(2,),
-    ).lower(params, _shape(chip, jnp.int32, B), cache).compile()
+    ).lower(params, _shape(chip, jnp.int32, rows), cache).compile()
     text = compiled.as_text()
     assert "gqa_decode_attention_tpu" in text
     assert "grouped_matmul_tpu" in text and "ragged-dot" not in text
+    assert "gated_delta_update_tpu" in text
     assert len(re.findall(r" while\(", text)) == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+    assert not re.findall(rf"f32\[6,{rows},32,128,128\]\S* copy\(", text)
 
 
 def test_hybrid_prefill_compiles_with_the_grouped_kernel(chip, monkeypatch):
