@@ -23,10 +23,11 @@ Built for the dense slot layout of ``ml/generate.py`` under
 
 - ``k``/``v`` ``[attention layers, B, S_max * KV, head_dim]`` and ``len``
   ``[B]``. Keys and values lie FLAT (row ``t * KV + g`` is token ``t``, KV
-  head ``g``): one KV head as ``[S_max, 1, 128]`` bfloat16 would be padded
-  on the chip to a whole (16, 128) tile in its last two axes, sixteen times
-  its bytes; flat it costs its own, and ``ops.cached_decode_attention``
-  reads it as one matrix a block (``flat_kv_heads``).
+  head ``g``; ``models/slot_state.py``): one KV head as ``[S_max, 1, 128]``
+  bfloat16 would be padded on the chip to a whole (16, 128) tile in its
+  last two axes, sixteen times its bytes; flat it costs its own, and
+  ``ops.cached_decode_attention`` reads it as one matrix a block
+  (``flat_kv_heads``).
 - ``state`` ``[Mamba layers, B, N, d_inner]`` float32 and ``conv``
   ``[Mamba layers, K - 1, B, d_inner]``: no length axis. The state's
   ``N = 16`` and the window's ``K - 1 = 3`` stand second to last for the

@@ -12,8 +12,10 @@ Built for the dense slot layout of ``ml/generate.py``: ``init_cache``,
 ``prefill_into`` and ``decode_step`` have ``models/llama.py``'s
 signatures, and the cache holds two kinds of per-slot state side by side:
 
-- ``k``/``v`` ``[periods, B, S_max, KV, head_dim]`` and ``len`` ``[B]`` for
-  the attention layers, written and read as llama's are;
+- ``k``/``v`` ``[periods, B, S_max * KV, head_dim]`` and ``len`` ``[B]``
+  for the attention layers, stored flat as Jamba's are
+  (``models/slot_state.py``): row ``t * KV + g`` is token ``t``, KV head
+  ``g``;
 - ``state`` ``[3 * periods, B, Hv, dk, dv]`` float32 and ``conv``
   ``[3 * periods, B, kernel - 1, channels]`` for the DeltaNet layers: no
   length axis. ``prefill_into`` computes both from zero, so a slot's
@@ -241,7 +243,7 @@ def init_cache(cfg: Qwen3NextConfig, batch: int,
                max_seq: int | None = None) -> dict:
     S = max_seq or cfg.max_position_embeddings
     P, NL = cfg.n_periods, cfg.n_periods * cfg.n_linear
-    kv = (P, batch, S, cfg.num_key_value_heads, cfg.head_dim)
+    kv = (P, batch, S * cfg.num_key_value_heads, cfg.head_dim)  # flat
     return {
         "k": jnp.zeros(kv, cfg.dtype),
         "v": jnp.zeros(kv, cfg.dtype),
@@ -457,9 +459,11 @@ def prefill_into(params: dict, tokens: jnp.ndarray, seq_lens: jnp.ndarray,
     real token's logits [1, V] and the cache."""
     del mesh
     T = tokens.shape[1]
-    S_max = cache["k"].shape[2]
-    if T > S_max:
-        raise ValueError(f"prompt bucket {T} exceeds cache length {S_max}")
+    KV, hd = cfg.num_key_value_heads, cfg.head_dim
+    rows_max = cache["k"].shape[2]
+    if T * KV > rows_max:
+        raise ValueError(f"prompt bucket {T} exceeds cache length "
+                         f"{rows_max // KV}")
     n = seq_lens[0]
     valid = jnp.arange(T) < n
     x = params["embed"][tokens[0]].astype(cfg.dtype)
@@ -493,7 +497,8 @@ def prefill_into(params: dict, tokens: jnp.ndarray, seq_lens: jnp.ndarray,
         x, counts = _experts(cfg, pp["moe"][NL], stack, p * I + NL,
                              x, valid, counts)
         return (x, counts, p + 1), (jnp.stack(states), jnp.stack(windows),
-                                    k[0], v[0])
+                                    k[0].reshape(T * KV, hd),
+                                    v[0].reshape(T * KV, hd))
 
     (x, counts, _), (states, windows, ks, vs) = jax.lax.scan(
         body, (x, cache["moe_counts"], jnp.int32(0)), params["periods"])
@@ -505,7 +510,7 @@ def prefill_into(params: dict, tokens: jnp.ndarray, seq_lens: jnp.ndarray,
         return jax.lax.dynamic_update_index_in_dim(
             cache[name], rows.astype(cache[name].dtype), slot, axis=1)
 
-    grow = ((0, 0), (0, S_max - T), (0, 0), (0, 0))
+    grow = ((0, 0), (0, rows_max - T * KV), (0, 0))
     return logits, {
         "k": put("k", jnp.pad(ks, grow)),
         "v": put("v", jnp.pad(vs, grow)),
@@ -525,12 +530,14 @@ def decode_step(params: dict, tokens: jnp.ndarray, cache: dict,
     row at its own ``len`` as llama's do."""
     del mesh
     b = tokens.shape[0]
+    KV = cfg.num_key_value_heads
     pos = cache["len"]
-    S_max = cache["k"].shape[2]
+    S_max = cache["k"].shape[2] // KV
     kv_len = jnp.minimum(pos + 1, S_max)
     x = params["embed"][tokens].astype(cfg.dtype)
     cos, sin = rope_table(pos[:, None], cfg.rotary_dim, cfg.rope_theta)
-    rows = jnp.arange(b)
+    rows = jnp.arange(b)[:, None]
+    kv_rows = pos[:, None] * KV + jnp.arange(KV)[None, :]   # [B, KV]
     valid = jnp.ones((b,), bool)
     NL, I = cfg.n_linear, cfg.full_attention_interval
     stack = params["experts"]
@@ -562,10 +569,11 @@ def decode_step(params: dict, tokens: jnp.ndarray, cache: dict,
         with jax.named_scope("attention"):
             h = _norm(x, lp["norm"], cfg.rms_norm_eps)[:, None]
             q, gate, k, v = _attn_inputs(cfg, lp, h, cos, sin)
-            st = {**st, "k": st["k"].at[p, rows, pos].set(k[:, 0]),
-                  "v": st["v"].at[p, rows, pos].set(v[:, 0])}
+            st = {**st, "k": st["k"].at[p, rows, kv_rows].set(k[:, 0]),
+                  "v": st["v"].at[p, rows, kv_rows].set(v[:, 0])}
             o = cached_decode_attention(q, st["k"], st["v"], kv_len, layer=p,
-                                        use_kernel=cfg.use_flash)
+                                        use_kernel=cfg.use_flash,
+                                        flat_kv_heads=KV)
             x = x + _attn_out(lp, o.reshape(b, 1, -1), gate)[:, 0]
         x, counts = _experts(cfg, pp["moe"][NL], stack, p * I + NL,
                              x, valid, st["moe_counts"])

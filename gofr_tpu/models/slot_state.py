@@ -12,6 +12,18 @@ the window ``conv``; ``Generator.pool_stats()`` reports their bytes beside
 the keys' and values'. What ``Generator`` refuses for it at construction,
 each with what it would take, is ``UNSUPPORTED``: one table for every such
 family.
+
+Both families store keys and values FLAT, ``[attention layers, B,
+S_max * KV, head_dim]``: row ``t * KV + g`` is token ``t``, KV head ``g``,
+so ``S_max`` is the plane's rows over ``KV``. Their few KV heads (Jamba's
+1 of 128, Qwen3-Next's 2 of 256) are fewer than a sublane tile holds: as
+``[S_max, KV, head_dim]`` the chip would pad them (one head of 128 to a
+whole tile, sixteen times its bytes) or pack a token's heads into one word
+(two of 256), and reading them as one matrix would take a relayout of the
+whole plane. Flat they cost their own bytes, ``prefill_into`` writes a
+prompt's rows as ``[T * KV, head_dim]``, ``decode_step`` writes a row's
+next token at ``pos * KV + g``, and ``ops.cached_decode_attention`` reads
+the plane as one matrix a block (``flat_kv_heads``).
 """
 
 from __future__ import annotations

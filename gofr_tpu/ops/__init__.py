@@ -326,17 +326,19 @@ def cached_decode_attention(q, k_cache, v_cache, kv_len, *, layer=None,
     the paged layout reads pages: every head in one product in the cache's
     dtype, the row's tail fetched to its live length. Any other cache takes
     the kernel that works a KV head at a time where S is a multiple of its
-    block of 256: fewer KV heads than a tile holds, and int8 caches, for
-    which pass ``k_scale``/``v_scale`` ([L, B, KV, S] or [B, KV, S]; seq
-    minor for DMA alignment; dequantized in VMEM after the halved HBM
-    read).
+    block of 256: a Llama-family ``[S, KV, D]`` cache with fewer KV heads
+    than a tile holds, and int8 caches, for which pass
+    ``k_scale``/``v_scale`` ([L, B, KV, S] or [B, KV, S]; seq minor for
+    DMA alignment; dequantized in VMEM after the halved HBM read).
 
     ``flat_kv_heads``: a full-precision stacked cache stored as
     ``[L, B, S * KV, D]`` (row ``t * KV + g`` is token ``t``, KV head
     ``g``), the form in which fewer KV heads than a sublane tile holds
-    cost their own bytes on the chip (``[S, 1, 128]`` bfloat16 pads its
-    last two axes to a whole tile, 16 times the bytes). It is read as one
-    matrix a block whatever the number of KV heads.
+    cost their own bytes on the chip and need no relayout to be read as
+    one matrix (``[S, 1, 128]`` bfloat16 pads its last two axes to a whole
+    tile, 16 times the bytes; ``[S, 2, 256]`` packs a token's two heads
+    into one word). It is read as one matrix a block whatever the number
+    of KV heads: Jamba and Qwen3-Next store their caches so.
     """
     if flat_kv_heads is not None:
         return _flat_decode_attention(q, k_cache, v_cache, kv_len, layer,

@@ -20,12 +20,14 @@ Two bodies, chosen by how the cache lies in HBM:
 
 - **One matrix a block** (``_dense_kernel``): a full-precision cache whose
   KV heads are whole sublane tiles (``KV % 8 == 0``: Mistral's 8,
-  DeepSeek's 32) is the page pool's easy case. ``[S_max, KV, D]`` and
-  ``[S_max * KV, D]`` are then the same bytes (row ``t * KV + g`` is token
-  ``t``, KV head ``g``), and the row is read by
-  ``paged_attention.walk_pages``, the body the paged layout's kernel runs,
-  with arithmetic where that one looks a page up in its table: the row's
-  ``j``-th "page" is its ``j``-th chunk of ``row_tiling``'s tokens. So
+  DeepSeek's 32), or one stored flat whatever its KV heads (Jamba's 1,
+  Qwen3-Next's 2 of 256: ``flat_kv_heads``), is the page pool's easy
+  case. ``[S_max, KV, D]`` and ``[S_max * KV, D]`` are then the same
+  bytes (row ``t * KV + g`` is token ``t``, KV head ``g``), and the row
+  is read by ``paged_attention.walk_pages``, the body the paged layout's
+  kernel runs, with arithmetic where that one looks a page up in its
+  table: the row's ``j``-th "page" is its ``j``-th chunk of
+  ``row_tiling``'s tokens. So
   every head meets a block of 2,048 rows in ONE product on the MXU in the
   cache's dtype with float32 accumulation, an additive own-head mask
   standing in for per-head slices (MHA and any group size are the same
@@ -33,15 +35,17 @@ Two bodies, chosen by how the cache lies in HBM:
   by chunk up to the live length, not whole.
 - **A product a KV head** (``_decode_kernel``): the int8 cache (flat
   ``[S_max, KV * D]`` values with seq-minor scales, dequantised in VMEM
-  so that HBM only ever moves int8), and a full-precision cache with
-  fewer KV heads than a sublane tile holds (Qwen3-Next's 2 of 256): there
-  the heads of one token lie packed inside one 32-bit word of a (2, 128)
+  so that HBM only ever moves int8), and a full-precision Llama-family
+  cache ``[S_max, KV, D]`` with fewer KV heads than a sublane tile holds:
+  there the heads of one token lie packed inside one 32-bit word of a
   tile, the flat view would be a copy of the cache (2 GB a layer call at
-  that cell's size), and putting the block's matrix together in VMEM from
-  a strided slice a head read eight times slower on the chip than this
-  body (``PERF.md`` §6, PR 33). It fetches whole blocks of ``block_s``
-  positions and contracts each KV head's ``n_rep`` query rows against the
-  un-expanded chunk in float32 — no ``repeat_kv`` inside VMEM either.
+  two KV heads of 256, 128 rows of 4,096), and putting the block's matrix
+  together in VMEM from a strided slice a head read eight times slower on
+  the chip than this body (``PERF.md`` §6). A family that stores
+  its cache flat never comes here. This body fetches whole blocks of
+  ``block_s`` positions and contracts each KV head's ``n_rep`` query rows
+  against the un-expanded chunk in float32 — no ``repeat_kv`` inside VMEM
+  either.
 
 Reference has no counterpart (pure-Go, no ML — SURVEY §2.10); this is the
 serving-path analogue of the prefill flash kernel, needed to hold the

@@ -288,30 +288,44 @@ def test_attention_is_position_free_but_causal(monkeypatch):
     assert np.abs(full_a - full_b).max() > 1e-3
 
 
-@pytest.mark.parametrize("lens", [[1, 17, 500], [128, 129, 127],
-                                  [2048, 2049, 3]])
-def test_flat_decode_kernel_matches_the_grouped_einsum(lens):
-    """The one-matrix body on a cache stored flat, at the published head
-    shape (20 query heads on one KV head of 128), interpreted: short rows,
-    a chunk's edges, a row at capacity handed one more than it holds."""
+# (positions, query heads, KV heads, head size, lengths): Jamba's published
+# head shape, and Qwen3-Next's attention layers at their cell's 4,096
+# positions (chunks of 64 tokens, blocks of 2,048 rows)
+_JAMBA, _QWEN3_NEXT = (2048, 20, 1, 128), (4096, 16, 2, 256)
+
+
+@pytest.mark.parametrize("widths,lens", [
+    pytest.param(_JAMBA, [1, 17, 500], id="lens0"),
+    pytest.param(_JAMBA, [128, 129, 127], id="lens1"),
+    pytest.param(_JAMBA, [2048, 2049, 3], id="lens2"),
+    pytest.param(_QWEN3_NEXT, [1, 63, 64, 65], id="qwen3next-chunk"),
+    pytest.param(_QWEN3_NEXT, [1024, 1025, 4096, 4097], id="qwen3next-block"),
+])
+def test_flat_decode_kernel_matches_the_grouped_einsum(widths, lens):
+    """The one-matrix body on a cache stored flat (row ``t * KV + g`` is
+    token ``t``, KV head ``g``), the layer traced, interpreted: at Jamba's
+    published head shape (20 query heads on one KV head of 128) and at
+    Qwen3-Next's (16 on 2 of 256); short rows, a chunk's and a block's
+    edges, a row at capacity handed one more than it holds."""
     from gofr_tpu.ops.decode_attention import (
         gqa_decode_attention_tpu,
         row_tiling,
     )
 
-    S, H, D = 2048, 20, 128
-    assert row_tiling(S, 1, D, 2) is None          # [S, 1, D] pads on the chip
-    assert row_tiling(S, 1, D, 2, flat=True) == (128, 2048)
+    S, H, KV, D = widths
+    assert row_tiling(S, KV, D, 2) is None      # [S, KV, D] pads on the chip
+    assert row_tiling(S, KV, D, 2, flat=True) == (128 // KV, 2048)
     keys = jax.random.split(jax.random.PRNGKey(1), 3)
     b = len(lens)
     q = jax.random.normal(keys[0], (b, 1, H, D), jnp.bfloat16)
-    k, v = (jax.random.normal(kk, (2, b, S, D), jnp.bfloat16)
+    k, v = (jax.random.normal(kk, (2, b, S * KV, D), jnp.bfloat16)
             for kk in keys[1:])
     attended = jnp.asarray(np.minimum(lens, S), jnp.int32)
-    want = ops.gqa_decode_attention(q, k[1][:, :, None], v[1][:, :, None],
+    want = ops.gqa_decode_attention(q, k[1].reshape(b, S, KV, D),
+                                    v[1].reshape(b, S, KV, D),
                                     kv_len=attended)
     got = gqa_decode_attention_tpu(q, k, v, jnp.asarray(lens, jnp.int32),
-                                   layer=1, flat_kv_heads=1, interpret=True)
+                                   layer=1, flat_kv_heads=KV, interpret=True)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=2e-2,
                                rtol=2e-2)

@@ -2,8 +2,9 @@
 then ``decode_step`` through the dense slot layout) against the float32
 plain reference's full forward, on logits; the chunk-parallel DeltaNet
 against the token-by-token recurrence; one chip's share of the experts
-against the uncut layer; what ``register_llm`` refuses for the family; and
-the value the decode kernels are handed as ``kv_len``.
+against the uncut layer; what ``register_llm`` refuses for the family; the
+value the decode kernels are handed as ``kv_len``; and the rows of the flat
+cache where keys and values lie.
 """
 
 import jax
@@ -363,6 +364,64 @@ def test_decode_hands_the_kernels_a_length_inside_the_cache(family, model,
     for handed in seen:
         np.testing.assert_array_equal(handed, [S_max, S_max, 5])
     np.testing.assert_array_equal(np.asarray(new["len"]), [S_max, S_max, 5])
+
+
+def test_keys_and_values_lie_flat(model, monkeypatch):
+    """Token ``t``'s KV head ``g`` lies at row ``t * KV + g`` of its
+    period's plane: where ``prefill_into`` puts a prompt's keys and values,
+    and where ``decode_step`` writes each row's next token (the rows below
+    it unmoved); the step hands the decode attention that flat cache."""
+    cfg, params = model
+    KV, hd = cfg.num_key_value_heads, cfg.head_dim
+    seen = []
+    inner = qn._attn_inputs
+
+    def spy(*args):
+        q, gate, k, v = inner(*args)
+        jax.debug.callback(
+            lambda k, v: seen.append((np.asarray(k), np.asarray(v))), k, v,
+            ordered=True)
+        return q, gate, k, v
+
+    monkeypatch.setattr(qn, "_attn_inputs", spy)
+    n, slot = 10, 1
+    toks = np.zeros((1, 128), np.int32)
+    toks[0, :n] = np.random.default_rng(4).integers(1, 128, n)
+    cache = qn.init_cache(cfg, 3, MAX_SEQ)
+    assert cache["k"].shape == (cfg.n_periods, 3, MAX_SEQ * KV, hd)
+    _, cache = jax.jit(lambda p, t, l, c, s: qn.prefill_into(
+        p, t, l, cfg, c, s))(params, toks, np.array([n], np.int32), cache,
+                             np.int32(slot))
+    jax.effects_barrier()
+    assert len(seen) == cfg.n_periods
+    for p, (k, v) in enumerate(seen):       # [1, T, KV, hd] a period
+        for name, want in (("k", k), ("v", v)):
+            np.testing.assert_array_equal(
+                np.asarray(cache[name][p, slot, :n * KV]),
+                want[0, :n].reshape(n * KV, hd))
+    # past the prompt's bucket of 128, nothing
+    assert not np.asarray(cache["k"][:, slot, 128 * KV:]).any()
+
+    seen.clear()
+    _, new = jax.jit(lambda p, t, c: qn.decode_step(p, t, c, cfg))(
+        params, np.full((3,), 7, np.int32), cache)
+    jax.effects_barrier()
+    assert len(seen) == cfg.n_periods
+    pos = np.asarray(cache["len"])              # [0, n, 0]
+    for p, (k, v) in enumerate(seen):       # [3, 1, KV, hd] a period
+        for name, want in (("k", k), ("v", v)):
+            for row in range(3):
+                at = pos[row] * KV + np.arange(KV)
+                np.testing.assert_array_equal(
+                    np.asarray(new[name][p, row, at]), want[row, 0])
+            np.testing.assert_array_equal(
+                np.asarray(new[name][p, slot, :n * KV]),
+                np.asarray(cache[name][p, slot, :n * KV]))
+    H = cfg.num_attention_heads
+    key = ops.branch_key("decode_attention",
+                         jax.ShapeDtypeStruct((3, 1, H, hd), jnp.float32),
+                         new["k"])
+    assert ops.kernel_branches()[key] == "xla"     # off the chip
 
 
 def test_a_prompt_and_a_step_record_the_grouped_products_branch(model,

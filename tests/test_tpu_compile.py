@@ -80,13 +80,15 @@ def test_decode_kernel_compiles(chip, layout):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("rows,s_max,heads,kv_heads,head_dim", [
-    (32, 2048, 32, 8, 128),    # mistral7b-batch
-    (128, 4096, 16, 2, 256),   # qwen3next-longanswer's attention layers
-    (8, 2048, 32, 32, 128),    # DeepSeek's widths on the dense layout
-], ids=["mistral-32x2048", "qwen3next-128x4096", "deepseek-8x2048"])
+@pytest.mark.parametrize("rows,s_max,heads,kv_heads,head_dim,flat", [
+    (32, 2048, 32, 8, 128, False),    # mistral7b-batch
+    (128, 4096, 16, 2, 256, False),   # 2 KV heads stored [S, KV, D]
+    (8, 2048, 32, 32, 128, False),    # DeepSeek's widths on the dense layout
+    (128, 4096, 16, 2, 256, True),    # qwen3next-longanswer's attention layers
+], ids=["mistral-32x2048", "qwen3next-128x4096", "deepseek-8x2048",
+        "qwen3next-flat-128x4096"])
 def test_dense_kernel_compiles_at_cell_widths(chip, rows, s_max, heads,
-                                              kv_heads, head_dim):
+                                              kv_heads, head_dim, flat):
     """The full-precision dense kernel at the cells' own widths, the cache
     stacked and the layer traced: it compiles, its block fits VMEM (the
     compiler refuses one that does not), and NO copy of the cache is made
@@ -94,26 +96,36 @@ def test_dense_kernel_compiles_at_cell_widths(chip, rows, s_max, heads,
     ``[S * KV, D]``, which is the same bytes only where the KV heads are
     whole sublane tiles: at 2 KV heads XLA answered that reshape with a
     relayout of both planes (2 GB of temporaries at the hybrid cell's
-    size), which is why such a cache keeps the per-head body."""
+    size), which is why such a cache keeps the per-head body. Qwen3-Next
+    stores its 2 KV heads flat, ``[S * KV, D]`` in HBM (``flat``), and so
+    takes the one-matrix body with no relayout."""
+    import re
+
     from gofr_tpu.ops.decode_attention import (
         gqa_decode_attention_tpu,
         row_tiling,
     )
 
-    tiling = row_tiling(s_max, kv_heads, head_dim, 2)
-    assert (tiling is not None) == (kv_heads % 8 == 0)
+    tiling = row_tiling(s_max, kv_heads, head_dim, 2, flat=flat)
+    assert (tiling is not None) == (kv_heads % 8 == 0 or flat)
     if tiling is not None:
         assert 4 * tiling[1] * head_dim * 2 <= 4 * 2**20
-    kv = _shape(chip, jnp.bfloat16, 2, rows, s_max, kv_heads, head_dim)
+    plane = ((2, rows, s_max * kv_heads, head_dim) if flat
+             else (2, rows, s_max, kv_heads, head_dim))
+    kv = _shape(chip, jnp.bfloat16, *plane)
     compiled = jax.jit(
         lambda q, k, v, kv_len, layer: gqa_decode_attention_tpu(
-            q, k, v, kv_len, layer=layer)
+            q, k, v, kv_len, layer=layer,
+            flat_kv_heads=kv_heads if flat else None)
     ).lower(_shape(chip, jnp.bfloat16, rows, 1, heads, head_dim), kv, kv,
             _shape(chip, jnp.int32, rows), _shape(chip, jnp.int32)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "gqa_decode_attention_tpu" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+    if flat:   # a relayout of a plane reads as a reshape or a copy
+        assert not re.findall(r"bf16\[{}\]\S* (?:copy|reshape)\(".format(
+            ",".join(map(str, plane))), text)
 
 
 def test_flash_kernel_compiles(chip):
@@ -165,15 +177,16 @@ def test_hybrid_decode_step_compiles_with_kernel_and_one_loop(
     """``qwen3_next.decode_step`` at the published widths (two periods, 16
     held experts a layer), at 32 rows and at the registered cell's 128 of
     4,096: the attention layers take the Pallas kernel at head size 256
-    with a group of 8, and the DeltaNet layers the gated-delta kernel on
-    the whole state stack; the step is ONE loop (what the benchmark counts
-    decode steps by); no layer's expert weights are copied out of their
-    stacks (a dynamic slice does not fuse into the grouped product's
-    kernel, nor a static slice of a period's slab into a matmul: the
-    temporaries were a period's weights wide, 3.8 GB at the benchmark's
-    size, until the tree was laid out against it); and the state stack is
-    not copied (a layer's slice handed to the kernel would be, in and
-    out: 268 MB a layer at the cell's size)."""
+    with a group of 8 on the flat cache, no plane of it copied, and the
+    DeltaNet layers the gated-delta kernel on the whole state stack; the
+    step is ONE loop (what the benchmark counts decode steps by); no
+    layer's expert weights are copied out of their stacks (a dynamic slice
+    does not fuse into the grouped product's kernel, nor a static slice of
+    a period's slab into a matmul: the temporaries were a period's weights
+    wide, 3.8 GB at the benchmark's size, until the tree was laid out
+    against it); and the state stack is not copied (a layer's slice handed
+    to the kernel would be, in and out: 268 MB a layer at the cell's
+    size)."""
     import re
 
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
@@ -189,6 +202,10 @@ def test_hybrid_decode_step_compiles_with_kernel_and_one_loop(
     assert len(re.findall(r" while\(", text)) == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
     assert not re.findall(rf"f32\[6,{rows},32,128,128\]\S* copy\(", text)
+    # the keys and values lie flat: the kernel reads them where they are
+    # (a relayout of a plane reads as a reshape or a copy)
+    assert not re.findall(
+        rf"bf16\[2,{rows},{2 * s_max},256\]\S* (?:copy|reshape)\(", text)
 
 
 def test_hybrid_prefill_compiles_with_the_grouped_kernel(chip, monkeypatch):
@@ -196,7 +213,10 @@ def test_hybrid_prefill_compiles_with_the_grouped_kernel(chip, monkeypatch):
     widths (two periods, 16 held experts a layer): the expert products are
     the Pallas grouped matmul, walking the stack of every layer's experts
     where it lies (no ``ragged-dot``, and the temporaries, 74 MiB of
-    activations, are smaller than one layer's slice of that stack)."""
+    activations, are smaller than one layer's slice of that stack); and no
+    plane of the flat key and value cache is copied."""
+    import re
+
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
     cfg, params, cache = _hybrid(chip)
     compiled = jax.jit(
@@ -208,6 +228,9 @@ def test_hybrid_prefill_compiles_with_the_grouped_kernel(chip, monkeypatch):
     assert "grouped_matmul_tpu" in text and "ragged-dot" not in text
     layer_slice = 16 * 3 * cfg.hidden_size * cfg.moe_intermediate_size * 2
     assert compiled.memory_analysis().temp_size_in_bytes < layer_slice
+    # the prompt's keys and values land in the flat planes where they lie
+    assert not re.findall(
+        rf"bf16\[2,{B},{2 * S},256\]\S* (?:copy|reshape)\(", text)
 
 
 @pytest.mark.parametrize("rows,kv_heads,vocab,ffn", [
