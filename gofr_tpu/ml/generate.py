@@ -301,12 +301,14 @@ def _model_of(cfg):
     place the serving loop chooses between families. The dense slot
     layout takes ``init_cache``, ``prefill_into`` and ``decode_step`` from
     it; every other layout is the llama block's."""
-    from ..models import jamba, llama, qwen3_next
+    from ..models import deepseek_v3, jamba, llama, qwen3_next
 
     if isinstance(cfg, qwen3_next.Qwen3NextConfig):
         return qwen3_next
     if isinstance(cfg, jamba.JambaConfig):
         return jamba
+    if isinstance(cfg, deepseek_v3.DeepseekV3Config):
+        return deepseek_v3
     return llama
 
 
@@ -1512,6 +1514,11 @@ class Generator:
                 kv_cache_bytes=int(cache["k"].nbytes + cache["v"].nbytes),
                 state_rows_swept=self.state_rows_swept,
                 state_rows_live=self.state_rows_live)
+        if "latent" in self.cache:
+            # a latent-attention family: one row a token and layer is all
+            # the keys and values its slots hold
+            latent = int(self.cache["latent"].nbytes)
+            out.update(latent_cache_bytes=latent, kv_cache_bytes=latent)
         if "moe_counts" in self.cache:
             out.update(self._expert_counts())  # what routing did so far
         return out

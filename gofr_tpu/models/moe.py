@@ -14,8 +14,9 @@ the MXU wants batched matmuls, not per-token gathers:
   axis and the dispatch/combine einsums become XLA all_to_alls over ICI;
 - combine: weighted scatter back, zeros for dropped tokens.
 
-Serving uses the dropless layer beside it (``route_top_k``,
-``dropless_experts``, ``gated_shared_expert``): no capacity, no dropped
+Serving uses the dropless layer beside it (a router, ``route_top_k`` or
+``route_group_limited``; ``dropless_experts``; a shared expert,
+``gated_shared_expert`` or ``shared_expert``): no capacity, no dropped
 token. It is told which experts this chip ``held``: the router keeps its
 full width, top-k and the renormalisation run over every expert, and only
 the held experts' terms are computed and added (one chip's share of an
@@ -37,7 +38,13 @@ from ..ops import grouped_matmul
 from ..parallel import P, constrain
 
 __all__ = ["MoEConfig", "init_moe_params", "moe_layer", "MOE_SHARDING_RULES",
-           "route_top_k", "dropless_experts", "gated_shared_expert"]
+           "route_top_k", "route_group_limited", "dropless_experts",
+           "gated_shared_expert", "shared_expert", "MOE_COUNTERS",
+           "add_counts"]
+
+# the routing counters a serving family with held experts keeps in its
+# cache (``moe_counts``), read only when ``Generator.pool_stats()`` asks
+MOE_COUNTERS = ("expert_pairs_routed", "expert_pairs_held", "experts_touched")
 
 
 class MoEConfig:
@@ -148,6 +155,36 @@ def route_top_k(x: jnp.ndarray, router: jnp.ndarray, top_k: int):
     return vals / vals.sum(-1, keepdims=True), idx
 
 
+def route_group_limited(x: jnp.ndarray, router: jnp.ndarray,
+                        bias: jnp.ndarray, top_k: int, n_group: int,
+                        topk_group: int, scale: float):
+    """DeepSeek-V3's ``noaux_tc`` routing: x [N, D], router [D, E], bias
+    [E] (``e_score_correction_bias``) -> (weights [N, k] float32 summing
+    to ``scale``, experts [N, k] int32), ``route_top_k``'s form.
+
+    ``s = sigmoid(x W)`` in float32 at full matmul precision; the choice
+    reads ``b = s + bias``: a group of ``E / n_group`` experts scores the
+    sum of its two best ``b``, the ``topk_group`` best groups stay, and the
+    ``top_k`` best ``b`` among their experts are chosen (the others' read
+    0, as transformers' ``masked_fill`` leaves them). The weights are the
+    chosen experts' unbiased ``s``, renormalised and times ``scale``
+    (``routed_scaling_factor``)."""
+    n, e = x.shape[0], router.shape[1]
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    b = s + bias.astype(jnp.float32)
+    grouped = b.reshape(n, n_group, e // n_group)
+    group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)          # [N, G]
+    _, keep = jax.lax.top_k(group_score, topk_group)
+    kept = jnp.zeros((n, n_group), bool).at[
+        jnp.arange(n)[:, None], keep].set(True)
+    choice = jnp.where(jnp.repeat(kept, e // n_group, axis=1), b, 0.0)
+    _, idx = jax.lax.top_k(choice, top_k)
+    w = jnp.take_along_axis(s, idx, axis=1)
+    return w / (w.sum(-1, keepdims=True) + 1e-20) * scale, idx
+
+
 def dropless_experts(x: jnp.ndarray, weights: jnp.ndarray, idx: jnp.ndarray,
                      w_gate_up: jnp.ndarray, w_down: jnp.ndarray,
                      held: tuple[int, int], valid: jnp.ndarray | None = None,
@@ -203,3 +240,21 @@ def gated_shared_expert(x: jnp.ndarray, s_gate_up: jnp.ndarray,
     y = (jax.nn.silu(h[:, :f]) * h[:, f:]) @ s_down
     mix = jax.nn.sigmoid(x.astype(jnp.float32) @ s_mix.astype(jnp.float32))
     return (y * mix[:, None].astype(y.dtype)).astype(x.dtype)
+
+
+def shared_expert(x: jnp.ndarray, s_gate_up: jnp.ndarray,
+                  s_down: jnp.ndarray):
+    """``down(silu(gate x) * up x)``, ungated: the expert every token meets,
+    computed whole on every chip. ``s_gate_up`` [D, 2F] (gate | up),
+    ``s_down`` [F, D]."""
+    h = x @ s_gate_up
+    f = s_down.shape[0]
+    return ((jax.nn.silu(h[:, :f]) * h[:, f:]) @ s_down).astype(x.dtype)
+
+
+def add_counts(counts, adds):
+    """``counts`` [n, 2] uint32 (low, high words) plus ``adds`` [n]: 64-bit
+    sums out of 32-bit adds (a busy server passes 2**32 pairs in hours)."""
+    low = counts[:, 0] + adds.astype(jnp.uint32)
+    high = counts[:, 1] + (low < counts[:, 0]).astype(jnp.uint32)
+    return jnp.stack([low, high], axis=-1)

@@ -16,14 +16,19 @@ head axes line up with the mesh's ``sp``/``tp`` axes without transposes.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 
-# (loaded before the function of the same name is defined: a submodule
-# loaded later would take the package's name ``grouped_matmul`` from it)
+# (loaded before the functions of the same names are defined: a submodule
+# loaded later would take the package's name ``grouped_matmul`` or
+# ``flash_attention`` from it, and a model module imported after that would
+# bind the module)
+from . import flash_attention as _flash
 from . import gated_delta as _delta
 from . import grouped_matmul as _grouped
+from . import latent_attention as _latent
 from . import selective_state as _state
 
 __all__ = [
@@ -31,6 +36,7 @@ __all__ = [
     "layer_norm",
     "rope_table",
     "scale_rope_freqs",
+    "yarn_mscale",
     "apply_rope",
     "repeat_kv",
     "attention",
@@ -38,6 +44,7 @@ __all__ = [
     "gqa_decode_attention",
     "cached_decode_attention",
     "paged_decode_attention",
+    "latent_decode_attention",
     "grouped_matmul",
     "ssm_update",
     "gated_delta_update",
@@ -99,24 +106,67 @@ def layer_norm(x: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
     return (out * scale.astype(jnp.float32) + bias.astype(jnp.float32)).astype(dtype)
 
 
-def scale_rope_freqs(freqs: jnp.ndarray, scaling: dict) -> jnp.ndarray:
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention scale for a context stretched ``factor`` times:
+    ``0.1 mscale ln(factor) + 1``, and 1 where nothing is stretched.
+    DeepSeek-V3 multiplies its softmax scale by the square of it at
+    ``mscale_all_dim``, and its cos and sin by the ratio of the one at
+    ``mscale`` to the one at ``mscale_all_dim``."""
+    if factor <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_freqs(freqs: jnp.ndarray, scaling: dict, theta: float
+                ) -> jnp.ndarray:
+    """YaRN (arXiv:2309.00071) as transformers' ``_compute_yarn_parameters``
+    computes it: the pairs that turn more than ``beta_fast`` times over the
+    original context keep their frequency, those that turn fewer than
+    ``beta_slow`` times are slowed ``factor``-fold, and a linear ramp over
+    the pairs between blends the two."""
+    factor = float(scaling["factor"])
+    orig = float(scaling["original_max_position_embeddings"])
+    fast = float(scaling.get("beta_fast") or 32)
+    slow = float(scaling.get("beta_slow") or 1)
+    dim = 2 * freqs.shape[-1]
+
+    def pair(turns):  # the pair whose wavelength fits ``turns`` times
+        return (dim * math.log(orig / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(pair(fast)), 0)
+    high = min(math.ceil(pair(slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return freqs / factor * ramp + freqs * (1.0 - ramp)
+
+
+def scale_rope_freqs(freqs: jnp.ndarray, scaling: dict,
+                     theta: float | None = None) -> jnp.ndarray:
     """Apply a HF ``rope_scaling`` spec to the base rotary frequencies.
 
     Supports ``llama3`` (Llama-3.1/3.2's NTK-by-parts: low-frequency bands
     are slowed by ``factor``, high-frequency bands kept, the middle smoothly
     interpolated — reference behavior: transformers'
-    ``_compute_llama3_parameters``) and ``linear`` (all bands divided by
-    ``factor``). Anything else raises at trace/load time rather than
+    ``_compute_llama3_parameters``), ``linear`` (all bands divided by
+    ``factor``) and ``yarn`` (``_yarn_freqs``; it needs the rotary base
+    ``theta``). Anything else raises at trace/load time rather than
     silently mis-rotating (ADVICE r4 #2: Llama-3.1 checkpoints specify
     llama3 scaling; ignoring it degrades every generation with no error).
     """
     rtype = str(scaling.get("rope_type") or scaling.get("type") or "").lower()
     if rtype == "linear":
         return freqs / float(scaling["factor"])
+    if rtype == "yarn":
+        if theta is None:
+            raise ValueError("yarn rope_scaling needs the rotary base theta")
+        return _yarn_freqs(freqs, scaling, float(theta))
     if rtype != "llama3":
         raise ValueError(
             f"unsupported rope_scaling type {rtype!r}; "
-            "supported: 'llama3', 'linear'")
+            "supported: 'llama3', 'linear', 'yarn'")
     factor = float(scaling.get("factor", 8.0))
     low_ff = float(scaling.get("low_freq_factor", 1.0))
     high_ff = float(scaling.get("high_freq_factor", 4.0))
@@ -143,7 +193,7 @@ def rope_table(positions: jnp.ndarray, head_dim: int, theta: float = 500_000.0,
     half = head_dim // 2
     freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
     if scaling is not None:
-        freqs = scale_rope_freqs(freqs, scaling)
+        freqs = scale_rope_freqs(freqs, scaling, theta)
     angles = positions.astype(jnp.float32)[..., None] * freqs
     return jnp.cos(angles), jnp.sin(angles)
 
@@ -431,6 +481,27 @@ def paged_decode_attention(q, k_pool, v_pool, table, kv_len, *, layer):
                      causal=False, kv_len=kv_len)
 
 
+def latent_decode_attention(q, cache, kv_len, *, layer, scale: float,
+                            value_width: int):
+    """Decode attention over a latent cache (absorbed multi-head latent
+    attention, ``ops/latent_attention.py``): ``q`` [B, H, W] against
+    layer ``layer`` (a traced index) of the whole stack ``cache``
+    [L, B, S, W], each row's first ``kv_len`` positions, the row's first
+    ``value_width`` values read back. Returns [B, H, value_width].
+
+    On a TPU, where the widths tile, the Pallas kernel reads each row's
+    live positions once for all heads; everywhere else the plain
+    computation on the layer's slice.
+    """
+    kernel = (_on_tpu() and _latent.block_positions(
+        cache.shape[2], cache.shape[3], value_width,
+        cache.dtype.itemsize) is not None)
+    record_branch("latent_decode_attention", kernel, q, cache)
+    run = (_latent.latent_decode_attention_tpu if kernel
+           else _latent.latent_decode_attention_ref)
+    return run(q, cache, kv_len, layer, scale=scale, value_width=value_width)
+
+
 def grouped_matmul(xs, w, sizes, *, first=None):
     """The grouped product of a dropless expert layer: ``xs`` [M, K] with
     its rows sorted by group, group ``g`` (``sizes[g]`` rows, [G] int32)
@@ -542,11 +613,9 @@ def _flash_diff(q, k, v, kv_len, causal, q_offset, block_q, block_k):
     takes ITS vjp — flash forward speed, standard-attention backward. The
     logits matrix does materialize during backward; training long
     sequences pairs this with LlamaConfig(remat=True)."""
-    from .flash_attention import flash_attention_tpu
-
-    return flash_attention_tpu(q, k, v, kv_len, causal=causal,
-                               q_offset=q_offset, block_q=block_q,
-                               block_k=block_k)
+    return _flash.flash_attention_tpu(q, k, v, kv_len, causal=causal,
+                                      q_offset=q_offset, block_q=block_q,
+                                      block_k=block_k)
 
 
 def _flash_diff_fwd(q, k, v, kv_len, causal, q_offset, block_q, block_k):

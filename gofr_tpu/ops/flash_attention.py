@@ -27,6 +27,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+_DEFAULT_VMEM = 16 * 2**20  # the v5e's scoped VMEM when a kernel asks for none
 
 
 def _attn_kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref, *, block_k: int,
@@ -117,6 +118,13 @@ def flash_attention_tpu(q, k, v, kv_len=None, *, causal: bool = True,
         _attn_kernel, block_k=block_k, causal=causal, q_offset=q_offset,
         seq_k=tk, has_kvlen=has_kvlen, n_heads=h,
     )
+    # a cell holds its head's whole K and V, double-buffered; past the
+    # chip's default scoped VMEM (16 MiB) the kernel asks for what it needs
+    lanes = -(-d // 128) * 128
+    vmem = 2 * (2 * tk + 2 * block_q) * lanes * q.dtype.itemsize
+    extra = ({} if vmem <= _DEFAULT_VMEM else {
+        "compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=vmem + _DEFAULT_VMEM)})
     out = pl.pallas_call(
         kernel,
         grid=(b * h, tq // block_q),
@@ -131,5 +139,6 @@ def flash_attention_tpu(q, k, v, kv_len=None, *, causal: bool = True,
         out_specs=pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
         interpret=interpret,
+        **extra,
     )(kv_len, qr, kr, vr)
     return out.reshape(b, h, tq, d).transpose(0, 2, 1, 3)

@@ -182,6 +182,9 @@ def test_rope_scaling_linear_and_unsupported():
     out = scale_rope_freqs(freqs, {"type": "linear", "factor": 4.0})
     np.testing.assert_allclose(np.asarray(out), np.asarray(freqs) / 4.0)
     with pytest.raises(ValueError, match="rope_scaling"):
+        scale_rope_freqs(freqs, {"rope_type": "dynamic", "factor": 2.0})
+    # yarn's correction range needs the base the frequencies came from
+    with pytest.raises(ValueError, match="rope_scaling"):
         scale_rope_freqs(freqs, {"rope_type": "yarn", "factor": 2.0})
 
 

@@ -336,3 +336,61 @@ def test_jamba_prefill_compiles(chip, monkeypatch, tokens):
     assert compiled.memory_analysis().temp_size_in_bytes < 640 * 2**20
     if tokens >= 128:
         assert "tpu_custom_call" in compiled.as_text()   # flash attention
+
+
+def _mla_shapes(chip, rows=64, s_max=8192):
+    """GigaChat3.1's block (DeepSeek-V3) at the published widths, one
+    leading dense layer and one expert layer of 16 held experts, YaRN as
+    published: the configuration, and its parameters' and cache's
+    shapes."""
+    from gofr_tpu.models import deepseek_v3
+
+    cfg = deepseek_v3.DeepseekV3Config(
+        vocab_size=4096, num_hidden_layers=2, first_k_dense_replace=1,
+        held=(0, 16), rope_scaling={
+            "beta_fast": 32, "beta_slow": 1, "factor": 64, "mscale": 1,
+            "mscale_all_dim": 1, "original_max_position_embeddings": 4096,
+            "rope_type": "yarn"})
+    params = _on_chip(chip, jax.eval_shape(
+        lambda: deepseek_v3.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = _on_chip(chip, jax.eval_shape(
+        lambda: deepseek_v3.init_cache(cfg, rows, s_max)))
+    return deepseek_v3, cfg, params, cache
+
+
+def test_mla_decode_step_compiles_with_the_latent_kernel_and_one_loop(
+        chip, monkeypatch):
+    """``deepseek_v3.decode_step`` at the cell's 64 rows of 8,192: the
+    absorbed attention is the Pallas latent kernel reading the latent
+    stack where it lies (no plane of it copied), the expert products the
+    grouped kernel, and the step ONE loop."""
+    import re
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    mod, cfg, params, cache = _mla_shapes(chip)
+    compiled = jax.jit(
+        lambda p, t, c: mod.decode_step(p, t, c, cfg), donate_argnums=(2,),
+    ).lower(params, _shape(chip, jnp.int32, 64), cache).compile()
+    text = compiled.as_text()
+    assert "latent_decode_attention_tpu" in text
+    assert "grouped_matmul_tpu" in text and "ragged-dot" not in text
+    assert len(re.findall(r" while\(", text)) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+    assert not re.findall(r"bf16\[2,64,8192,640\]\S* copy\(", text)
+
+
+def test_mla_prefill_compiles_at_the_longest_prompt(chip, monkeypatch):
+    """The 8,192-token prefill program: the flash kernel takes q, k and v
+    192 wide, and a head's whole keys and values (16.5 MiB with their
+    buffers) pass the default scoped VMEM, so the kernel asks for more."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    mod, cfg, params, cache = _mla_shapes(chip)
+    compiled = jax.jit(
+        lambda p, t, n, c, s: mod.prefill_into(p, t, n, cfg, c, s),
+        donate_argnums=(3,),
+    ).lower(params, _shape(chip, jnp.int32, 1, 8192),
+            _shape(chip, jnp.int32, 1), cache,
+            _shape(chip, jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "flash_attention_tpu" in text and "grouped_matmul_tpu" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 2**30
